@@ -113,7 +113,7 @@ def _scenarios():
     graph = trace(_softmax_chain, x, g)
     yield "softmax_chain_16x2048", graph, make_plan(graph), V5E
 
-    hw = Hardware(vmem_bytes=160 * 1024)  # the A+B infeasibility cliff
+    hw = Hardware(vmem_bytes=768 * 1024)  # the A+B infeasibility cliff
     x, g, b = _rand((512, 2048)), _scale(2048), _rand(2048)
     graph = trace(_waist, x, g, b)
     yield "waist_512x2048", graph, _waist_plan(graph), hw

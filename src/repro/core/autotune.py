@@ -20,8 +20,8 @@ per-candidate compile-measure loop survives as ``batch_compile=False``
 speedup is quoted against).
 
 Gating: measuring wall time in Pallas interpret mode on CPU says nothing
-about TPU latency, so the sweep runs only when an accelerator backend is
-present (or ``REPRO_AUTOTUNE=force`` for tests / CI smoke).  Otherwise
+about TPU latency, so the sweep runs only where kernels compile for the
+chip (or ``REPRO_AUTOTUNE=force`` for tests / CI smoke).  Otherwise
 the caller falls back to the analytic cost model.
 """
 from __future__ import annotations
@@ -38,7 +38,8 @@ from repro.testing import faults as _faults
 
 from .codegen import _override_estimate, emit_group, emit_pattern, \
     pattern_emittable
-from .cost_model import BLOCK_ROWS, STREAM_TILES, Hardware, V5E
+from .cost_model import BLOCK_ROWS, STREAM_TILES, Hardware, V5E, \
+    legal_block_rows, row_tile
 from .ir import Graph, OpKind
 from .plan_cache import override_fp
 
@@ -47,25 +48,24 @@ ENV_AUTOTUNE = "REPRO_AUTOTUNE"
 
 
 def autotune_available() -> bool:
-    """Measured tuning is meaningful only on a real accelerator."""
+    """Measured tuning is meaningful only for compiled kernels: timing
+    the Pallas interpreter says nothing about the chip."""
     if os.environ.get(ENV_AUTOTUNE, "").lower() == "force":
         return True
-    try:
-        import jax
+    from repro import kernels
 
-        return jax.default_backend() not in ("cpu",)
-    except Exception:  # noqa: BLE001 - no backend -> analytic fallback
-        return False
+    return not kernels.interpret_mode()
 
 
-def _candidate_overrides(info) -> list[dict]:
+def _candidate_overrides(info, tile: int) -> list[dict]:
+    """The analytic schedule space, rounded onto the sublane ``tile``."""
     cands: list[dict] = []
-    for br in BLOCK_ROWS:
+    for br in sorted({legal_block_rows(br, info.R, tile)
+                      for br in BLOCK_ROWS}):
         cands.append({"schedule": "onepass", "block_rows": br})
-        if br >= info.R:
-            break
     for br, bc in STREAM_TILES:
-        cands.append({"schedule": "streaming", "block_rows": br,
+        cands.append({"schedule": "streaming",
+                      "block_rows": legal_block_rows(br, info.R, tile),
                       "block_cols": bc})
     return cands
 
@@ -165,7 +165,7 @@ def _time_callable(fn, args, *, warmup: int = 1, iters: int = 3,
 _TIME_CALLABLE_DEFAULT = _time_callable
 
 
-def _emit_candidates(info, emit,
+def _emit_candidates(info, emit, tile: int,
                      extra: list[dict] | None = None
                      ) -> list[tuple[dict, object]]:
     """Emit every analytic-space candidate (plus ``extra`` recompute
@@ -178,7 +178,7 @@ def _emit_candidates(info, emit,
     schedule, its (clamped) block rows, and its stage-vs-recompute
     choice."""
     cands: list[tuple[dict, object]] = []
-    for over in _candidate_overrides(info) + list(extra or ()):
+    for over in _candidate_overrides(info, tile) + list(extra or ()):
         try:
             em = emit(over)
         except Exception:  # noqa: BLE001 - a failing candidate just loses
@@ -436,9 +436,9 @@ def _measure_batched(cands, graph: Graph, rng) -> dict | None:
     return best_over
 
 
-def _sweep(info, emit, graph: Graph, *, batch_compile: bool,
+def _sweep(info, emit, graph: Graph, tile: int, *, batch_compile: bool,
            extra_overrides: list[dict] | None = None) -> dict | None:
-    cands = _emit_candidates(info, emit, extra=extra_overrides)
+    cands = _emit_candidates(info, emit, tile, extra=extra_overrides)
     if not cands:
         return None
     rng = np.random.default_rng(0)
@@ -448,7 +448,7 @@ def _sweep(info, emit, graph: Graph, *, batch_compile: bool,
 
 
 def tune_pattern(graph: Graph, pattern: frozenset[int], *,
-                 hw: Hardware = V5E, interpret: bool = True,
+                 hw: Hardware = V5E,
                  ctx=None, batch_compile: bool = True) -> dict | None:
     """Measure candidate schedules for one pattern; None -> keep analytic.
 
@@ -466,16 +466,17 @@ def tune_pattern(graph: Graph, pattern: frozenset[int], *,
         return None
 
     def emit(over):
-        return emit_pattern(graph, pattern, hw=hw, interpret=interpret,
-                            ctx=ctx, schedule_override=over)
+        return emit_pattern(graph, pattern, hw=hw, ctx=ctx,
+                            schedule_override=over)
 
-    return _sweep(info, emit, graph, batch_compile=batch_compile,
+    return _sweep(info, emit, graph, row_tile(graph, pattern, ctx),
+                  batch_compile=batch_compile,
                   extra_overrides=_recompute_overrides(graph, pattern,
                                                        info, ctx, hw))
 
 
 def tune_group(graph: Graph, parts, *, hw: Hardware = V5E,
-               interpret: bool = True, ctx=None,
+               ctx=None,
                batch_compile: bool = True) -> dict | None:
     """Measure candidate schedules for a stitch group's union megakernel.
 
@@ -499,10 +500,11 @@ def tune_group(graph: Graph, parts, *, hw: Hardware = V5E,
         return None
 
     def emit(over):
-        return emit_group(graph, parts, hw=hw, interpret=interpret,
-                          ctx=ctx, schedule_override=over)
+        return emit_group(graph, parts, hw=hw, ctx=ctx,
+                          schedule_override=over)
 
-    return _sweep(info, emit, graph, batch_compile=batch_compile,
+    return _sweep(info, emit, graph, row_tile(graph, union, ctx),
+                  batch_compile=batch_compile,
                   extra_overrides=_recompute_overrides(graph, union,
                                                        info, ctx, hw))
 
@@ -543,7 +545,7 @@ def _alt_schedule_override(graph, union, info, ctx, hw) -> dict | None:
     if alt is None or info is None:
         return None
     pick: tuple[dict, float] | None = None
-    for over in _candidate_overrides(info):
+    for over in _candidate_overrides(info, row_tile(graph, union, ctx)):
         if over["schedule"] != alt:
             continue
         est = _override_estimate(graph, union, info, over, hw, ctx=ctx)
@@ -661,7 +663,7 @@ def _branch_tkey(ci: int, assignment: dict) -> tuple:
 
 
 def _candidate_branches(graph: Graph, ci: int, groups, region, ext_ids,
-                        out_ids, ctx, hw, interpret: bool,
+                        out_ids, ctx, hw,
                         emit_cache: dict) -> list[_Branch]:
     """All (this partition, schedule-assignment) branches: the
     all-analytic assignment first, then one swap per stitched group
@@ -673,8 +675,8 @@ def _candidate_branches(graph: Graph, ci: int, groups, region, ext_ids,
         anchors = tuple(getattr(grp, "anchors", ()))
         key = (grp.members, anchors, override_fp(over))
         if key not in emit_cache:
-            em = emit_group(graph, grp.parts, hw=hw, interpret=interpret,
-                            ctx=ctx, schedule_override=over or None,
+            em = emit_group(graph, grp.parts, hw=hw, ctx=ctx,
+                            schedule_override=over or None,
                             anchors=anchors)
             if anchors:
                 pass  # anchored emission has one fixed scheme
@@ -734,7 +736,7 @@ def _candidate_branches(graph: Graph, ci: int, groups, region, ext_ids,
 
 
 def tune_partitions(graph: Graph, candidates, *, hw: Hardware = V5E,
-                    interpret: bool = True, ctx=None,
+                    ctx=None,
                     batch_compile: bool = True
                     ) -> PartitionTuneResult | None:
     """Race candidate partitions (each a list of ``StitchGroup``) on
@@ -776,7 +778,7 @@ def tune_partitions(graph: Graph, candidates, *, hw: Hardware = V5E,
     for ci, groups in enumerate(candidates):
         branches.extend(_candidate_branches(
             graph, ci, groups, region, ext_ids, out_ids, ctx, hw,
-            interpret, emit_cache))
+            emit_cache))
     if not branches:
         return None
     if len(branches) > MAX_PARTITION_BRANCHES:

@@ -25,7 +25,10 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 
-from .cost_model import Hardware, KernelEstimate, V5E, best_estimate
+from repro import kernels
+
+from .cost_model import Hardware, KernelEstimate, V5E, best_estimate, \
+    legal_block_rows, row_tile
 from .ir import Graph, OpKind
 from .memory_planner import plan_scratch
 from .rowspec import Role, RowInfo, analyze
@@ -566,7 +569,7 @@ def _alias_map_streaming(graph: Graph, info: RowInfo, ext_ids: list[int],
 
 
 def emit_pattern(graph: Graph, pattern: frozenset[int], *,
-                 hw: Hardware = V5E, interpret: bool = True,
+                 hw: Hardware = V5E,
                  force_packed: bool = False, ctx=None,
                  schedule_override: dict | None = None,
                  donate_into: "frozenset[int] | None" = None) -> Emitted:
@@ -608,8 +611,7 @@ def emit_pattern(graph: Graph, pattern: frozenset[int], *,
         if est.schedule == "onepass":
             aliases = _alias_map(graph, info, ext_ids, out_ids, donate_into)
             fn = _emit_pallas(graph, pattern, info, est.block_rows, ext_ids,
-                              out_ids, interpret=interpret,
-                              io_aliases=aliases, recompute=rec)
+                              out_ids, io_aliases=aliases, recompute=rec)
             return Emitted(fn, "pallas", est, ext_ids, out_ids,
                            scratch.total_bytes, scratch.naive_bytes,
                            parts=(tuple(sorted(pattern)),),
@@ -626,7 +628,6 @@ def emit_pattern(graph: Graph, pattern: frozenset[int], *,
                                            est.block_cols or 2048, phases)
             fn = _emit_pallas_streaming(graph, pattern, info,
                                         est.block_rows, ext_ids, out_ids,
-                                        interpret=interpret,
                                         block_cols=est.block_cols or 2048,
                                         io_aliases=aliases)
             return Emitted(fn, "pallas", est, ext_ids, out_ids,
@@ -643,7 +644,7 @@ def emit_pattern(graph: Graph, pattern: frozenset[int], *,
 
 
 def emit_group(graph: Graph, parts, *, hw: Hardware = V5E,
-               interpret: bool = True, ctx=None,
+               ctx=None,
                schedule_override: dict | None = None,
                donate_into: "frozenset[int] | None" = None,
                anchors: tuple = ()) -> Emitted:
@@ -666,10 +667,10 @@ def emit_group(graph: Graph, parts, *, hw: Hardware = V5E,
     union = frozenset(n for p in parts for n in p)
     if anchors:
         return _emit_anchored(graph, parts, tuple(sorted(anchors)),
-                              hw=hw, interpret=interpret, ctx=ctx)
+                              hw=hw, ctx=ctx)
     if len(parts) == 1:
-        return emit_pattern(graph, union, hw=hw, interpret=interpret,
-                            ctx=ctx, schedule_override=schedule_override,
+        return emit_pattern(graph, union, hw=hw, ctx=ctx,
+                            schedule_override=schedule_override,
                             donate_into=donate_into)
 
     info = ctx.info(union) if ctx is not None else analyze(graph, union)
@@ -718,7 +719,7 @@ def emit_group(graph: Graph, parts, *, hw: Hardware = V5E,
                                          info.C)
             n_staged = len(staged[1])
             fn = _emit_pallas(graph, union, info, est.block_rows, ext_ids,
-                              out_ids, interpret=interpret, order=order,
+                              out_ids, order=order,
                               staged=staged, io_aliases=aliases,
                               recompute=rec)
         else:
@@ -730,7 +731,6 @@ def emit_group(graph: Graph, parts, *, hw: Hardware = V5E,
                                            est.block_cols or 2048, phases)
             fn = _emit_pallas_streaming(graph, union, info, est.block_rows,
                                         ext_ids, out_ids,
-                                        interpret=interpret,
                                         block_cols=est.block_cols or 2048,
                                         order=order, io_aliases=aliases)
         return Emitted(fn, "pallas", est, ext_ids, out_ids,
@@ -763,7 +763,7 @@ _REDUCE_COMBINE = {
 def _emit_pallas_streaming(graph: Graph, pattern: frozenset[int],
                            info: RowInfo, block_rows: int,
                            ext_ids: list[int], out_ids: list[int], *,
-                           interpret: bool, block_cols: int = 2048,
+                           block_cols: int = 2048,
                            order: list[int] | None = None,
                            io_aliases: dict[int, int] | None = None
                            ) -> Callable:
@@ -780,7 +780,7 @@ def _emit_pallas_streaming(graph: Graph, pattern: frozenset[int],
     from .cost_model import reduce_levels
 
     R, C = info.R, info.C
-    br = max(1, min(block_rows, R))
+    br = legal_block_rows(block_rows, R, row_tile(graph, pattern))
     bc = min(block_cols, C)
     Rp = math.ceil(R / br) * br
     NC = math.ceil(C / bc)
@@ -908,7 +908,7 @@ def _emit_pallas_streaming(graph: Graph, pattern: frozenset[int],
         out_shape=out_shapes if len(out_shapes) > 1 else out_shapes[0],
         scratch_shapes=[pltpu.VMEM((br, 1), jnp.float32) for _ in reduces],
         input_output_aliases=dict(io_aliases or {}),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )
 
     out_orig = {o: graph.node(o).spec.shape for o in out_ids}
@@ -972,12 +972,12 @@ def _emit_packed(graph: Graph, pattern: frozenset[int],
 
 def _emit_pallas(graph: Graph, pattern: frozenset[int], info: RowInfo,
                  block_rows: int, ext_ids: list[int], out_ids: list[int],
-                 *, interpret: bool, order: list[int] | None = None,
+                 *, order: list[int] | None = None,
                  staged: tuple | None = None,
                  io_aliases: dict[int, int] | None = None,
                  recompute: frozenset[int] = frozenset()) -> Callable:
     R, C = info.R, info.C
-    br = max(1, min(block_rows, R))
+    br = legal_block_rows(block_rows, R, row_tile(graph, pattern))
     Rp = math.ceil(R / br) * br
     members = order if order is not None else sorted(pattern)
     roles = info.roles
@@ -1096,7 +1096,7 @@ def _emit_pallas(graph: Graph, pattern: frozenset[int], info: RowInfo,
         scratch_shapes=[pltpu.VMEM(shape, dtype)
                         for shape, dtype in scratch_buffers],
         input_output_aliases=dict(io_aliases or {}),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )
 
     ext_shapes = {i: graph.node(i).spec.shape for i in ext_ids}
@@ -1205,7 +1205,7 @@ def _anchored_estimate(graph: Graph, union: frozenset[int],
 
 
 def _emit_anchored(graph: Graph, parts, anchors, *, hw: Hardware = V5E,
-                   interpret: bool = True, ctx=None) -> Emitted:
+                   ctx=None) -> Emitted:
     """Compile an anchored stitch group into ONE compute kernel whose
     grid also runs the folded prologue/epilogue chains.  Raises
     ``AnchorEmitError`` on any structural mismatch -- the dispatch
@@ -1229,7 +1229,7 @@ def _emit_anchored(graph: Graph, parts, anchors, *, hw: Hardware = V5E,
         if m is None:
             raise AnchorEmitError("anchored matmul: structure mismatch")
         return _emit_anchored_matmul(graph, parts, m, ext_ids, out_ids,
-                                     hbm_saved, hw=hw, interpret=interpret)
+                                     hbm_saved, hw=hw)
     if len(anchors) == 2:
         m = _match_attention_anchors(graph, union, anchors)
         if m is None:
@@ -1237,14 +1237,12 @@ def _emit_anchored(graph: Graph, parts, anchors, *, hw: Hardware = V5E,
         if list(out_ids) != [m["pv"]]:
             raise AnchorEmitError("anchored attention: escaping chain value")
         return _emit_anchored_attention(graph, parts, m, ext_ids,
-                                        hbm_saved, hw=hw,
-                                        interpret=interpret)
+                                        hbm_saved, hw=hw)
     raise AnchorEmitError(f"unsupported anchor count {len(anchors)}")
 
 
 def _emit_anchored_matmul(graph: Graph, parts, m: dict, ext_ids, out_ids,
-                          hbm_saved: int, *, hw: Hardware,
-                          interpret: bool) -> Emitted:
+                          hbm_saved: int, *, hw: Hardware) -> Emitted:
     from ..kernels.matmul import DEFAULT_BLOCK_M, matmul_fused
 
     a, lhs_id, rhs_id = m["a"], m["lhs"], m["rhs"]
@@ -1302,7 +1300,7 @@ def _emit_anchored_matmul(graph: Graph, parts, m: dict, ext_ids, out_ids,
             M=M, K=K, N=N, pro_roles=pro_roles, epi_roles=epi_roles,
             out_roles=out_roles, out_dtypes=out_dtypes,
             anchor_dtype=anchor_dtype, prologue=prologue,
-            epilogue=epilogue, block_m=bm, interpret=interpret)
+            epilogue=epilogue, block_m=bm)
         return tuple(o.reshape(out_shapes[oid])
                      for o, oid in zip(outs, out_ids))
 
@@ -1315,8 +1313,7 @@ def _emit_anchored_matmul(graph: Graph, parts, m: dict, ext_ids, out_ids,
 
 
 def _emit_anchored_attention(graph: Graph, parts, m: dict, ext_ids,
-                             hbm_saved: int, *, hw: Hardware,
-                             interpret: bool) -> Emitted:
+                             hbm_saved: int, *, hw: Hardware) -> Emitted:
     from ..kernels.flash_attention import DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, \
         flash_attention
 
@@ -1379,7 +1376,7 @@ def _emit_anchored_attention(graph: Graph, parts, m: dict, ext_ids,
         out = flash_attention(
             get(q_id), get(k_id), get(v_id), causal=False, scale=1.0,
             score_mod=score_mod if score else None,
-            score_args=sargs, interpret=interpret)
+            score_args=sargs)
         return (out.astype(out_spec.dtype).reshape(out_spec.shape),)
 
     union = frozenset(n for p in parts for n in p)

@@ -27,6 +27,8 @@ import math
 import os
 from dataclasses import dataclass
 
+from repro.kernels.matmul import DEFAULT_BLOCK_M
+
 from .classify import vpu_cost
 from .ir import Graph, OpKind
 from .memory_planner import ReusePlan, plan_reuse, plan_scratch, \
@@ -81,12 +83,71 @@ class Hardware:
 
 V5E = Hardware()
 
+#: Hardware models keyed by ``device_kind`` as JAX reports it.
+HARDWARE = {"TPU v5 lite": V5E}
+
+
+def hardware() -> Hardware:
+    """The hardware model of the default backend's chip.
+
+    A TPU whose ``device_kind`` is missing from ``HARDWARE`` is an error,
+    not a default: its VMEM and bandwidth would be guessed.  Off the TPU
+    (the CPU test host) plans are made for v5e.
+    """
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return V5E
+    try:
+        return HARDWARE[dev.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware model for TPU kind {dev.device_kind!r}; known: "
+            f"{sorted(HARDWARE)}") from None
+
+
 #: Block-row candidates the codegen enumerates (launch-dimension analogue).
-BLOCK_ROWS = (1, 8, 16, 32, 64, 128, 256)
+#: Each is rounded up to the blocks' sublane tile (``legal_block_rows``).
+BLOCK_ROWS = (8, 16, 32, 64, 128, 256)
 
 
 def _pad(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+def sublane_rows(graph: Graph, ids) -> int:
+    """Rows of one native TPU tile for the given nodes' dtypes: 8 for
+    4-byte, 16 for 2-byte, 32 for 1-byte types (the largest wins).
+    Constants (embedded in the kernel) and booleans (masks that never
+    become a block) do not count."""
+    tile = 8
+    for i in ids:
+        node = graph.node(i)
+        if node.kind is OpKind.CONST or node.spec.dtype == "bool":
+            continue
+        tile = max(tile, 32 // min(4, node.spec.itemsize))
+    return tile
+
+
+def legal_block_rows(block_rows: int, R: int, tile: int) -> int:
+    """The row-block size the chip's compiler accepts for ``block_rows``:
+    rounded up to a multiple of the sublane ``tile``, or all ``R`` rows
+    when that is fewer (a block may only be smaller than a tile when it
+    spans the whole row axis)."""
+    return min(_pad(max(1, block_rows), tile), R)
+
+
+def row_tile(graph: Graph, pattern: frozenset[int], ctx=None) -> int:
+    """``sublane_rows`` over every value a row kernel for ``pattern``
+    holds in a block or a staged scratch buffer."""
+    if ctx is not None:
+        b = ctx.bounds(pattern)
+        ext_in, outs = b.inputs, b.outputs
+    else:
+        ext_in = graph.pattern_inputs(pattern)
+        outs = graph.pattern_outputs(pattern)
+    return sublane_rows(graph, [*pattern, *ext_in, *outs])
 
 
 # ---------------------------------------------------------------------------
@@ -163,8 +224,6 @@ def estimate_onepass(graph: Graph, pattern: frozenset[int], info: RowInfo,
     """
     R, C = info.R, info.C
     Cp = _pad(C, 128)
-    br = min(block_rows, R)
-    n_steps = math.ceil(R / br)
     rec = frozenset(recompute) & pattern if recompute else frozenset()
 
     if ctx is not None:
@@ -173,6 +232,8 @@ def estimate_onepass(graph: Graph, pattern: frozenset[int], info: RowInfo,
     else:
         ext_in = graph.pattern_inputs(pattern)
         outs = graph.pattern_outputs(pattern)
+    br = legal_block_rows(block_rows, R, row_tile(graph, pattern, ctx))
+    n_steps = math.ceil(R / br)
 
     step_hbm, col_bytes = _onepass_fixed_bytes(graph, info, br, Cp,
                                                ext_in, outs)
@@ -291,13 +352,13 @@ def reuse_plan(graph: Graph, pattern: frozenset[int], info: RowInfo,
         return None
     R, C = info.R, info.C
     Cp = _pad(C, 128)
-    br = min(max(1, block_rows), R)
     if ctx is not None:
         b = ctx.bounds(pattern)
         ext_in, outs = b.inputs, b.outputs
     else:
         ext_in = graph.pattern_inputs(pattern)
         outs = graph.pattern_outputs(pattern)
+    br = legal_block_rows(block_rows, R, row_tile(graph, pattern, ctx))
 
     # legal flip targets with their cone prices (the greedy's per-round
     # evaluation-order tie-break: cheaper cones first)
@@ -343,18 +404,17 @@ def estimate_streaming(graph: Graph, pattern: frozenset[int], info: RowInfo,
     FULL inputs are re-read (and low-level nodes re-computed) once per
     phase -- the reuse/recompute trade of paper §2.3, priced here."""
     R, C = info.R, info.C
-    br = max(1, min(block_rows, R))
-    bc = max(128, min(block_cols, _pad(C, 128)))
-    phases = max(reduce_levels(graph, pattern).values(), default=0) + 1
-    n_col_tiles = math.ceil(C / bc)
-    n_steps = math.ceil(R / br) * phases * n_col_tiles
-
     if ctx is not None:
         b = ctx.bounds(pattern)
         ext_in, outs = b.inputs, b.outputs
     else:
         ext_in = graph.pattern_inputs(pattern)
         outs = graph.pattern_outputs(pattern)
+    br = legal_block_rows(block_rows, R, row_tile(graph, pattern, ctx))
+    bc = max(128, min(block_cols, _pad(C, 128)))
+    phases = max(reduce_levels(graph, pattern).values(), default=0) + 1
+    n_col_tiles = math.ceil(C / bc)
+    n_steps = math.ceil(R / br) * phases * n_col_tiles
     full_in = sum(br * bc * graph.node(i).spec.itemsize for i in ext_in
                   if info.roles.get(i) is Role.FULL)
     other_in = sum(graph.node(i).spec.itemsize * br for i in ext_in
@@ -439,8 +499,12 @@ def best_estimate(graph: Graph, pattern: frozenset[int],
     info = ctx.info(pattern) if ctx is not None else analyze(graph, pattern)
     if info is not None:
         allow_recompute = recompute_enabled()
+        tried: set[int] = set()
         for br in BLOCK_ROWS:
             est = estimate_onepass(graph, pattern, info, br, hw, ctx=ctx)
+            if est.block_rows in tried:
+                continue  # rounded onto a tile already priced
+            tried.add(est.block_rows)
             if est.feasible:
                 cands.append(est)
             elif allow_recompute:
@@ -451,7 +515,7 @@ def best_estimate(graph: Graph, pattern: frozenset[int],
                                            ctx=ctx, recompute=rp.recompute)
                     if est.feasible:
                         cands.append(est)
-            if br >= info.R:
+            if est.block_rows >= info.R:
                 break
         # streaming (warp-composition analogue) for long rows
         for br, bc in STREAM_TILES:
@@ -565,10 +629,9 @@ class AnchorGain:
     folded parts) stops round-tripping HBM -- one store plus one load
     each.  ``latency_gain_s`` adds the launches saved by collapsing the
     parts and the anchor's own dispatch into one ``pallas_call``.
-    ``vmem_bytes`` is the rough per-step working set of the anchored
-    kernel's grid (accumulator tile + resident operand panels); a group
-    whose working set blows the VMEM budget is infeasible and must stay
-    on the memory-only plan.
+    ``vmem_bytes`` is the per-step VMEM the anchored kernel's grid
+    allocates (``_anchor_vmem``); a group whose allocation exceeds the
+    chip's scoped VMEM is infeasible and stays on the memory-only plan.
     """
 
     latency_gain_s: float
@@ -606,29 +669,52 @@ def anchor_interface_bytes(graph: Graph, anchors, parts) -> int:
     return saved
 
 
-def _anchor_vmem(graph: Graph, anchors, hw: Hardware) -> int:
-    """Per-grid-step working set of the anchored kernel (rough)."""
-    total = 0
-    for a in anchors:
-        node = graph.node(a)
-        if node.prim != "dot_general" or len(node.inputs) < 2:
-            # attention-call prims / conv: assume flash-style 128-blocks
-            total += 4 * 128 * 128 * 4
-            continue
-        lhs = graph.node(node.inputs[0]).spec
-        rhs = graph.node(node.inputs[1]).spec
-        K = lhs.shape[-1] if lhs.shape else 1
-        N = rhs.shape[-1] if rhs.shape else 1
-        bm = 128
-        if len(anchors) > 1:
-            # attention pair (QK + PV): flash blocks, panels never whole
-            total += bm * (K + N) * 4 + bm * bm * 4
-        else:
-            # matmul: lhs tile (bm, K) + resident rhs panel (K, N)
-            # + f32 accumulator tile (bm, N)
-            total += bm * K * lhs.itemsize + K * N * rhs.itemsize \
-                + bm * N * 4
-    return total
+def _block_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of a (rows, cols) block, padded to whole native tiles
+    (sublanes by dtype width, 128 lanes)."""
+    return _pad(rows, 32 // min(4, itemsize)) * _pad(cols, 128) * itemsize
+
+
+def _anchor_vmem(graph: Graph, anchors, parts) -> int:
+    """VMEM the chip's compiler allocates per grid step for the anchored
+    kernel.
+
+    A matmul anchor keeps Pallas's pipeline: every operand and output
+    block that moves with the M grid is double-buffered, the (K, N)
+    weight panel (its block never moves) is held once, and the f32
+    accumulator tile is one more (bm, N) buffer.  Blocks pad to whole
+    tiles, so a (bm, 1) per-row operand costs (bm, 128).  This is the
+    sum the compiler checks against its scoped-VMEM limit.
+    """
+    if len(anchors) != 1 or graph.node(anchors[0]).prim != "dot_general":
+        # attention pair (QK + PV): flash-style 128-blocks
+        return 4 * 128 * 128 * 4
+    a = anchors[0]
+    lhs_id, rhs_id = graph.node(a).inputs[:2]
+    lhs, rhs = graph.node(lhs_id).spec, graph.node(rhs_id).spec
+    K = lhs.shape[-1] if lhs.shape else 1
+    N = rhs.shape[-1] if rhs.shape else 1
+    M = max(1, lhs.size // max(1, K))
+    bm = min(DEFAULT_BLOCK_M, M)
+    union = frozenset({a}).union(*parts)
+
+    def block(nid: int) -> int:
+        spec = graph.node(nid).spec
+        if spec.size == M * K:
+            rows, cols = bm, K
+        elif spec.size == M * N:
+            rows, cols = bm, N
+        elif spec.size == M:
+            rows, cols = bm, 1
+        else:                       # per-column vector or scalar
+            rows, cols = 1, max(1, spec.size)
+        return _block_bytes(rows, cols, spec.itemsize)
+
+    moving = [i for i in graph.pattern_inputs(union)
+              if i != rhs_id and graph.node(i).kind is not OpKind.CONST]
+    moving += graph.pattern_outputs(union)
+    return (2 * sum(block(i) for i in moving)
+            + _block_bytes(K, N, rhs.itemsize) + _block_bytes(bm, N, 4))
 
 
 def anchor_gain(graph: Graph, anchors, parts, hw: Hardware = V5E,
@@ -643,12 +729,12 @@ def anchor_gain(graph: Graph, anchors, parts, hw: Hardware = V5E,
     saved = anchor_interface_bytes(graph, anchors, parts)
     launches_saved = max(0, len(parts) + len(anchors) - 1) \
         * (hw.launch_s + hw.hbm_latency_s)
-    vmem = _anchor_vmem(graph, anchors, hw)
+    vmem = _anchor_vmem(graph, anchors, parts)
     return AnchorGain(
         latency_gain_s=saved / hw.hbm_bw + launches_saved,
         hbm_bytes_saved=saved,
         vmem_bytes=vmem,
-        feasible=vmem <= hw.vmem_budget,
+        feasible=vmem <= hw.vmem_bytes,
     )
 
 

@@ -213,6 +213,54 @@ class Graph:
             a |= anc[nid]
         return not (d & a & ~pmask)
 
+    def group_cycle(self, groups: Sequence[frozenset[int]]) -> list[int] | None:
+        """Indices of the groups on one dependence cycle of the graph
+        with every group collapsed to one kernel, or None when such a
+        schedule exists.
+
+        Each group may be convex alone and the set still be cyclic: A
+        feeds B through one member while B feeds A through another, so
+        neither kernel can run first.
+        """
+        G = len(groups)
+        owner = {n: gi for gi, g in enumerate(groups) for n in g}
+
+        def macro(nid: int) -> int:
+            return owner.get(nid, G + nid)
+
+        deps: dict[int, set[int]] = {}
+        for nid, node in self.nodes.items():
+            m = macro(nid)
+            d = deps.setdefault(m, set())
+            for i in node.inputs:
+                if macro(i) != m:
+                    d.add(macro(i))
+        users: dict[int, list[int]] = {}
+        for m, d in deps.items():
+            for x in d:
+                users.setdefault(x, []).append(m)
+        waiting = {m: len(d) for m, d in deps.items()}
+        ready = [m for m, n in waiting.items() if n == 0]
+        while ready:
+            m = ready.pop()
+            for u in users.get(m, ()):
+                waiting[u] -= 1
+                if waiting[u] == 0:
+                    ready.append(u)
+        stuck = {m for m, n in waiting.items() if n > 0}
+        if not stuck:
+            return None
+        # every stuck macro-node waits on a stuck one: walk back until a
+        # node repeats; the walk's tail from that node is a cycle
+        path: list[int] = []
+        seen: dict[int, int] = {}
+        m = min(stuck)
+        while m not in seen:
+            seen[m] = len(path)
+            path.append(m)
+            m = min(x for x in deps[m] if x in stuck)
+        return sorted(x for x in path[seen[m]:] if x < G)
+
     def is_convex_bfs(self, pattern: frozenset[int]) -> bool:
         """Reference BFS convexity check (the pre-bitset implementation).
 

@@ -46,6 +46,8 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Any
 
+import jax
+
 from repro.runtime.guard import GuardError
 
 
@@ -154,17 +156,15 @@ class ShardCtx:
     # -- dispatch ------------------------------------------------------------
     def wrap(self, fn):
         """``shard_map`` ``fn`` (a flat per-shard callable) over the
-        mesh.  ``check_rep=False``: the fusion schedule replays pallas
+        mesh.  ``check_vma=False``: the fusion schedule replays pallas
         calls and per-node binds whose replication the checker cannot
         see through."""
-        from jax.experimental.shard_map import shard_map
-
         if not self.explicit:
             raise ValueError("ambient ShardCtx cannot wrap a dispatch")
-        return shard_map(fn, mesh=self.mesh,
-                         in_specs=tuple(self.in_specs),
-                         out_specs=tuple(self.out_specs),
-                         check_rep=False)
+        return jax.shard_map(fn, mesh=self.mesh,
+                             in_specs=tuple(self.in_specs),
+                             out_specs=tuple(self.out_specs),
+                             check_vma=False)
 
     # -- cache signature -----------------------------------------------------
     def signature_items(self) -> tuple:

@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import functools
 import hashlib
+import heapq
 import threading
 import time
 from dataclasses import dataclass, field
@@ -56,13 +57,14 @@ from repro.testing import faults as _faults
 
 from .codegen import Emitted, emit_group, emit_pattern
 from .costctx import CostContext
-from .cost_model import Hardware, KernelEstimate, V5E, anchor_enabled
+from .cost_model import Hardware, KernelEstimate, anchor_enabled, \
+    hardware
 from .ir import FUSIBLE_KINDS, FusionPlan, Graph, OpKind, StitchGroup
 from .plan_cache import PlanCache, entry_format_for, \
     entry_partition_source, entry_to_groups, entry_to_plan, \
     graph_signature, override_fp, plan_to_entry
 from .planner import PlanStats, make_plan, plan_stats
-from .stitcher import absorb_anchors, search_groups
+from .stitcher import absorb_anchors, break_cycles, search_groups
 from .tracer import bind_node, trace, trace_with_shape
 
 
@@ -314,53 +316,52 @@ class _Compiled:
 
 
 def _build_schedule(graph: Graph, emitted: list[Emitted]) -> list[tuple[str, Any]]:
-    """Topologically order macro-nodes (groups + leftover singletons)."""
+    """Topologically order macro-nodes (groups + leftover singletons).
+
+    A group runs once every value it reads from outside exists, so it
+    can land after a bare node with a smaller id; that node's consumers
+    must wait for it in turn.  Kahn's algorithm over the macro-node DAG
+    (acyclic because groups are convex) orders both, earliest original
+    position first among the ready ones.
+    """
     member_of: dict[int, int] = {}
     for idx, em in enumerate(emitted):
         for nid in em._members:  # type: ignore[attr-defined]
             member_of[nid] = idx
+    inputs = set(graph.inputs)
 
-    done: set[int] = set(graph.inputs)
-    emitted_done = [False] * len(emitted)
-    schedule: list[tuple[str, Any]] = []
-    for nid in graph.topo_order():
-        if nid in done:
-            continue
+    def macro(nid: int) -> tuple:
         idx = member_of.get(nid)
-        if idx is None:
-            schedule.append(("node", nid))
-            done.add(nid)
+        return ("pattern", idx) if idx is not None else ("node", nid)
+
+    first: dict[tuple, int] = {}
+    deps: dict[tuple, set] = {}
+    users: dict[tuple, list] = {}
+    for nid in graph.topo_order():
+        if nid in inputs:
             continue
-        if emitted_done[idx]:
-            continue
-        em = emitted[idx]
-        if all(e in done for e in em.ext_ids):
-            schedule.append(("pattern", em))
-            done.update(em._members)  # type: ignore[attr-defined]
-            emitted_done[idx] = True
-        else:
-            # defer: emit the node standalone is illegal (it's a member);
-            # instead postpone -- reinsert pattern when deps are ready.
-            # Because patterns are convex, walking ids in topo order and
-            # retrying at the *last* member always succeeds.
-            continue
-    # second sweep for deferred patterns (rare: ext produced between
-    # members) -- deferred groups may feed each other, so drain them in
-    # dependency order, not list order
-    remaining = [i for i, d in enumerate(emitted_done) if not d]
-    while remaining:
-        progressed = False
-        for idx in list(remaining):
-            em = emitted[idx]
-            if all(e in done for e in em.ext_ids):
-                schedule.append(("pattern", em))
-                done.update(em._members)  # type: ignore[attr-defined]
-                remaining.remove(idx)
-                progressed = True
-        if not progressed:  # unreachable for convex plans; never hang
-            for idx in remaining:
-                schedule.append(("pattern", emitted[idx]))
-            break
+        m = macro(nid)
+        first.setdefault(m, nid)
+        mdeps = deps.setdefault(m, set())
+        for i in graph.node(nid).inputs:
+            mi = macro(i)
+            if i not in inputs and mi != m and mi not in mdeps:
+                mdeps.add(mi)
+                users.setdefault(mi, []).append(m)
+    waiting = {m: len(d) for m, d in deps.items()}
+    ready = [(first[m], m) for m, n in waiting.items() if n == 0]
+    heapq.heapify(ready)
+    schedule: list[tuple[str, Any]] = []
+    while ready:
+        _, m = heapq.heappop(ready)
+        kind, ref = m
+        schedule.append((kind, emitted[ref] if kind == "pattern" else ref))
+        for u in users.get(m, ()):
+            waiting[u] -= 1
+            if waiting[u] == 0:
+                heapq.heappush(ready, (first[u], u))
+    if len(schedule) != len(first):  # unreachable for convex plans
+        raise GuardError("fusion schedule has a dependence cycle")
     return schedule
 
 
@@ -527,8 +528,8 @@ class _RaceContext:
 
 
 class StitchedFunction:
-    def __init__(self, fn: Callable, *, hw: Hardware = V5E,
-                 interpret: bool = True, use_remote_fusion: bool = True,
+    def __init__(self, fn: Callable, *, hw: Hardware | None = None,
+                 use_remote_fusion: bool = True,
                  dispatch: str = "single", plan_cache: str | None = None,
                  autotune: bool = False, stitch_groups: bool = True,
                  donate: bool = False,
@@ -557,8 +558,9 @@ class StitchedFunction:
         self._shard = (ShardCtx.build(mesh, in_specs, out_specs)
                        if mesh is not None else None)
         self._fn = fn
-        self._hw = hw
-        self._interpret = interpret
+        #: the planning target: the default backend's chip unless a
+        #: caller models another one (tests shrink VMEM this way)
+        self._hw = hw if hw is not None else hardware()
         self._remote = use_remote_fusion
         self._dispatch = dispatch
         self._autotune = autotune
@@ -707,7 +709,6 @@ class StitchedFunction:
                         if hit is None:
                             over = tune_pattern(graph, pat.members,
                                                 hw=self._hw,
-                                                interpret=self._interpret,
                                                 ctx=ctx) or {}
                             tuned_by_struct[skey] = (over, members)
                         else:
@@ -806,7 +807,7 @@ class StitchedFunction:
 
                     res = tune_partitions(
                         graph, [c.groups for c in candidates],
-                        hw=self._hw, interpret=self._interpret, ctx=ctx)
+                        hw=self._hw, ctx=ctx)
                     if res is not None:
                         # commit the raced winner; its schedule *pins*
                         # are left to the per-group measured sweep below
@@ -868,6 +869,14 @@ class StitchedFunction:
         from .cost_model import shard_enabled
 
         explicit_shard = shard is not None and shard.explicit
+        # groups convex one by one can still deadlock as kernels (A feeds
+        # B through one member, B feeds A through another): split until
+        # the partition schedules
+        legal = break_cycles(graph, groups, ctx)
+        if legal != groups:
+            over_of = {g.parts: o for g, o in zip(groups, group_overrides)}
+            group_overrides = [dict(over_of.get(g.parts, {})) for g in legal]
+            groups = legal
         # kill switch: the compile completes (the graph, tree and the
         # shard_map-wrapped baseline are all still needed to answer
         # calls correctly on the mesh) but pins the baseline rung below
@@ -914,7 +923,6 @@ class StitchedFunction:
                                 if hit[0] is not None else None)
                     else:
                         over = tune_group(graph, grp.parts, hw=self._hw,
-                                          interpret=self._interpret,
                                           ctx=ctx)
                         group_tuned_by_struct[skey] = (over, members)
                     if over is None:
@@ -989,7 +997,7 @@ class StitchedFunction:
                 # replay as plain XLA schedule entries.
                 try:
                     ems = [emit_group(graph, tuple(sub), hw=self._hw,
-                                      interpret=self._interpret, ctx=ctx)
+                                      ctx=ctx)
                            for sub in grp.unanchored
                            if frozenset(x for p in sub for x in p)
                            - anchor_set]
@@ -1002,7 +1010,7 @@ class StitchedFunction:
             if parts and (anchor_set or len(parts) > 1):
                 try:
                     ems = [emit_group(graph, (part,), hw=self._hw,
-                                      interpret=self._interpret, ctx=ctx,
+                                      ctx=ctx,
                                       schedule_override=(
                                           dict(pat_over.get(frozenset(part),
                                                             {})) or None))
@@ -1013,8 +1021,7 @@ class StitchedFunction:
                     pass
             try:
                 ems = [emit_pattern(graph, frozenset(grp.members),
-                                    hw=self._hw, interpret=self._interpret,
-                                    force_packed=True, ctx=ctx)]
+                                    hw=self._hw, force_packed=True, ctx=ctx)]
                 fallbacks.append((gi, RUNG_BASELINE, reason))
                 return ems
             except Exception as exc2:  # noqa: BLE001 - last rung: the
@@ -1063,7 +1070,7 @@ class StitchedFunction:
                             raise EmitError(
                                 f"injected anchor_emit_fail on group {gi}")
                     em = emit_group(graph, grp.parts, hw=self._hw,
-                                    interpret=self._interpret, ctx=ctx,
+                                    ctx=ctx,
                                     schedule_override=over or None,
                                     donate_into=donate_into,
                                     anchors=grp.anchors)
@@ -1288,8 +1295,7 @@ class StitchedFunction:
         if len(rc.candidates) > 1:
             res = tune_partitions(rc.graph,
                                   [c.groups for c in rc.candidates],
-                                  hw=self._hw, interpret=self._interpret,
-                                  ctx=rc.ctx)
+                                  hw=self._hw, ctx=rc.ctx)
             if res is not None:
                 groups = rc.candidates[res.index].groups
                 partition_source = "measured"
@@ -1358,7 +1364,7 @@ class StitchedFunction:
         return compiled.report
 
 
-def stitched_jit(fn: Callable, *, hw: Hardware = V5E, interpret: bool = True,
+def stitched_jit(fn: Callable, *, hw: Hardware | None = None,
                  use_remote_fusion: bool = True,
                  differentiable: bool = False,
                  dispatch: str = "single",
@@ -1422,8 +1428,7 @@ def stitched_jit(fn: Callable, *, hw: Hardware = V5E, interpret: bool = True,
             "an explicit mesh (the backward re-trace is mesh-free)")
     # differentiable wrappers keep the primal inputs as VJP residuals, so
     # the forward must not donate them out from under the backward pass.
-    sf = StitchedFunction(fn, hw=hw, interpret=interpret,
-                          use_remote_fusion=use_remote_fusion,
+    sf = StitchedFunction(fn, hw=hw, use_remote_fusion=use_remote_fusion,
                           dispatch=dispatch, plan_cache=plan_cache,
                           autotune=autotune, stitch_groups=stitch_groups,
                           donate=donate and not differentiable,
@@ -1453,8 +1458,8 @@ def stitched_jit(fn: Callable, *, hw: Hardware = V5E, interpret: bool = True,
                 _, pullback = jax.vjp(fn, *primals)
                 return pullback(ct)
             bwd_cache[key] = StitchedFunction(
-                vjp_fn, hw=hw, interpret=interpret,
-                use_remote_fusion=use_remote_fusion, dispatch=dispatch,
+                vjp_fn, hw=hw, use_remote_fusion=use_remote_fusion,
+                dispatch=dispatch,
                 plan_cache=plan_cache, autotune=autotune,
                 stitch_groups=stitch_groups, canary=False)
         return bwd_cache[key](cts, *args)
@@ -1464,7 +1469,7 @@ def stitched_jit(fn: Callable, *, hw: Hardware = V5E, interpret: bool = True,
     return wrapped
 
 
-def fusion_report(fn: Callable, *example_args, hw: Hardware = V5E,
+def fusion_report(fn: Callable, *example_args, hw: Hardware | None = None,
                   **example_kwargs) -> StitchReport:
     """Plan ``fn`` on example inputs and return the plan statistics."""
     sf = stitched_jit(fn, hw=hw)

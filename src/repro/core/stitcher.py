@@ -534,6 +534,61 @@ def _absorb_leftovers(graph: Graph, groups: list[list[frozenset[int]]],
 # ---------------------------------------------------------------------------
 # compute-anchored absorption (fold groups into adjacent compute kernels)
 # ---------------------------------------------------------------------------
+def _components(graph: Graph, members: frozenset[int]) -> list[frozenset[int]]:
+    """Weakly connected components of ``members``' induced subgraph
+    (each convex when ``members`` is)."""
+    comps: list[frozenset[int]] = []
+    left = set(members)
+    while left:
+        stack = [min(left)]
+        comp = set()
+        while stack:
+            n = stack.pop()
+            if n not in left:
+                continue
+            left.discard(n)
+            comp.add(n)
+            stack.extend(graph.node(n).inputs)
+            stack.extend(graph.consumers(n))
+        comps.append(frozenset(comp))
+    return sorted(comps, key=min)
+
+
+def break_cycles(graph: Graph, groups: list[StitchGroup],
+                 ctx: CostContext | None = None) -> list[StitchGroup]:
+    """Split groups until every group can run as one kernel in some
+    order (``Graph.group_cycle`` finds none).
+
+    On each cycle the group spanning the widest id range is split one
+    step: an anchored group back into its unanchored composition, a
+    stitched group into its parts, a lone pattern into its connected
+    components -- and a connected one dissolved, its members left as
+    bare schedule nodes.  Each split is counted under
+    ``caps_hit["cycle_split"]``.
+    """
+    groups = list(groups)
+    while True:
+        cyc = graph.group_cycle([g.members for g in groups])
+        if cyc is None:
+            return groups
+        gi = max(cyc, key=lambda i: (max(groups[i].members)
+                                     - min(groups[i].members), -i))
+        g = groups[gi]
+        if g.anchors:
+            repl = [StitchGroup(tuple(sub)) for sub in g.unanchored
+                    if not (len(sub) == 1 and len(sub[0]) == 1
+                            and next(iter(sub[0])) in g.anchors)]
+        elif g.stitched:
+            repl = [StitchGroup((p,)) for p in g.parts]
+        else:
+            comps = _components(graph, g.members)
+            repl = ([StitchGroup((c,)) for c in comps if len(c) > 1]
+                    if len(comps) > 1 else [])
+        groups[gi:gi + 1] = repl
+        if ctx is not None:
+            ctx.note_cap("cycle_split")
+
+
 def absorb_anchors(graph: Graph, groups: list[list[frozenset[int]]],
                    ctx: CostContext) -> tuple[list[StitchGroup], int]:
     """Open anchored stitch groups around compute ops.

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
@@ -86,8 +88,7 @@ def _attn_kernel(*refs, scale: float, causal: bool, sq: int, skv: int,
 
 def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
                     block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K,
-                    score_mod=None, score_args=(),
-                    interpret: bool = True):
+                    score_mod=None, score_args=()):
     """q: [B, Hq, Sq, D]; k, v: [B, Hkv, Skv, D]; returns [B, Hq, Sq, D].
 
     ``score_mod`` (anchored stitching) rewrites the scaled score block
@@ -153,13 +154,13 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
             pltpu.VMEM((blk_q, 1), jnp.float32),   # running denom
             pltpu.VMEM((blk_q, D), jnp.float32),   # output accumulator
         ],
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(q, k, v, *padded_scores)
     return out[:, :, :Sq, :]
 
 
 def flash_decode(q, k_cache, v_cache, *, kv_len: int | None = None, scale=None,
-                 block_k: int = 512, interpret: bool = True):
+                 block_k: int = 512):
     """Decode-shape attention: q [B, Hq, D] against caches [B, Hkv, S, D].
 
     Uses the same streaming kernel with a single q row per block; the
@@ -174,6 +175,5 @@ def flash_decode(q, k_cache, v_cache, *, kv_len: int | None = None, scale=None,
         k_cache = k_cache[:, :, :eff, :]
         v_cache = v_cache[:, :, :eff, :]
     out = flash_attention(q[:, :, None, :], k_cache, v_cache, causal=False,
-                          scale=scale, block_q=1, block_k=min(block_k, eff),
-                          interpret=interpret)
+                          scale=scale, block_q=1, block_k=min(block_k, eff))
     return out[:, :, 0, :]

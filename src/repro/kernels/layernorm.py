@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
+
 
 def _ln_kernel(x_ref, g_ref, b_ref, y_ref, mean_ref, rstd_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)            # [br, C]
@@ -31,8 +33,7 @@ def _ln_kernel(x_ref, g_ref, b_ref, y_ref, mean_ref, rstd_ref, *, eps: float):
     rstd_ref[...] = rstd.astype(rstd_ref.dtype)
 
 
-def layernorm_fwd(x, gamma, beta, *, eps: float = 1e-6, block_rows: int = 128,
-                  interpret: bool = True):
+def layernorm_fwd(x, gamma, beta, *, eps: float = 1e-6, block_rows: int = 128):
     orig_shape = x.shape
     C = x.shape[-1]
     R = x.size // C
@@ -60,7 +61,7 @@ def layernorm_fwd(x, gamma, beta, *, eps: float = 1e-6, block_rows: int = 128,
             jax.ShapeDtypeStruct((Rp, 1), jnp.float32),
             jax.ShapeDtypeStruct((Rp, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(x2, gamma.reshape(1, C), beta.reshape(1, C))
     y = y[:R].reshape(orig_shape)
     return y, (mean[:R], rstd[:R])
@@ -90,7 +91,7 @@ def _ln_bwd_kernel(x_ref, g_ref, mean_ref, rstd_ref, dy_ref,
 
 
 def _ln_bwd(x2, gamma, mean, rstd, dy2, *, block_rows: int = 128,
-            interpret: bool = True, use_pallas: bool = True):
+            use_pallas: bool = True):
     """Analytic LN backward; Pallas kernel with jnp fallback."""
     if not use_pallas:
         xf = x2.astype(jnp.float32)
@@ -132,7 +133,7 @@ def _ln_bwd(x2, gamma, mean, rstd, dy2, *, block_rows: int = 128,
             jax.ShapeDtypeStruct((nb, C), jnp.float32),
             jax.ShapeDtypeStruct((nb, C), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(x2, gamma.reshape(1, C).astype(jnp.float32), mean, rstd, dy2)
     return dx[:R], jnp.sum(dgp, axis=0), jnp.sum(dbp, axis=0)
 

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
+
 #: Block-role strings shared with the emitter (kept as plain strings so
 #: this kernel does not import the core planner): how an operand folds
 #: into the kernel's 2D row view.
@@ -57,8 +59,7 @@ def matmul_fused(pro_args: Sequence, rhs, epi_args: Sequence, *,
                  acc_dtype=jnp.float32, anchor_dtype=None,
                  prologue: Callable | None = None,
                  epilogue: Callable | None = None,
-                 block_m: int = DEFAULT_BLOCK_M,
-                 interpret: bool = True):
+                 block_m: int = DEFAULT_BLOCK_M):
     """Run ``epilogue(prologue(pro_blocks) @ rhs, epi_blocks)`` tiled over M.
 
     ``prologue`` maps the prologue operands' blocks to the (bm, K) lhs
@@ -108,7 +109,7 @@ def matmul_fused(pro_args: Sequence, rhs, epi_args: Sequence, *,
         in_specs=in_specs,
         out_specs=out_specs if len(out_specs) > 1 else out_specs[0],
         out_shape=out_shapes if len(out_shapes) > 1 else out_shapes[0],
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )
 
     def pad2d(v, role: str, C: int):

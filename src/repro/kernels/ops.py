@@ -1,9 +1,10 @@
-"""jit'd public wrappers over the Pallas kernels with oracle fallback.
+"""Public wrappers over the Pallas kernels with oracle fallback.
 
 ``use_pallas=False`` (or ``fusion_mode="xla"`` at the model level) routes
-to the pure-jnp oracles in ``ref.py`` — that is the XLA-baseline execution
-mode of every benchmark.  Kernels run in ``interpret=True`` on CPU and
-compile to Mosaic on real TPUs.
+to the pure-jnp oracles in ``ref.py`` -- the XLA-baseline execution mode.
+With ``use_pallas=True`` every kernel compiles to Mosaic on a TPU backend
+and runs in the Pallas interpreter anywhere else
+(``repro.kernels.interpret_mode``).
 """
 from __future__ import annotations
 
@@ -19,13 +20,6 @@ from .layernorm import layernorm as _layernorm_kernel
 from .rmsnorm import rmsnorm as _rmsnorm_kernel
 from .softmax import softmax as _softmax_kernel
 from .ssd_scan import ssd_scan as _ssd_scan_kernel
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 def layernorm(x, gamma, beta, eps: float = 1e-6, *, use_pallas: bool = True):
@@ -49,8 +43,7 @@ def softmax(x, *, use_pallas: bool = True):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _attention_diff(q, k, v, causal, scale, block_q, block_k):
     return _flash_attention(q, k, v, causal=causal, scale=scale,
-                            block_q=block_q, block_k=block_k,
-                            interpret=not _on_tpu())
+                            block_q=block_q, block_k=block_k)
 
 
 def _attention_fwd(q, k, v, causal, scale, block_q, block_k):
@@ -89,7 +82,7 @@ def decode_attention(q, k_cache, v_cache, *, kv_len=None, scale=None,
                                     scale=scale)
     if use_pallas:
         return _flash_decode(q, k_cache, v_cache, kv_len=kv_len, scale=scale,
-                             block_k=block_k, interpret=not _on_tpu())
+                             block_k=block_k)
     if kv_len is not None and kv_len < k_cache.shape[2]:
         k_cache = k_cache[:, :, :kv_len, :]
         v_cache = v_cache[:, :, :kv_len, :]
@@ -98,8 +91,7 @@ def decode_attention(q, k_cache, v_cache, *, kv_len=None, scale=None,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _ssd_diff(x, dt, A, B, C, chunk):
-    return _ssd_scan_kernel(x, dt, A, B, C, chunk=chunk,
-                            interpret=not _on_tpu())
+    return _ssd_scan_kernel(x, dt, A, B, C, chunk=chunk)
 
 
 def _ssd_fwd(x, dt, A, B, C, chunk):

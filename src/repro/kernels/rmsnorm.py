@@ -8,6 +8,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
+
 
 def _rms_kernel(x_ref, g_ref, y_ref, rstd_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)
@@ -17,8 +19,7 @@ def _rms_kernel(x_ref, g_ref, y_ref, rstd_ref, *, eps: float):
     rstd_ref[...] = rstd.astype(rstd_ref.dtype)
 
 
-def rmsnorm_fwd(x, gamma, *, eps: float = 1e-6, block_rows: int = 128,
-                interpret: bool = True):
+def rmsnorm_fwd(x, gamma, *, eps: float = 1e-6, block_rows: int = 128):
     orig_shape = x.shape
     C = x.shape[-1]
     R = x.size // C
@@ -43,7 +44,7 @@ def rmsnorm_fwd(x, gamma, *, eps: float = 1e-6, block_rows: int = 128,
             jax.ShapeDtypeStruct((Rp, C), x.dtype),
             jax.ShapeDtypeStruct((Rp, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(x2, gamma.reshape(1, C))
     return y[:R].reshape(orig_shape), rstd[:R]
 

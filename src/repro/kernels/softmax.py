@@ -10,6 +10,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
+
 
 def _softmax_kernel(x_ref, y_ref):
     x = x_ref[...].astype(jnp.float32)
@@ -19,7 +21,7 @@ def _softmax_kernel(x_ref, y_ref):
     y_ref[...] = (e / s).astype(y_ref.dtype)
 
 
-def softmax_fwd(x, *, block_rows: int = 64, interpret: bool = True):
+def softmax_fwd(x, *, block_rows: int = 64):
     orig_shape = x.shape
     C = x.shape[-1]
     R = x.size // C
@@ -35,7 +37,7 @@ def softmax_fwd(x, *, block_rows: int = 64, interpret: bool = True):
         in_specs=[pl.BlockSpec((br, C), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, C), x.dtype),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(x2)
     return y[:R].reshape(orig_shape)
 
@@ -49,7 +51,7 @@ def _softmax_bwd_kernel(y_ref, dy_ref, dx_ref):
     dx_ref[...] = (yf * (dyf - s)).astype(dx_ref.dtype)
 
 
-def softmax_bwd(y, dy, *, block_rows: int = 64, interpret: bool = True):
+def softmax_bwd(y, dy, *, block_rows: int = 64):
     orig_shape = y.shape
     C = y.shape[-1]
     R = y.size // C
@@ -67,7 +69,7 @@ def softmax_bwd(y, dy, *, block_rows: int = 64, interpret: bool = True):
                   pl.BlockSpec((br, C), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, C), y.dtype),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(y2, dy2)
     return dx[:R].reshape(orig_shape)
 

@@ -157,8 +157,6 @@ def _lower_and_compile(cfg, mdl, cell, mesh, *, zero1: bool,
 
 def _cost_of(compiled) -> dict:
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # older jax: one dict per device
-        cost = cost[0] if cost else None
     coll = collective_bytes(compiled.as_text())
     return {
         "flops": float(cost.get("flops", -1)) if cost else -1.0,

@@ -8,6 +8,15 @@ to obtain 512 placeholder devices.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with ``Auto`` axes: the partitioner places
+    values and ``with_sharding_constraint`` may name any axis (jax 0.9
+    defaults new meshes to ``Explicit`` axes, which refuse those
+    constraints)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -18,17 +27,17 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
     """Arbitrary mesh (elastic-scaling tests resize DP with this)."""
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh():
     """1x1 mesh over the real local device (CPU smoke paths)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _mesh((1, 1), ("data", "model"))
 
 
 def make_test_mesh(n: int = 8):
@@ -48,4 +57,4 @@ def make_test_mesh(n: int = 8):
             f"make_test_mesh({n}) needs {n} devices, have "
             f"{len(jax.devices())}; set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={n} before jax init")
-    return jax.make_mesh((n // 2, 2), ("data", "model"))
+    return _mesh((n // 2, 2), ("data", "model"))

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
+from repro.launch.cache import enable_compile_cache
 from repro.core.stitch import stitched_jit
 from repro.models import build_model
 from repro.runtime.canary import CanaryController
@@ -98,6 +99,7 @@ def generate(mdl, params, prompts: np.ndarray, gen_len: int, *,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-3b", choices=ARCH_IDS)
     ap.add_argument("--reduced", action="store_true")

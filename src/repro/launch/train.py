@@ -19,6 +19,7 @@ import numpy as np
 
 from repro import optim
 from repro.configs import ARCH_IDS, get_config
+from repro.launch.cache import enable_compile_cache
 from repro.data import DataConfig, SyntheticTokens
 from repro.dist import partitioning
 from repro.dist.partitioning import param_specs
@@ -51,6 +52,7 @@ def build_trainer(cfg, *, fusion_mode="stitched", lr=1e-3, total_steps=1000,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-3b", choices=ARCH_IDS)
     ap.add_argument("--reduced", action="store_true")

@@ -5,16 +5,6 @@ import sys
 # make it work without the env var too)
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-# hypothesis is optional: without it, property-based tests degrade to a
-# single run on each strategy's canonical example instead of breaking
-# collection of every module that imports it.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    from _hypothesis_stub import install as _install_hypothesis_stub
-
-    _install_hypothesis_stub()
-
 # NOTE: do NOT set --xla_force_host_platform_device_count here; smoke tests
 # and benches must see the single real device (only dryrun.py forces 512).
 # Multi-device sharding tests instead run their bodies in a subprocess via

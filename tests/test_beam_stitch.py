@@ -74,7 +74,7 @@ def _waist_case():
         [n for n in fus if n <= a_end],
         [n for n in fus if a_end < n <= b_end],
         [n for n in fus if n > b_end]) if s])
-    return graph, plan, Hardware(vmem_bytes=160 * 1024)
+    return graph, plan, Hardware(vmem_bytes=768 * 1024)
 
 
 def _partition_gain(ctx, groups) -> float:

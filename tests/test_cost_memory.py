@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import V5E, best_estimate, delta_evaluator, trace
@@ -116,3 +117,39 @@ def test_scratch_never_exceeds_naive(depth, width):
     plan = plan_scratch(G, pat, info)
     assert plan.total_bytes <= plan.naive_bytes
     assert plan.total_bytes > 0
+
+
+def test_block_rows_are_rounded_onto_the_sublane_tile():
+    """Mosaic takes a row block that is a multiple of the dtype's
+    sublane tile (8 rows for 4-byte, 16 for 2-byte, 32 for 1-byte
+    types), or one that spans every row."""
+    from repro.core.cost_model import legal_block_rows, sublane_rows
+
+    assert legal_block_rows(1, 2048, 8) == 8
+    assert legal_block_rows(12, 2048, 8) == 16
+    assert legal_block_rows(8, 2048, 16) == 16
+    assert legal_block_rows(64, 4, 8) == 4       # fewer rows than a tile
+    for dtype, tile in [(np.float32, 8), (jnp.bfloat16, 16), (np.int8, 32)]:
+        G = trace(lambda x: x * x, np.zeros((64, 128), dtype))
+        assert sublane_rows(G, list(G.nodes)) == tile
+    G = _ln_graph(R=64, C=128)
+    est = best_estimate(G, _full_pattern(G), V5E)
+    assert est.block_rows % 8 == 0 or est.block_rows == 64
+
+
+def test_hardware_model_follows_the_device_kind(monkeypatch):
+    from repro.core import cost_model
+
+    assert cost_model.hardware() is V5E          # the CPU plans for v5e
+
+    class Chip:
+        platform = "tpu"
+
+        def __init__(self, kind):
+            self.device_kind = kind
+
+    monkeypatch.setattr(jax, "devices", lambda: [Chip("TPU v5 lite")])
+    assert cost_model.hardware() is V5E
+    monkeypatch.setattr(jax, "devices", lambda: [Chip("TPU v99")])
+    with pytest.raises(ValueError, match="no hardware model"):
+        cost_model.hardware()

@@ -248,7 +248,7 @@ def test_repro_shard_kill_switch_degrades_never_rekeys(tmp_path,
 _CHILD_COMMON = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.core import stitched_jit
 from repro.launch.mesh import make_test_mesh
 
@@ -311,7 +311,7 @@ for name, fn, ref_fn, specs, args in [
     assert rep.mesh_axes == (("data", 4), ("model", 2)), rep.mesh_axes
 
     # sharded XLA reference: same per-shard body, no stitching
-    xla = jax.jit(shard_map(fn, mesh=mesh, check_rep=False, **specs))
+    xla = jax.jit(shard_map(fn, mesh=mesh, check_vma=False, **specs))
     # single-device stitched + plain references (global formulation)
     single = stitched_jit(ref_fn)
     for tag, want in [("xla-sharded", xla(*args)),
@@ -334,7 +334,7 @@ args = (ints(8, 16).astype(jnp.bfloat16),
         ints(32, 16).astype(jnp.bfloat16))
 sf = stitched_jit(block, mesh=mesh, **BLOCK_SPECS)
 out = np.asarray(sf(*args), np.float32)
-xla = jax.jit(shard_map(block, mesh=mesh, check_rep=False, **BLOCK_SPECS))
+xla = jax.jit(shard_map(block, mesh=mesh, check_vma=False, **BLOCK_SPECS))
 want = np.asarray(xla(*args), np.float32)
 np.testing.assert_allclose(out, want, rtol=2e-2, atol=2e-2)
 print("DONE bf16")

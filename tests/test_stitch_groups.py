@@ -405,3 +405,36 @@ def test_donate_marks_nonoutput_inputs_and_stays_correct():
 
     # default: nothing is donated
     assert StitchedFunction(_deep).compiled(*args).donate_argnums == ()
+
+
+def _crossed(x):
+    a1 = x + 1.0
+    b2 = x * 2.0
+    b1 = a1 * 3.0
+    a2 = b2 - 1.0
+    return b1, a2
+
+
+def test_break_cycles_splits_convex_groups_that_feed_each_other():
+    """A = {a1, a2} and B = {b1, b2} are each convex, but A's a2 reads
+    B's b2 while B's b1 reads A's a1: neither kernel can run first."""
+    from repro.core.stitcher import break_cycles
+
+    G = trace(_crossed, np.ones((8, 128), np.float32))
+    by_prim = {}
+    for nid in G.topo_order():
+        by_prim.setdefault(G.node(nid).prim, []).append(nid)
+    (a1,), (a2,) = by_prim["add"], by_prim["sub"]
+    b2, b1 = by_prim["mul"]
+    A, B = frozenset({a1, a2}), frozenset({b1, b2})
+    assert G.is_convex(A) and G.is_convex(B)
+    assert sorted(G.group_cycle([A, B])) == [0, 1]
+
+    ctx = CostContext(G, V5E)
+    legal = break_cycles(G, [StitchGroup((A,)), StitchGroup((B,))], ctx)
+    assert G.group_cycle([g.members for g in legal]) is None
+    # the widest group dissolves into its (single-node) components
+    assert [g.members for g in legal] == [B]
+    assert ctx.caps["cycle_split"] == 1
+    # a partition without a cycle is left as it is
+    assert break_cycles(G, legal, ctx) == legal

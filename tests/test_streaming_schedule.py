@@ -48,7 +48,7 @@ def test_streaming_layernorm_allclose(R, C, bc):
     G, pat, ext = _graph_and_pattern(_ln, x, g, b)
     info = analyze(G, pat)
     fn = _emit_pallas_streaming(G, pat, info, 4, ext,
-                                G.pattern_outputs(pat), interpret=True,
+                                G.pattern_outputs(pat),
                                 block_cols=bc)
     np.testing.assert_allclose(np.asarray(fn(x, g, b)[0]),
                                np.asarray(_ln(x, g, b)),
@@ -61,7 +61,7 @@ def test_streaming_softmax_with_max_reduce():
     G, pat, ext = _graph_and_pattern(fn_ref, z)
     info = analyze(G, pat)
     fn = _emit_pallas_streaming(G, pat, info, 2, ext,
-                                G.pattern_outputs(pat), interpret=True,
+                                G.pattern_outputs(pat),
                                 block_cols=1024)
     np.testing.assert_allclose(np.asarray(fn(z)[0]),
                                np.asarray(fn_ref(z)), rtol=1e-5, atol=1e-6)
@@ -89,7 +89,7 @@ def test_emit_pattern_streaming_path_runs():
     b = rng.standard_normal(2048).astype(np.float32)
     G, pat, ext = _graph_and_pattern(_ln, x, g, b)
     small = Hardware(vmem_bytes=96 * 1024)
-    em = emit_pattern(G, pat, hw=small, interpret=True)
+    em = emit_pattern(G, pat, hw=small)
     out = em.fn(x, g, b)
     np.testing.assert_allclose(np.asarray(out[0]), np.asarray(_ln(x, g, b)),
                                rtol=1e-4, atol=1e-4)
