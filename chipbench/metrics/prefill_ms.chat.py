@@ -1,0 +1,9 @@
+"""Median host milliseconds of one prefill call in the window."""
+from benchlib import readers as R
+
+
+def read(run):
+    if not R.open_loop(run):
+        return None
+    v = R.median(R.prefill_s(run))
+    return None if v is None else 1e3 * v
