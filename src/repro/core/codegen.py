@@ -418,6 +418,12 @@ def _to_block(val, role: Role, br: int, C: int):
     return val.reshape(())
 
 
+def kernel_name(scheme: str, group: int) -> str:
+    """A stitch group's kernel name in the compiled program and in the
+    profiler's trace: ``stitch_<scheme>_<group index>``."""
+    return f"stitch_{scheme}_{group}"
+
+
 @dataclass
 class Emitted:
     """A compiled pattern or stitch group: callable + benchmark metadata."""
@@ -435,6 +441,8 @@ class Emitted:
     io_aliases: dict = None      # ext pos -> out pos donated into the kernel
     n_recomputed: int = 0        # values inlined per consumer (not staged)
     recompute_bytes_freed: int = 0  # VMEM scratch bytes those flips elide
+    name: str = ""               # the kernel's name (``kernel_name``)
+    group: int = 0               # index of the stitch group it runs as
 
 
 def _override_estimate(graph: Graph, pattern: frozenset[int], info,
@@ -572,12 +580,15 @@ def emit_pattern(graph: Graph, pattern: frozenset[int], *,
                  hw: Hardware = V5E,
                  force_packed: bool = False, ctx=None,
                  schedule_override: dict | None = None,
-                 donate_into: "frozenset[int] | None" = None) -> Emitted:
+                 donate_into: "frozenset[int] | None" = None,
+                 group: int = 0) -> Emitted:
     """Compile one pattern.  ``schedule_override`` (from the persistent
     plan cache or the measured autotuner) pins {schedule, block_rows,
     block_cols} instead of re-running the analytic sweep.
     ``donate_into`` names graph inputs this kernel may overwrite with
-    its outputs (one-pass schedule only; see ``_alias_map``)."""
+    its outputs (one-pass schedule only; see ``_alias_map``).  ``group``
+    is the stitch group's index, which names the kernel
+    (``kernel_name``)."""
     info = ctx.info(pattern) if ctx is not None else analyze(graph, pattern)
     est = None
     if schedule_override is not None:
@@ -611,12 +622,14 @@ def emit_pattern(graph: Graph, pattern: frozenset[int], *,
         if est.schedule == "onepass":
             aliases = _alias_map(graph, info, ext_ids, out_ids, donate_into)
             fn = _emit_pallas(graph, pattern, info, est.block_rows, ext_ids,
-                              out_ids, io_aliases=aliases, recompute=rec)
+                              out_ids, io_aliases=aliases, recompute=rec,
+                              name=kernel_name("onepass", group))
             return Emitted(fn, "pallas", est, ext_ids, out_ids,
                            scratch.total_bytes, scratch.naive_bytes,
                            parts=(tuple(sorted(pattern)),),
                            io_aliases=aliases, n_recomputed=len(rec),
-                           recompute_bytes_freed=rec_freed)
+                           recompute_bytes_freed=rec_freed,
+                           name=kernel_name("onepass", group), group=group)
         if est.schedule == "streaming":
             # the estimate carries the column tile (analytic sweep, tuned
             # override or plan-cache entry alike -- no side-channel)
@@ -629,25 +642,28 @@ def emit_pattern(graph: Graph, pattern: frozenset[int], *,
             fn = _emit_pallas_streaming(graph, pattern, info,
                                         est.block_rows, ext_ids, out_ids,
                                         block_cols=est.block_cols or 2048,
-                                        io_aliases=aliases)
+                                        io_aliases=aliases,
+                                        name=kernel_name("streaming", group))
             return Emitted(fn, "pallas", est, ext_ids, out_ids,
                            scratch.total_bytes, scratch.naive_bytes,
                            parts=(tuple(sorted(pattern)),),
-                           io_aliases=aliases)
+                           io_aliases=aliases,
+                           name=kernel_name("streaming", group), group=group)
 
     fn = _emit_packed(graph, pattern, ext_ids, out_ids)
     if est.schedule in ("onepass", "streaming"):  # emitter gap: packed
         from .cost_model import estimate_packed
         est = estimate_packed(graph, pattern, hw, ctx=ctx)
     return Emitted(fn, "packed", est, ext_ids, out_ids, 0, 0,
-                   parts=(tuple(sorted(pattern)),))
+                   parts=(tuple(sorted(pattern)),),
+                   name=kernel_name("packed", group), group=group)
 
 
 def emit_group(graph: Graph, parts, *, hw: Hardware = V5E,
                ctx=None,
                schedule_override: dict | None = None,
                donate_into: "frozenset[int] | None" = None,
-               anchors: tuple = ()) -> Emitted:
+               anchors: tuple = (), group: int = 0) -> Emitted:
     """Compile one stitch group into a single Pallas megakernel (paper §4).
 
     ``parts`` are the group's member patterns in topological order.  A
@@ -661,17 +677,18 @@ def emit_group(graph: Graph, parts, *, hw: Hardware = V5E,
     schedule phases over the *cumulative* reduce levels (the max phase
     count across the chain -- the paper's non-homogeneous-parallelism
     case), while a union that fits VMEM residency runs all members in a
-    single one-pass cell.
+    single one-pass cell.  ``group`` is the group's index, which names
+    its kernel (``kernel_name``).
     """
     parts = tuple(tuple(sorted(p)) for p in parts)
     union = frozenset(n for p in parts for n in p)
     if anchors:
         return _emit_anchored(graph, parts, tuple(sorted(anchors)),
-                              hw=hw, ctx=ctx)
+                              hw=hw, ctx=ctx, group=group)
     if len(parts) == 1:
         return emit_pattern(graph, union, hw=hw, ctx=ctx,
                             schedule_override=schedule_override,
-                            donate_into=donate_into)
+                            donate_into=donate_into, group=group)
 
     info = ctx.info(union) if ctx is not None else analyze(graph, union)
     est = None
@@ -721,7 +738,8 @@ def emit_group(graph: Graph, parts, *, hw: Hardware = V5E,
             fn = _emit_pallas(graph, union, info, est.block_rows, ext_ids,
                               out_ids, order=order,
                               staged=staged, io_aliases=aliases,
-                              recompute=rec)
+                              recompute=rec,
+                              name=kernel_name("onepass", group))
         else:
             from .cost_model import reduce_levels
             phases = max(reduce_levels(graph, union).values(),
@@ -732,13 +750,15 @@ def emit_group(graph: Graph, parts, *, hw: Hardware = V5E,
             fn = _emit_pallas_streaming(graph, union, info, est.block_rows,
                                         ext_ids, out_ids,
                                         block_cols=est.block_cols or 2048,
-                                        order=order, io_aliases=aliases)
+                                        order=order, io_aliases=aliases,
+                                        name=kernel_name("streaming", group))
         return Emitted(fn, "pallas", est, ext_ids, out_ids,
                        scratch.total_bytes, scratch.naive_bytes,
                        parts=parts, hbm_saved=hbm_saved,
                        staged_slots=n_staged, io_aliases=aliases,
                        n_recomputed=len(rec),
-                       recompute_bytes_freed=rec_freed)
+                       recompute_bytes_freed=rec_freed,
+                       name=kernel_name(est.schedule, group), group=group)
 
     # defensive fallback (stale cached group / emitter gap): the union
     # still runs as one launch via kernel packing.
@@ -746,7 +766,8 @@ def emit_group(graph: Graph, parts, *, hw: Hardware = V5E,
     from .cost_model import estimate_packed
     est = estimate_packed(graph, union, hw, ctx=ctx)
     return Emitted(fn, "packed", est, ext_ids, out_ids, 0, 0,
-                   parts=parts, hbm_saved=hbm_saved)
+                   parts=parts, hbm_saved=hbm_saved,
+                   name=kernel_name("packed", group), group=group)
 
 
 _REDUCE_IDENTITY = {
@@ -765,7 +786,8 @@ def _emit_pallas_streaming(graph: Graph, pattern: frozenset[int],
                            ext_ids: list[int], out_ids: list[int], *,
                            block_cols: int = 2048,
                            order: list[int] | None = None,
-                           io_aliases: dict[int, int] | None = None
+                           io_aliases: dict[int, int] | None = None,
+                           name: str = kernel_name("streaming", 0)
                            ) -> Callable:
     """Streaming multi-phase kernel (warp-composition analogue, §4.1).
 
@@ -908,6 +930,7 @@ def _emit_pallas_streaming(graph: Graph, pattern: frozenset[int],
         out_shape=out_shapes if len(out_shapes) > 1 else out_shapes[0],
         scratch_shapes=[pltpu.VMEM((br, 1), jnp.float32) for _ in reduces],
         input_output_aliases=dict(io_aliases or {}),
+        name=name,
         interpret=kernels.interpret_mode(),
     )
 
@@ -975,7 +998,8 @@ def _emit_pallas(graph: Graph, pattern: frozenset[int], info: RowInfo,
                  *, order: list[int] | None = None,
                  staged: tuple | None = None,
                  io_aliases: dict[int, int] | None = None,
-                 recompute: frozenset[int] = frozenset()) -> Callable:
+                 recompute: frozenset[int] = frozenset(),
+                 name: str = kernel_name("onepass", 0)) -> Callable:
     R, C = info.R, info.C
     br = legal_block_rows(block_rows, R, row_tile(graph, pattern))
     Rp = math.ceil(R / br) * br
@@ -1096,6 +1120,7 @@ def _emit_pallas(graph: Graph, pattern: frozenset[int], info: RowInfo,
         scratch_shapes=[pltpu.VMEM(shape, dtype)
                         for shape, dtype in scratch_buffers],
         input_output_aliases=dict(io_aliases or {}),
+        name=name,
         interpret=kernels.interpret_mode(),
     )
 
@@ -1205,7 +1230,7 @@ def _anchored_estimate(graph: Graph, union: frozenset[int],
 
 
 def _emit_anchored(graph: Graph, parts, anchors, *, hw: Hardware = V5E,
-                   ctx=None) -> Emitted:
+                   ctx=None, group: int = 0) -> Emitted:
     """Compile an anchored stitch group into ONE compute kernel whose
     grid also runs the folded prologue/epilogue chains.  Raises
     ``AnchorEmitError`` on any structural mismatch -- the dispatch
@@ -1229,7 +1254,7 @@ def _emit_anchored(graph: Graph, parts, anchors, *, hw: Hardware = V5E,
         if m is None:
             raise AnchorEmitError("anchored matmul: structure mismatch")
         return _emit_anchored_matmul(graph, parts, m, ext_ids, out_ids,
-                                     hbm_saved, hw=hw)
+                                     hbm_saved, hw=hw, group=group)
     if len(anchors) == 2:
         m = _match_attention_anchors(graph, union, anchors)
         if m is None:
@@ -1237,12 +1262,13 @@ def _emit_anchored(graph: Graph, parts, anchors, *, hw: Hardware = V5E,
         if list(out_ids) != [m["pv"]]:
             raise AnchorEmitError("anchored attention: escaping chain value")
         return _emit_anchored_attention(graph, parts, m, ext_ids,
-                                        hbm_saved, hw=hw)
+                                        hbm_saved, hw=hw, group=group)
     raise AnchorEmitError(f"unsupported anchor count {len(anchors)}")
 
 
 def _emit_anchored_matmul(graph: Graph, parts, m: dict, ext_ids, out_ids,
-                          hbm_saved: int, *, hw: Hardware) -> Emitted:
+                          hbm_saved: int, *, hw: Hardware,
+                          group: int) -> Emitted:
     from ..kernels.matmul import DEFAULT_BLOCK_M, matmul_fused
 
     a, lhs_id, rhs_id = m["a"], m["lhs"], m["rhs"]
@@ -1300,7 +1326,8 @@ def _emit_anchored_matmul(graph: Graph, parts, m: dict, ext_ids, out_ids,
             M=M, K=K, N=N, pro_roles=pro_roles, epi_roles=epi_roles,
             out_roles=out_roles, out_dtypes=out_dtypes,
             anchor_dtype=anchor_dtype, prologue=prologue,
-            epilogue=epilogue, block_m=bm)
+            epilogue=epilogue, block_m=bm,
+            name=kernel_name("anchored", group))
         return tuple(o.reshape(out_shapes[oid])
                      for o, oid in zip(outs, out_ids))
 
@@ -1309,11 +1336,13 @@ def _emit_anchored_matmul(graph: Graph, parts, m: dict, ext_ids, out_ids,
     vmem = bm * K * graph.node(lhs_id).spec.itemsize \
         + K * N * graph.node(rhs_id).spec.itemsize + bm * N * 4
     return Emitted(fn, "pallas", est, ext_ids, list(out_ids),
-                   vmem, vmem, parts=parts, hbm_saved=hbm_saved)
+                   vmem, vmem, parts=parts, hbm_saved=hbm_saved,
+                   name=kernel_name("anchored", group), group=group)
 
 
 def _emit_anchored_attention(graph: Graph, parts, m: dict, ext_ids,
-                             hbm_saved: int, *, hw: Hardware) -> Emitted:
+                             hbm_saved: int, *, hw: Hardware,
+                             group: int) -> Emitted:
     from ..kernels.flash_attention import DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, \
         flash_attention
 
@@ -1376,7 +1405,7 @@ def _emit_anchored_attention(graph: Graph, parts, m: dict, ext_ids,
         out = flash_attention(
             get(q_id), get(k_id), get(v_id), causal=False, scale=1.0,
             score_mod=score_mod if score else None,
-            score_args=sargs)
+            score_args=sargs, name=kernel_name("anchored", group))
         return (out.astype(out_spec.dtype).reshape(out_spec.shape),)
 
     union = frozenset(n for p in parts for n in p)
@@ -1386,4 +1415,5 @@ def _emit_anchored_attention(graph: Graph, parts, m: dict, ext_ids,
     est = _anchored_estimate(graph, union, hw, bq, n_steps)
     vmem = bq * D * 4 + bk * D * 8 + bq * bk * 4 + bq * (D + 2) * 4
     return Emitted(fn, "pallas", est, ext_ids, [pv],
-                   vmem, vmem, parts=parts, hbm_saved=hbm_saved)
+                   vmem, vmem, parts=parts, hbm_saved=hbm_saved,
+                   name=kernel_name("anchored", group), group=group)

@@ -37,6 +37,7 @@ cutting HBM pressure at decode batch sizes.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import hashlib
 import heapq
@@ -49,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime import spans
 from repro.runtime.canary import CanaryController
 from repro.runtime.guard import EmitError, GuardError, PoisonList, \
     RUNG_ANCHORED, RUNG_BASELINE, RUNG_PATTERNS, RUNG_STITCHED, RUNGS, \
@@ -78,6 +80,11 @@ class StitchReport:
     scratch_naive_bytes: int
     plan_time_s: float
     patterns: list[frozenset] = field(default_factory=list)
+    # -- plan phases (parts of plan_time_s, each under a span) ---------------
+    trace_s: float = 0.0             # jaxpr trace (``stitch.trace``)
+    search_s: float = 0.0            # plan-cache load or explore + stitch,
+    #                                  and group tuning (``stitch.search``)
+    emit_s: float = 0.0              # codegen (``stitch.emit``)
     plan_cache_hit: bool = False
     autotuned: bool = False
     signature: str = ""
@@ -123,6 +130,24 @@ class StitchReport:
     quarantined: bool = False        # plan evicted + signature poisoned
 
 
+class _Phases:
+    """Seconds of one build's phases (``trace``, ``search``, ``emit``);
+    ``with phases("trace"):`` times a block into its phase inside the
+    span ``stitch.trace``."""
+
+    def __init__(self):
+        self.s = {"trace": 0.0, "search": 0.0, "emit": 0.0}
+
+    @contextlib.contextmanager
+    def __call__(self, phase: str):
+        t = time.perf_counter()
+        try:
+            with spans.span(f"stitch.{phase}"):
+                yield
+        finally:
+            self.s[phase] += time.perf_counter() - t
+
+
 class _Compiled:
     """One traced+planned+emitted instance for a fixed shape signature.
 
@@ -146,11 +171,16 @@ class _Compiled:
     so the owner can evict + poison the plan-cache entry.  The call
     still returns a correct result -- degradation is recorded, never
     silent, and never an exception on the serving path.
+
+    The compiled program is named ``name`` (its HLO module is
+    ``jit_<name>``), and each stitch group's kernels and ops run under
+    the name scope ``g<group index>``.
     """
 
     def __init__(self, graph: Graph, plan: FusionPlan,
                  emitted: list[Emitted], schedule: list[tuple[str, Any]],
                  report: StitchReport, out_tree, dispatch: str = "single",
+                 name: str = "stitched",
                  donate: bool = False,
                  donate_argnums: tuple[int, ...] | None = None,
                  verify_policy: VerifyPolicy | None = None,
@@ -199,7 +229,11 @@ class _Compiled:
                     if nid not in outset)
         body = (self.shard.wrap(self._run_schedule)
                 if self.shard is not None else self._run_schedule)
-        self._jitted = jax.jit(body, donate_argnums=self.donate_argnums)
+
+        def program(*flat_args):
+            return body(*flat_args)
+        program.__name__ = program.__qualname__ = name
+        self._jitted = jax.jit(program, donate_argnums=self.donate_argnums)
 
     def _run_schedule(self, *flat_args):
         """Execute the fusion schedule (traceable; jitted for dispatch)."""
@@ -217,7 +251,8 @@ class _Compiled:
                 env[item] = bind_node(node, ins)
             else:
                 em: Emitted = item
-                outs = em.fn(*[env[i] for i in em.ext_ids])
+                with jax.named_scope(f"g{em.group}"):
+                    outs = em.fn(*[env[i] for i in em.ext_ids])
                 for oid, val in zip(em.out_ids, outs):
                     env[oid] = val
         return tuple(env[o] for o in graph.outputs)
@@ -275,11 +310,13 @@ class _Compiled:
             return jax.tree_util.tree_unflatten(self.out_tree,
                                                 list(flat_out))
         if self._use_baseline:
-            flat_out = self._baseline(*flat_args)
+            with spans.span("stitch.launch"):
+                flat_out = self._baseline(*flat_args)
             return jax.tree_util.tree_unflatten(self.out_tree,
                                                 list(flat_out))
         if self.canary is not None:
-            flat_out = self.canary.guarded_call(self, flat_args)
+            with spans.span("stitch.guard"):
+                flat_out = self.canary.guarded_call(self, flat_args)
             return jax.tree_util.tree_unflatten(self.out_tree,
                                                 list(flat_out))
         policy = self.verify_policy
@@ -289,9 +326,11 @@ class _Compiled:
         if verify:
             # the stitched call may donate its inputs: the reference
             # must consume them first.
-            ref = self._baseline(*flat_args)
+            with spans.span("stitch.guard"):
+                ref = self._baseline(*flat_args)
         try:
-            flat_out = self._jitted(*flat_args)
+            with spans.span("stitch.launch"):
+                flat_out = self._jitted(*flat_args)
         except Exception as e:  # noqa: BLE001 - contained: baseline rung
             self._quarantine(f"dispatch failed: {type(e).__name__}: {e}")
             if ref is None:
@@ -303,15 +342,17 @@ class _Compiled:
                         f"could not run (inputs donated?): {e2}") from e
             return jax.tree_util.tree_unflatten(self.out_tree, list(ref))
         if ref is not None:
-            self.report.verified += 1
-            reason = outputs_mismatch(
-                ref, flat_out, anchored=self.report.n_anchored > 0)
-            if _faults.fire("numeric_mismatch") is not None:
-                reason = reason or "injected numeric_mismatch"
-            if reason is not None:
-                self.report.verify_failures += 1
-                self._quarantine(f"shadow verification mismatch: {reason}")
-                flat_out = ref  # serve the reference, not the mismatch
+            with spans.span("stitch.guard"):
+                self.report.verified += 1
+                reason = outputs_mismatch(
+                    ref, flat_out, anchored=self.report.n_anchored > 0)
+                if _faults.fire("numeric_mismatch") is not None:
+                    reason = reason or "injected numeric_mismatch"
+                if reason is not None:
+                    self.report.verify_failures += 1
+                    self._quarantine(
+                        f"shadow verification mismatch: {reason}")
+                    flat_out = ref  # serve the reference, not the mismatch
         return jax.tree_util.tree_unflatten(self.out_tree, list(flat_out))
 
 
@@ -437,8 +478,12 @@ def _emit_signature(graph: Graph, ctx: CostContext, union: frozenset[int],
 
 def _rebind_emitted(graph: Graph, ctx: CostContext, union: frozenset[int],
                     parts: tuple, template: Emitted,
-                    template_seen: list[int]) -> Emitted | None:
-    """Reuse a structurally identical compiled kernel for ``union``.
+                    template_seen: list[int], *,
+                    group: int) -> Emitted | None:
+    """Reuse a structurally identical compiled kernel for ``union``, run
+    as the stitch group ``group``.  The kernel keeps the template's name:
+    one emitted kernel, one name, so isomorphic groups (repeated layers)
+    still lower once.
 
     The template callable takes its externals in *its* id-sorted order;
     this instance's id-sorted order can differ, so arguments are routed
@@ -471,7 +516,8 @@ def _rebind_emitted(graph: Graph, ctx: CostContext, union: frozenset[int],
                    hbm_saved=template.hbm_saved,
                    staged_slots=template.staged_slots,
                    n_recomputed=template.n_recomputed,
-                   recompute_bytes_freed=template.recompute_bytes_freed)
+                   recompute_bytes_freed=template.recompute_bytes_freed,
+                   name=template.name, group=group)
 
 
 def _remap_override(over: dict, src_members: list[int],
@@ -558,6 +604,8 @@ class StitchedFunction:
         self._shard = (ShardCtx.build(mesh, in_specs, out_specs)
                        if mesh is not None else None)
         self._fn = fn
+        #: the compiled programs' name (``jit_<program>`` in a profile)
+        self.program = f"stitched_{getattr(fn, '__name__', 'fn')}"
         #: the planning target: the default backend's chip unless a
         #: caller models another one (tests shrink VMEM this way)
         self._hw = hw if hw is not None else hardware()
@@ -641,7 +689,8 @@ class StitchedFunction:
         with self._compile_lock:
             compiled = self._cache.get(key)
             if compiled is None:
-                compiled = self._build(flat, in_tree)
+                with spans.span("stitch.build", program=self.program):
+                    compiled = self._build(flat, in_tree)
                 self._cache[key] = compiled
                 submit = (compiled._race_ctx is not None
                           and self._background is not None)
@@ -658,6 +707,7 @@ class StitchedFunction:
 
     def _build(self, flat, in_tree) -> _Compiled:
         t0 = time.perf_counter()
+        phases = _Phases()
 
         def flat_fn(*fargs):
             a, k = jax.tree_util.tree_unflatten(in_tree, fargs)
@@ -665,176 +715,176 @@ class StitchedFunction:
 
         shard = self._shard_ctx()
         explicit_shard = shard is not None and shard.explicit
-        out_tree = None
-        if explicit_shard:
-            # the per-shard program IS the plan's subject: trace on local
-            # shapes with the mesh axes bound, so collectives become
-            # COLLECTIVE nodes and every row count / VMEM / HBM figure
-            # downstream is per-shard with no cost-formula changes.
-            graph, out_tree, _ = trace_with_shape(
-                flat_fn, *shard.local_args(flat),
-                axis_env=shard.axis_env())
-        else:
-            graph = trace(flat_fn, *flat)
-        ctx = CostContext(graph, self._hw, shard=shard)
-        sig = graph_signature(graph, self._hw, remote_fusion=self._remote,
-                              shard=shard)
-
-        # persistent cache: an identical graph signature in any process
-        # reuses the stored patterns + group composition + tuned
-        # schedules and skips exploration *and* stitching entirely.
-        overrides: list[dict] = []
-        entry: dict | None = None
-        cached = self._load_cached_plan(graph, sig)
-        autotuned = False
-        if cached is not None:
-            plan, overrides, entry = cached
-        else:
-            plan = make_plan(graph, self._hw,
-                             use_remote_fusion=self._remote, ctx=ctx)
-            if self._autotune:
-                from .autotune import autotune_available, tune_pattern
-
-                if autotune_available():
-                    # isomorphic patterns (repeated layers) share one
-                    # measured sweep: timing depends on structure +
-                    # shapes, not on which instance runs it.  Shared
-                    # pins are remapped to each sibling's node ids
-                    # (the recompute flip set is id-specific).
-                    tuned_by_struct: dict[tuple, tuple] = {}
-                    for pat in plan.patterns:
-                        skey = ctx.struct_key(pat.members)
-                        members = sorted(pat.members)
-                        hit = tuned_by_struct.get(skey)
-                        if hit is None:
-                            over = tune_pattern(graph, pat.members,
-                                                hw=self._hw,
-                                                ctx=ctx) or {}
-                            tuned_by_struct[skey] = (over, members)
-                        else:
-                            over = _remap_override(hit[0], hit[1], members)
-                        overrides.append(over)
-                    autotuned = True
-            if not overrides:
-                overrides = [{} for _ in plan.patterns]
-
-        # ---- stitch groups: compose patterns into megakernels -------------
-        # The partition search ranks the top-k distinct candidate
-        # partitions by modeled gain; with an accelerator available the
-        # candidates are *raced on silicon* (``tune_partitions``) and
-        # the measured winner is committed -- the paper's
-        # model-validated-by-measurement tuning of the stitching scheme.
-        # A cached entry whose partition was already measured is
-        # trusted; a pre-v4 (or model-sourced) entry degrades to
-        # re-measuring and is upgraded in place.
-        groups: list[StitchGroup]
-        group_overrides: list[dict]
-        groups_from_cache = False
-        stitch_stats = None
-        race_ctx: _RaceContext | None = None
-        partition_source = "model"
-        partition_index = 0
-        partition_candidates = 0
-        if self._stitch_groups:
-            from .autotune import autotune_available
-
-            # explicit-shard compiles neither race nor measure: the
-            # in-process tuner runs unsharded branches that would price
-            # a different (global-shape) program.  Sharded racing is a
-            # follow-on; the analytic sharded cost model decides.
-            defer = self._background is not None and not explicit_shard
-            can_tune = ((self._autotune or defer) and not explicit_shard
-                        and autotune_available())
-            loaded = (entry_to_groups(entry, plan, graph)
-                      if entry is not None else None)
-            cached_source = (entry_partition_source(entry)
-                             if entry is not None else "model")
-            if loaded is not None and (cached_source == "measured"
-                                       or not can_tune):
-                # trust the cached composition: its partition was raced
-                # already, or this process cannot measure anyway.
-                groups, group_overrides = loaded
-                groups_from_cache = True
-                partition_source = cached_source
-                # a pre-anchor (v5) composition re-plans its anchors on
-                # load: absorption is deterministic given the graph, so
-                # the backfill below rewrites the upgraded entry in v6.
-                if anchor_enabled() and not any(g.anchors for g in groups):
-                    a_groups, n_anch = absorb_anchors(
-                        graph, [list(g.parts) for g in groups], ctx)
-                    if n_anch:
-                        over_by = {g.parts: o for g, o in
-                                   zip(groups, group_overrides)}
-                        groups = a_groups
-                        group_overrides = [
-                            dict(over_by.get(g.parts, {}))
-                            for g in groups]
+        with phases("trace"):
+            if explicit_shard:
+                # the per-shard program IS the plan's subject: trace on
+                # local shapes with the mesh axes bound, so collectives
+                # become COLLECTIVE nodes and every row count / VMEM / HBM
+                # figure downstream is per-shard with no cost-formula
+                # changes.  The output tree comes from the same trace:
+                # eval_shape on the *global* args would re-trace the
+                # per-shard body without its axis_env and fail on the
+                # first collective.
+                graph, out_tree, _ = trace_with_shape(
+                    flat_fn, *shard.local_args(flat),
+                    axis_env=shard.axis_env())
             else:
-                # pre-v4 / model-sourced entries degrade to re-measuring
-                # the *partition*, but their group schedule pins (PR 3
-                # measurements, keyed by composition) are reused for any
-                # winner group with the same parts instead of being
-                # re-swept from scratch.
-                loaded_over_by_parts: dict[tuple, dict] = {}
-                if loaded is not None:
-                    for lgrp, lover in zip(*loaded):
-                        if lover:
-                            loaded_over_by_parts[lgrp.parts] = lover
-                result = search_groups(graph, plan, self._hw, ctx=ctx)
-                stitch_stats = result.stats
-                candidates = result.candidates
-                partition_candidates = len(candidates)
-                groups = result.groups
-                if can_tune and defer:
-                    # cold-miss policy (paper §7 production regime):
-                    # serve the analytic (cost-model) plan NOW; the
-                    # top-k partition race and the per-group tile
-                    # sweeps run via ``rerace`` on the background
-                    # executor, whose winner is hot-swapped into the
-                    # live dispatch table and persisted.
-                    if len(candidates) > 1:
-                        partition_source = "analytic"
-                    if len(candidates) > 1 or any(g.stitched
-                                                  for g in groups):
-                        race_ctx = _RaceContext(
-                            graph=graph, ctx=ctx, sig=sig, plan=plan,
-                            overrides=overrides, candidates=candidates,
-                            groups=groups,
-                            loaded_over_by_parts=loaded_over_by_parts,
-                            stitch_stats=stitch_stats, out_tree=None)
-                elif can_tune and len(candidates) > 1:
-                    from .autotune import tune_partitions
+                graph = trace(flat_fn, *flat)
+                # the output tree (also needed by a deferred race rebuild)
+                out_shape = jax.eval_shape(flat_fn, *flat)
+                _, out_tree = jax.tree_util.tree_flatten(out_shape)
 
-                    res = tune_partitions(
-                        graph, [c.groups for c in candidates],
-                        hw=self._hw, ctx=ctx)
-                    if res is not None:
-                        # commit the raced winner; its schedule *pins*
-                        # are left to the per-group measured sweep below
-                        # (the race's family swaps screen partitions,
-                        # they are not a substitute for the tile sweep).
-                        groups = candidates[res.index].groups
-                        partition_source = "measured"
-                        partition_index = res.index
+        with phases("search"):
+            ctx = CostContext(graph, self._hw, shard=shard)
+            sig = graph_signature(graph, self._hw,
+                                  remote_fusion=self._remote, shard=shard)
+            # persistent cache: an identical graph signature in any process
+            # reuses the stored patterns + group composition + tuned
+            # schedules and skips exploration *and* stitching entirely.
+            overrides: list[dict] = []
+            entry: dict | None = None
+            cached = self._load_cached_plan(graph, sig)
+            autotuned = False
+            if cached is not None:
+                plan, overrides, entry = cached
+            else:
+                plan = make_plan(graph, self._hw,
+                                 use_remote_fusion=self._remote, ctx=ctx)
+                if self._autotune:
+                    from .autotune import autotune_available, tune_pattern
+
+                    if autotune_available():
+                        # isomorphic patterns (repeated layers) share one
+                        # measured sweep: timing depends on structure +
+                        # shapes, not on which instance runs it.  Shared
+                        # pins are remapped to each sibling's node ids
+                        # (the recompute flip set is id-specific).
+                        tuned_by_struct: dict[tuple, tuple] = {}
+                        for pat in plan.patterns:
+                            skey = ctx.struct_key(pat.members)
+                            members = sorted(pat.members)
+                            hit = tuned_by_struct.get(skey)
+                            if hit is None:
+                                over = tune_pattern(graph, pat.members,
+                                                    hw=self._hw,
+                                                    ctx=ctx) or {}
+                                tuned_by_struct[skey] = (over, members)
+                            else:
+                                over = _remap_override(hit[0], hit[1], members)
+                            overrides.append(over)
                         autotuned = True
-                # a lone candidate stays model-sourced: "measured" is
-                # never stamped without an actual race, so a later
-                # process with a wider REPRO_STITCH_TOPK still races.
-                group_overrides = [
-                    dict(loaded_over_by_parts.get(grp.parts, {}))
-                    for grp in groups]
-        else:
-            groups = [StitchGroup((p.members,)) for p in plan.patterns]
-            group_overrides = [{} for _ in groups]
+                if not overrides:
+                    overrides = [{} for _ in plan.patterns]
 
-        # determine output tree (also needed by a deferred race rebuild).
-        # An explicit-shard build already has it from the local-shape
-        # trace; eval_shape on the *global* args would re-trace the
-        # per-shard body without its axis_env and fail on the first
-        # collective.
-        if out_tree is None:
-            out_shape = jax.eval_shape(flat_fn, *flat)
-            _, out_tree = jax.tree_util.tree_flatten(out_shape)
+            # ---- stitch groups: compose patterns into megakernels ---------
+            # The partition search ranks the top-k distinct candidate
+            # partitions by modeled gain; with an accelerator available the
+            # candidates are *raced on silicon* (``tune_partitions``) and
+            # the measured winner is committed -- the paper's
+            # model-validated-by-measurement tuning of the stitching scheme.
+            # A cached entry whose partition was already measured is
+            # trusted; a pre-v4 (or model-sourced) entry degrades to
+            # re-measuring and is upgraded in place.
+            groups: list[StitchGroup]
+            group_overrides: list[dict]
+            groups_from_cache = False
+            stitch_stats = None
+            race_ctx: _RaceContext | None = None
+            partition_source = "model"
+            partition_index = 0
+            partition_candidates = 0
+            if self._stitch_groups:
+                from .autotune import autotune_available
+
+                # explicit-shard compiles neither race nor measure: the
+                # in-process tuner runs unsharded branches that would price
+                # a different (global-shape) program.  Sharded racing is a
+                # follow-on; the analytic sharded cost model decides.
+                defer = self._background is not None and not explicit_shard
+                can_tune = ((self._autotune or defer) and not explicit_shard
+                            and autotune_available())
+                loaded = (entry_to_groups(entry, plan, graph)
+                          if entry is not None else None)
+                cached_source = (entry_partition_source(entry)
+                                 if entry is not None else "model")
+                if loaded is not None and (cached_source == "measured"
+                                           or not can_tune):
+                    # trust the cached composition: its partition was raced
+                    # already, or this process cannot measure anyway.
+                    groups, group_overrides = loaded
+                    groups_from_cache = True
+                    partition_source = cached_source
+                    # a pre-anchor (v5) composition re-plans its anchors on
+                    # load: absorption is deterministic given the graph, so
+                    # the backfill below rewrites the upgraded entry in v6.
+                    if anchor_enabled() and not any(g.anchors for g in groups):
+                        a_groups, n_anch = absorb_anchors(
+                            graph, [list(g.parts) for g in groups], ctx)
+                        if n_anch:
+                            over_by = {g.parts: o for g, o in
+                                       zip(groups, group_overrides)}
+                            groups = a_groups
+                            group_overrides = [
+                                dict(over_by.get(g.parts, {}))
+                                for g in groups]
+                else:
+                    # pre-v4 / model-sourced entries degrade to re-measuring
+                    # the *partition*, but their group schedule pins (earlier
+                    # measurements, keyed by composition) are reused for any
+                    # winner group with the same parts instead of being
+                    # re-swept from scratch.
+                    loaded_over_by_parts: dict[tuple, dict] = {}
+                    if loaded is not None:
+                        for lgrp, lover in zip(*loaded):
+                            if lover:
+                                loaded_over_by_parts[lgrp.parts] = lover
+                    result = search_groups(graph, plan, self._hw, ctx=ctx)
+                    stitch_stats = result.stats
+                    candidates = result.candidates
+                    partition_candidates = len(candidates)
+                    groups = result.groups
+                    if can_tune and defer:
+                        # cold-miss policy (paper §7 production regime):
+                        # serve the analytic (cost-model) plan NOW; the
+                        # top-k partition race and the per-group tile
+                        # sweeps run via ``rerace`` on the background
+                        # executor, whose winner is hot-swapped into the
+                        # live dispatch table and persisted.
+                        if len(candidates) > 1:
+                            partition_source = "analytic"
+                        if len(candidates) > 1 or any(g.stitched
+                                                      for g in groups):
+                            race_ctx = _RaceContext(
+                                graph=graph, ctx=ctx, sig=sig, plan=plan,
+                                overrides=overrides, candidates=candidates,
+                                groups=groups,
+                                loaded_over_by_parts=loaded_over_by_parts,
+                                stitch_stats=stitch_stats, out_tree=None)
+                    elif can_tune and len(candidates) > 1:
+                        from .autotune import tune_partitions
+
+                        res = tune_partitions(
+                            graph, [c.groups for c in candidates],
+                            hw=self._hw, ctx=ctx)
+                        if res is not None:
+                            # commit the raced winner; its schedule *pins*
+                            # are left to the per-group measured sweep below
+                            # (the race's family swaps screen partitions,
+                            # they are not a substitute for the tile sweep).
+                            groups = candidates[res.index].groups
+                            partition_source = "measured"
+                            partition_index = res.index
+                            autotuned = True
+                    # a lone candidate stays model-sourced: "measured" is
+                    # never stamped without an actual race, so a later
+                    # process with a wider REPRO_STITCH_TOPK still races.
+                    group_overrides = [
+                        dict(loaded_over_by_parts.get(grp.parts, {}))
+                        for grp in groups]
+            else:
+                groups = [StitchGroup((p.members,)) for p in plan.patterns]
+                group_overrides = [{} for _ in groups]
+
         if race_ctx is not None:
             race_ctx.out_tree = out_tree
             race_ctx.shard = shard
@@ -851,8 +901,8 @@ class StitchedFunction:
             partition_source=partition_source,
             partition_index=partition_index,
             partition_candidates=partition_candidates,
-            tune_groups=tune_groups, t0=t0, out_tree=out_tree,
-            race_ctx=race_ctx, shard=shard)
+            tune_groups=tune_groups, t0=t0, phases=phases,
+            out_tree=out_tree, race_ctx=race_ctx, shard=shard)
 
     def _finalize(self, *, graph: Graph, ctx: CostContext, sig: str,
                   plan: FusionPlan, overrides: list[dict],
@@ -861,7 +911,8 @@ class StitchedFunction:
                   groups_from_cache: bool, stitch_stats,
                   partition_source: str, partition_index: int,
                   partition_candidates: int, tune_groups: bool, t0: float,
-                  out_tree, race_ctx: "_RaceContext | None",
+                  phases: "_Phases", out_tree,
+                  race_ctx: "_RaceContext | None",
                   shard=None) -> _Compiled:
         """Group tuning + emission + plan-cache store + report: the part
         of compilation shared by the cold path and the background
@@ -869,69 +920,72 @@ class StitchedFunction:
         from .cost_model import shard_enabled
 
         explicit_shard = shard is not None and shard.explicit
-        # groups convex one by one can still deadlock as kernels (A feeds
-        # B through one member, B feeds A through another): split until
-        # the partition schedules
-        legal = break_cycles(graph, groups, ctx)
-        if legal != groups:
-            over_of = {g.parts: o for g, o in zip(groups, group_overrides)}
-            group_overrides = [dict(over_of.get(g.parts, {})) for g in legal]
-            groups = legal
         # kill switch: the compile completes (the graph, tree and the
         # shard_map-wrapped baseline are all still needed to answer
         # calls correctly on the mesh) but pins the baseline rung below
         # and skips the cache store -- degrade, never re-key.
         shard_off = explicit_shard and not shard_enabled()
-
-        # ---- measured group tuning (paper: tune the stitching scheme) -----
-        # Stitched unions get their onepass/streaming phase split + tile
-        # measured (batch-compiled sweep); a cache hit that already holds
-        # a measured pin (override carries ``tuned``) is trusted, and a
-        # v2-format entry arrives with its group schedules dropped, so it
-        # re-tunes here instead of erroring.
         group_tuned = group_tuned_wins = 0
         tuned_fresh = False
-        if tune_groups and self._stitch_groups:
-            from .autotune import autotune_available, tune_group
+        with phases("search"):
+            # groups convex one by one can still deadlock as kernels (A
+            # feeds B through one member, B feeds A through another):
+            # split until the partition schedules
+            legal = break_cycles(graph, groups, ctx)
+            if legal != groups:
+                over_of = {g.parts: o
+                           for g, o in zip(groups, group_overrides)}
+                group_overrides = [dict(over_of.get(g.parts, {}))
+                                   for g in legal]
+                groups = legal
 
-            if autotune_available():
-                # isomorphic groups share one measured sweep (same
-                # rationale as emission dedup: struct_key equality means
-                # identical kernels up to constant values).
-                group_tuned_by_struct: dict[tuple, tuple] = {}
-                for gi, grp in enumerate(groups):
-                    if grp.anchors or not grp.stitched:
-                        # anchored groups carry their own fixed scheme
-                        # (the anchor kernel's grid); single patterns
-                        # are tune_pattern's job.
-                        continue
-                    gover = group_overrides[gi]
-                    analytic = _sched_of(ctx.best(grp.members))
-                    if gover.get("tuned"):
+            # ---- measured group tuning (paper: tune the stitching scheme)
+            # Stitched unions get their onepass/streaming phase split + tile
+            # measured (batch-compiled sweep); a cache hit that already holds
+            # a measured pin (override carries ``tuned``) is trusted, and a
+            # v2-format entry arrives with its group schedules dropped, so it
+            # re-tunes here instead of erroring.
+            if tune_groups and self._stitch_groups:
+                from .autotune import autotune_available, tune_group
+
+                if autotune_available():
+                    # isomorphic groups share one measured sweep (same
+                    # rationale as emission dedup: struct_key equality means
+                    # identical kernels up to constant values).
+                    group_tuned_by_struct: dict[tuple, tuple] = {}
+                    for gi, grp in enumerate(groups):
+                        if grp.anchors or not grp.stitched:
+                            # anchored groups carry their own fixed scheme
+                            # (the anchor kernel's grid); single patterns
+                            # are tune_pattern's job.
+                            continue
+                        gover = group_overrides[gi]
+                        analytic = _sched_of(ctx.best(grp.members))
+                        if gover.get("tuned"):
+                            group_tuned += 1
+                            pin = {k: v for k, v in gover.items()
+                                   if k != "tuned"}
+                            group_tuned_wins += pin != analytic
+                            continue
+                        skey = ctx.struct_key(grp.members)
+                        members = sorted(grp.members)
+                        hit = group_tuned_by_struct.get(skey)
+                        if hit is not None:
+                            # shared measured pin, remapped to this
+                            # sibling's node ids (recompute is id-specific)
+                            over = (_remap_override(hit[0], hit[1], members)
+                                    if hit[0] is not None else None)
+                        else:
+                            over = tune_group(graph, grp.parts, hw=self._hw,
+                                              ctx=ctx)
+                            group_tuned_by_struct[skey] = (over, members)
+                        if over is None:
+                            continue
                         group_tuned += 1
-                        pin = {k: v for k, v in gover.items()
-                               if k != "tuned"}
-                        group_tuned_wins += pin != analytic
-                        continue
-                    skey = ctx.struct_key(grp.members)
-                    members = sorted(grp.members)
-                    hit = group_tuned_by_struct.get(skey)
-                    if hit is not None:
-                        # shared measured pin, remapped to this
-                        # sibling's node ids (recompute is id-specific)
-                        over = (_remap_override(hit[0], hit[1], members)
-                                if hit[0] is not None else None)
-                    else:
-                        over = tune_group(graph, grp.parts, hw=self._hw,
-                                          ctx=ctx)
-                        group_tuned_by_struct[skey] = (over, members)
-                    if over is None:
-                        continue
-                    group_tuned += 1
-                    group_tuned_wins += over != analytic
-                    group_overrides[gi] = dict(over, tuned=True)
-                    tuned_fresh = True
-                autotuned = True
+                        group_tuned_wins += over != analytic
+                        group_overrides[gi] = dict(over, tuned=True)
+                        tuned_fresh = True
+                    autotuned = True
 
         pat_over = {pat.members: over
                     for pat, over in zip(plan.patterns, overrides)}
@@ -979,113 +1033,118 @@ class StitchedFunction:
                 if not donate_first:
                     first_idx = -1
 
-        # ---- emission (isomorphic groups emitted once, rebound after) -----
-        # Each group descends the fallback ladder on emission failure:
-        # stitched megakernel -> one fused kernel per member pattern ->
-        # plain packed (XLA) lowering of the union -> bare per-node
-        # schedule entries.  A degraded group never degrades its
-        # neighbors, and every rung taken is recorded on the report.
-        fallbacks: list[tuple[int, str, str]] = []
+        with phases("emit"):
+            # ---- emission (isomorphic groups emitted once, rebound after)
+            # Each group descends the fallback ladder on emission failure:
+            # stitched megakernel -> one fused kernel per member pattern ->
+            # plain packed (XLA) lowering of the union -> bare per-node
+            # schedule entries.  A degraded group never degrades its
+            # neighbors, and every rung taken is recorded on the report.
+            fallbacks: list[tuple[int, str, str]] = []
 
-        def _emit_fallback(gi: int, grp, exc: BaseException) -> list[Emitted]:
-            reason = f"{type(exc).__name__}: {exc}"
-            anchor_set = set(grp.anchors)
-            if anchor_set:
-                # anchored -> unanchored stitched: re-emit the exact
-                # pre-absorption composition (``grp.unanchored``); the
-                # bare anchor nodes fall out of every emitted union and
-                # replay as plain XLA schedule entries.
+            def _emit_fallback(gi: int, grp,
+                               exc: BaseException) -> list[Emitted]:
+                reason = f"{type(exc).__name__}: {exc}"
+                anchor_set = set(grp.anchors)
+                if anchor_set:
+                    # anchored -> unanchored stitched: re-emit the exact
+                    # pre-absorption composition (``grp.unanchored``); the
+                    # bare anchor nodes fall out of every emitted union and
+                    # replay as plain XLA schedule entries.
+                    try:
+                        ems = [emit_group(graph, tuple(sub), hw=self._hw,
+                                          ctx=ctx, group=gi)
+                               for sub in grp.unanchored
+                               if frozenset(x for p in sub for x in p)
+                               - anchor_set]
+                        fallbacks.append((gi, RUNG_STITCHED, reason))
+                        return ems
+                    except Exception:  # noqa: BLE001 - descend one more rung
+                        pass
+                parts = [p for p in grp.parts
+                         if not (len(p) == 1 and next(iter(p)) in anchor_set)]
+                if parts and (anchor_set or len(parts) > 1):
+                    try:
+                        ems = [emit_group(
+                                   graph, (part,), hw=self._hw, ctx=ctx,
+                                   schedule_override=(dict(pat_over.get(
+                                       frozenset(part), {})) or None),
+                                   group=gi)
+                               for part in parts]
+                        fallbacks.append((gi, RUNG_PATTERNS, reason))
+                        return ems
+                    except Exception:  # noqa: BLE001 - descend one more rung
+                        pass
                 try:
-                    ems = [emit_group(graph, tuple(sub), hw=self._hw,
-                                      ctx=ctx)
-                           for sub in grp.unanchored
-                           if frozenset(x for p in sub for x in p)
-                           - anchor_set]
-                    fallbacks.append((gi, RUNG_STITCHED, reason))
+                    ems = [emit_pattern(graph, frozenset(grp.members),
+                                        hw=self._hw, force_packed=True,
+                                        ctx=ctx, group=gi)]
+                    fallbacks.append((gi, RUNG_BASELINE, reason))
                     return ems
-                except Exception:  # noqa: BLE001 - descend one more rung
-                    pass
-            parts = [p for p in grp.parts
-                     if not (len(p) == 1 and next(iter(p)) in anchor_set)]
-            if parts and (anchor_set or len(parts) > 1):
-                try:
-                    ems = [emit_group(graph, (part,), hw=self._hw,
-                                      ctx=ctx,
-                                      schedule_override=(
-                                          dict(pat_over.get(frozenset(part),
-                                                            {})) or None))
-                           for part in parts]
-                    fallbacks.append((gi, RUNG_PATTERNS, reason))
-                    return ems
-                except Exception:  # noqa: BLE001 - descend one more rung
-                    pass
-            try:
-                ems = [emit_pattern(graph, frozenset(grp.members),
-                                    hw=self._hw, force_packed=True, ctx=ctx)]
-                fallbacks.append((gi, RUNG_BASELINE, reason))
-                return ems
-            except Exception as exc2:  # noqa: BLE001 - last rung: the
-                # members run as bare per-node schedule entries (the
-                # interpreter path _build_schedule keeps for uncovered
-                # nodes) -- slow, still correct.
-                fallbacks.append((gi, RUNG_BASELINE,
-                                  f"{reason}; packed emission also failed "
-                                  f"({type(exc2).__name__}: {exc2})"))
-                return []
+                except Exception as exc2:  # noqa: BLE001 - last rung: the
+                    # members run as bare per-node schedule entries (the
+                    # interpreter path _build_schedule keeps for uncovered
+                    # nodes) -- slow, still correct.
+                    fallbacks.append((gi, RUNG_BASELINE,
+                                      f"{reason}; packed emission also failed "
+                                      f"({type(exc2).__name__}: {exc2})"))
+                    return []
 
-        emit_cache: dict[tuple, tuple[Emitted, list[int]]] = {}
-        emitted: list[Emitted] = []
-        reused = 0
-        for gi, (grp, gover) in enumerate(zip(groups, group_overrides)):
-            union = grp.members
-            over = gover or (pat_over.get(grp.parts[0], {})
-                             if len(grp.parts) == 1 else {})
-            parts = tuple(tuple(sorted(p)) for p in grp.parts)
-            donate_into = donate_first if gi == first_idx else None
-            ekey = _emit_signature(graph, ctx, union, over,
-                                   anchors=grp.anchors) + (
-                ("donate", tuple(sorted(donate_first)))
-                if donate_into else ())
-            em = None
-            hit = emit_cache.get(ekey)
-            if hit is not None:
-                em = _rebind_emitted(graph, ctx, union, parts, *hit)
-                if em is not None:
-                    reused += 1
-            if em is None:
-                try:
-                    if explicit_shard:
-                        from .codegen import check_shard_emittable
+            emit_cache: dict[tuple, tuple[Emitted, list[int]]] = {}
+            emitted: list[Emitted] = []
+            reused = 0
+            for gi, (grp, gover) in enumerate(zip(groups, group_overrides)):
+                union = grp.members
+                over = gover or (pat_over.get(grp.parts[0], {})
+                                 if len(grp.parts) == 1 else {})
+                parts = tuple(tuple(sorted(p)) for p in grp.parts)
+                donate_into = donate_first if gi == first_idx else None
+                ekey = _emit_signature(graph, ctx, union, over,
+                                       anchors=grp.anchors) + (
+                    ("donate", tuple(sorted(donate_first)))
+                    if donate_into else ())
+                em = None
+                hit = emit_cache.get(ekey)
+                if hit is not None:
+                    em = _rebind_emitted(graph, ctx, union, parts, *hit,
+                                         group=gi)
+                    if em is not None:
+                        reused += 1
+                if em is None:
+                    try:
+                        if explicit_shard:
+                            from .codegen import check_shard_emittable
 
-                        # spec-sanity seam (also the shard_spec_fail
-                        # fault site): a bad layout degrades THIS group
-                        # down the ladder, siblings stay stitched.
-                        check_shard_emittable(graph, union, shard, gi)
-                    flt = _faults.fire("emit_fail", group=gi)
-                    if flt is not None:
-                        raise EmitError(f"injected emit_fail on group {gi}")
-                    if grp.anchors:
-                        flt = _faults.fire("anchor_emit_fail", group=gi)
+                            # spec-sanity seam (also the shard_spec_fail
+                            # fault site): a bad layout degrades THIS group
+                            # down the ladder, siblings stay stitched.
+                            check_shard_emittable(graph, union, shard, gi)
+                        flt = _faults.fire("emit_fail", group=gi)
                         if flt is not None:
                             raise EmitError(
-                                f"injected anchor_emit_fail on group {gi}")
-                    em = emit_group(graph, grp.parts, hw=self._hw,
-                                    ctx=ctx,
-                                    schedule_override=over or None,
-                                    donate_into=donate_into,
-                                    anchors=grp.anchors)
-                except Exception as exc:  # noqa: BLE001 - ladder below
-                    for fem in _emit_fallback(gi, grp, exc):
-                        fem._members = sorted(  # type: ignore[attr-defined]
-                            n for p in fem.parts for n in p)
-                        emitted.append(fem)
-                    continue
-                ext_set = set(em.ext_ids)
-                emit_cache[ekey] = (em, _ext_seen_order(graph, union,
-                                                        ext_set))
-            em._members = sorted(union)  # type: ignore[attr-defined]
-            emitted.append(em)
-        schedule = _build_schedule(graph, emitted)
+                                f"injected emit_fail on group {gi}")
+                        if grp.anchors:
+                            flt = _faults.fire("anchor_emit_fail", group=gi)
+                            if flt is not None:
+                                raise EmitError(
+                                    f"injected anchor_emit_fail on group {gi}")
+                        em = emit_group(graph, grp.parts, hw=self._hw,
+                                        ctx=ctx,
+                                        schedule_override=over or None,
+                                        donate_into=donate_into,
+                                        anchors=grp.anchors, group=gi)
+                    except Exception as exc:  # noqa: BLE001 - ladder below
+                        for fem in _emit_fallback(gi, grp, exc):
+                            fem._members = sorted(  # type: ignore[attr-defined]
+                                n for p in fem.parts for n in p)
+                            emitted.append(fem)
+                        continue
+                    ext_set = set(em.ext_ids)
+                    emit_cache[ekey] = (em, _ext_seen_order(graph, union,
+                                                            ext_set))
+                em._members = sorted(union)  # type: ignore[attr-defined]
+                emitted.append(em)
+            schedule = _build_schedule(graph, emitted)
         rung = (RUNG_ANCHORED if any(g.anchors for g in groups)
                 else RUNG_STITCHED)
         for _gi, r, _r in fallbacks:
@@ -1175,6 +1234,9 @@ class StitchedFunction:
             scratch_bytes=sum(e.scratch_bytes for e in emitted),
             scratch_naive_bytes=sum(e.scratch_naive_bytes for e in emitted),
             plan_time_s=plan_time,
+            trace_s=phases.s["trace"],
+            search_s=phases.s["search"],
+            emit_s=phases.s["emit"],
             patterns=[p.members for p in plan.patterns],
             plan_cache_hit=cached_hit,
             autotuned=autotuned,
@@ -1235,6 +1297,7 @@ class StitchedFunction:
 
         compiled = _Compiled(graph, plan, emitted, schedule, report,
                              out_tree, dispatch=self._dispatch,
+                             name=self.program,
                              donate=self._donate,
                              donate_argnums=self._donate_argnums,
                              verify_policy=VerifyPolicy.from_env(),
@@ -1290,30 +1353,34 @@ class StitchedFunction:
         if not autotune_available():
             return None
         t0 = time.perf_counter()
+        phases = _Phases()
         partition_source, partition_index, autotuned = "model", 0, False
         groups = rc.groups
-        if len(rc.candidates) > 1:
-            res = tune_partitions(rc.graph,
-                                  [c.groups for c in rc.candidates],
-                                  hw=self._hw, ctx=rc.ctx)
-            if res is not None:
-                groups = rc.candidates[res.index].groups
-                partition_source = "measured"
-                partition_index = res.index
-                autotuned = True
-        group_overrides = [dict(rc.loaded_over_by_parts.get(grp.parts, {}))
-                           for grp in groups]
-        new = self._finalize(
-            graph=rc.graph, ctx=rc.ctx, sig=rc.sig, plan=rc.plan,
-            overrides=rc.overrides, entry=None, cached_hit=False,
-            autotuned=autotuned, groups=groups,
-            group_overrides=group_overrides, groups_from_cache=False,
-            stitch_stats=rc.stitch_stats,
-            partition_source=partition_source,
-            partition_index=partition_index,
-            partition_candidates=len(rc.candidates),
-            tune_groups=True, t0=t0, out_tree=rc.out_tree, race_ctx=None,
-            shard=rc.shard)
+        with spans.span("stitch.build", program=self.program):
+            with phases("search"):
+                if len(rc.candidates) > 1:
+                    res = tune_partitions(rc.graph,
+                                          [c.groups for c in rc.candidates],
+                                          hw=self._hw, ctx=rc.ctx)
+                    if res is not None:
+                        groups = rc.candidates[res.index].groups
+                        partition_source = "measured"
+                        partition_index = res.index
+                        autotuned = True
+            group_overrides = [
+                dict(rc.loaded_over_by_parts.get(grp.parts, {}))
+                for grp in groups]
+            new = self._finalize(
+                graph=rc.graph, ctx=rc.ctx, sig=rc.sig, plan=rc.plan,
+                overrides=rc.overrides, entry=None, cached_hit=False,
+                autotuned=autotuned, groups=groups,
+                group_overrides=group_overrides, groups_from_cache=False,
+                stitch_stats=rc.stitch_stats,
+                partition_source=partition_source,
+                partition_index=partition_index,
+                partition_candidates=len(rc.candidates),
+                tune_groups=True, t0=t0, phases=phases,
+                out_tree=rc.out_tree, race_ctx=None, shard=rc.shard)
         if _faults.fire("swap_crash", signature=rc.sig) is not None:
             raise GuardError("injected swap_crash: hot-swap commit failed")
         if self._canary is not None:
@@ -1351,8 +1418,10 @@ class StitchedFunction:
         return [c.report for c in self._cache.values()]
 
     def __call__(self, *args, **kwargs):
-        compiled, flat = self._compile(args, kwargs)
-        return compiled(flat)
+        with spans.span("stitch.call", program=self.program):
+            with spans.span("stitch.lookup"):
+                compiled, flat = self._compile(args, kwargs)
+            return compiled(flat)
 
     def compiled(self, *args, **kwargs) -> _Compiled:
         """The compiled instance for these example args (tests/benchmarks)."""

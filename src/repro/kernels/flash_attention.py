@@ -88,7 +88,8 @@ def _attn_kernel(*refs, scale: float, causal: bool, sq: int, skv: int,
 
 def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
                     block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K,
-                    score_mod=None, score_args=()):
+                    score_mod=None, score_args=(),
+                    name: str = "flash_attention"):
     """q: [B, Hq, Sq, D]; k, v: [B, Hkv, Skv, D]; returns [B, Hq, Sq, D].
 
     ``score_mod`` (anchored stitching) rewrites the scaled score block
@@ -96,7 +97,8 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
     the f32 [blk_q, blk_k] tile and one 2D block per entry of
     ``score_args``.  Each score arg must be 4D with every dim either 1
     or the matching full extent of (B, Hq, Sq, Skv); size-1 dims are
-    pinned, full dims tile with the grid.
+    pinned, full dims tile with the grid.  ``name`` names the kernel in
+    the compiled program and the profiler's trace.
     """
     B, Hq, Sq, D = q.shape
     _, Hkv, Skv, _ = k.shape
@@ -154,6 +156,7 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
             pltpu.VMEM((blk_q, 1), jnp.float32),   # running denom
             pltpu.VMEM((blk_q, D), jnp.float32),   # output accumulator
         ],
+        name=name,
         interpret=kernels.interpret_mode(),
     )(q, k, v, *padded_scores)
     return out[:, :, :Sq, :]
@@ -175,5 +178,6 @@ def flash_decode(q, k_cache, v_cache, *, kv_len: int | None = None, scale=None,
         k_cache = k_cache[:, :, :eff, :]
         v_cache = v_cache[:, :, :eff, :]
     out = flash_attention(q[:, :, None, :], k_cache, v_cache, causal=False,
-                          scale=scale, block_q=1, block_k=min(block_k, eff))
+                          scale=scale, block_q=1, block_k=min(block_k, eff),
+                          name="flash_decode")
     return out[:, :, 0, :]

@@ -61,6 +61,7 @@ def layernorm_fwd(x, gamma, beta, *, eps: float = 1e-6, block_rows: int = 128):
             jax.ShapeDtypeStruct((Rp, 1), jnp.float32),
             jax.ShapeDtypeStruct((Rp, 1), jnp.float32),
         ],
+        name="layernorm",
         interpret=kernels.interpret_mode(),
     )(x2, gamma.reshape(1, C), beta.reshape(1, C))
     y = y[:R].reshape(orig_shape)
@@ -133,6 +134,7 @@ def _ln_bwd(x2, gamma, mean, rstd, dy2, *, block_rows: int = 128,
             jax.ShapeDtypeStruct((nb, C), jnp.float32),
             jax.ShapeDtypeStruct((nb, C), jnp.float32),
         ],
+        name="layernorm_bwd",
         interpret=kernels.interpret_mode(),
     )(x2, gamma.reshape(1, C).astype(jnp.float32), mean, rstd, dy2)
     return dx[:R], jnp.sum(dgp, axis=0), jnp.sum(dbp, axis=0)

@@ -59,7 +59,8 @@ def matmul_fused(pro_args: Sequence, rhs, epi_args: Sequence, *,
                  acc_dtype=jnp.float32, anchor_dtype=None,
                  prologue: Callable | None = None,
                  epilogue: Callable | None = None,
-                 block_m: int = DEFAULT_BLOCK_M):
+                 block_m: int = DEFAULT_BLOCK_M,
+                 name: str = "matmul_fused"):
     """Run ``epilogue(prologue(pro_blocks) @ rhs, epi_blocks)`` tiled over M.
 
     ``prologue`` maps the prologue operands' blocks to the (bm, K) lhs
@@ -68,7 +69,8 @@ def matmul_fused(pro_args: Sequence, rhs, epi_args: Sequence, *,
     the tuple of output blocks (None: the anchor result is the single
     output).  Roles describe how each operand folds into the kernel's
     2D view: prologue operands against (M, K), epilogue operands and
-    outputs against (M, N).
+    outputs against (M, N).  ``name`` names the kernel in the compiled
+    program and the profiler's trace.
     """
     bm = max(1, min(block_m, M))
     Mp = math.ceil(M / bm) * bm
@@ -109,6 +111,7 @@ def matmul_fused(pro_args: Sequence, rhs, epi_args: Sequence, *,
         in_specs=in_specs,
         out_specs=out_specs if len(out_specs) > 1 else out_specs[0],
         out_shape=out_shapes if len(out_shapes) > 1 else out_shapes[0],
+        name=name,
         interpret=kernels.interpret_mode(),
     )
 

@@ -44,6 +44,7 @@ def rmsnorm_fwd(x, gamma, *, eps: float = 1e-6, block_rows: int = 128):
             jax.ShapeDtypeStruct((Rp, C), x.dtype),
             jax.ShapeDtypeStruct((Rp, 1), jnp.float32),
         ],
+        name="rmsnorm",
         interpret=kernels.interpret_mode(),
     )(x2, gamma.reshape(1, C))
     return y[:R].reshape(orig_shape), rstd[:R]

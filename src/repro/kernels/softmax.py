@@ -37,6 +37,7 @@ def softmax_fwd(x, *, block_rows: int = 64):
         in_specs=[pl.BlockSpec((br, C), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, C), x.dtype),
+        name="softmax",
         interpret=kernels.interpret_mode(),
     )(x2)
     return y[:R].reshape(orig_shape)
@@ -69,6 +70,7 @@ def softmax_bwd(y, dy, *, block_rows: int = 64):
                   pl.BlockSpec((br, C), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, C), y.dtype),
+        name="softmax_bwd",
         interpret=kernels.interpret_mode(),
     )(y2, dy2)
     return dx[:R].reshape(orig_shape)

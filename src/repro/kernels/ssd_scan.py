@@ -125,6 +125,7 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 64):
             jax.ShapeDtypeStruct((b, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
+        name="ssd_scan",
         interpret=kernels.interpret_mode(),
     )(xc, dt_col, dt_row, Ah, Bc, Cc)
 

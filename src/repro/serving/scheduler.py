@@ -43,6 +43,7 @@ import numpy as np
 
 from repro.core.stitch import StitchedFunction, stitched_jit
 from repro.models.model import Model
+from repro.runtime import spans
 from repro.runtime.canary import CanaryController
 
 from .buckets import Buckets, pad_tokens
@@ -57,6 +58,7 @@ class Request:
     pos: int = 0                  # next cache position
     done: bool = False
     t_submit: float = 0.0         # perf_counter at submit (TTFT anchor)
+    t_admit: float = 0.0          # perf_counter at the start of its prefill
 
 
 def _pct(xs: list[float], q: float) -> float:
@@ -95,6 +97,8 @@ class ServeStats:
     # -- latency samples ------------------------------------------------------
     ttft_s: list = field(default_factory=list)   # submit -> first token
     wave_s: list = field(default_factory=list)   # per decode wave
+    #: (t_admit, t_admit - t_submit) per prefill: time in the queue
+    queue_wait: list = field(default_factory=list)
     steady_wall_s: float = 0.0  # wall in warm (already-compiled) calls
     steady_tokens: int = 0      # tokens produced by warm calls
 
@@ -127,11 +131,11 @@ class ServeStats:
         return _pct(self.ttft_s, 99)
 
     @property
-    def p50_tok_s(self) -> float:
+    def p50_wave_s(self) -> float:
         return _pct(self.wave_s, 50)
 
     @property
-    def p99_tok_s(self) -> float:
+    def p99_wave_s(self) -> float:
         return _pct(self.wave_s, 99)
 
     def summary(self) -> str:
@@ -141,8 +145,8 @@ class ServeStats:
                f"plan-cache {self.plan_cache_hits}h/"
                f"{self.plan_cache_misses}m | ttft p50/p99 "
                f"{self.p50_ttft_s * 1e3:.1f}/{self.p99_ttft_s * 1e3:.1f}ms"
-               f" | tok p50/p99 {self.p50_tok_s * 1e3:.1f}/"
-               f"{self.p99_tok_s * 1e3:.1f}ms | "
+               f" | wave p50/p99 {self.p50_wave_s * 1e3:.1f}/"
+               f"{self.p99_wave_s * 1e3:.1f}ms | "
                f"{self.tok_per_s:.1f} tok/s "
                f"({self.tok_per_s_steady:.1f} steady)")
         if self.canaried or self.canary_quarantines \
@@ -198,7 +202,9 @@ class ContinuousBatcher:
         self.cache = jax.tree_util.tree_map(
             lambda x: jnp.zeros((n_slots,) + x.shape, x.dtype), one)
 
-        def prefill_fn(p, t, c):
+        # the function names name the stitched programs
+        # (``stitched_prefill``, ``stitched_decode_wave``)
+        def prefill(p, t, c):
             return mdl.prefill(p, tokens=t, cache=c)
 
         # params are an explicit argument (NOT a closure): a closed-over
@@ -210,11 +216,13 @@ class ContinuousBatcher:
                                          kv_len=pos + 1)
             return logits[:, -1, : mdl.cfg.vocab_size], nc
 
-        wave = jax.vmap(decode_one, in_axes=(None, 0, 0, 0))
+        def decode_wave(p, cache, toks, poss):
+            return jax.vmap(decode_one, in_axes=(None, 0, 0, 0))(
+                p, cache, toks, poss)
 
         if self.stitched:
             self._prefill = stitched_jit(
-                prefill_fn, plan_cache=plan_cache, autotune=autotune,
+                prefill, plan_cache=plan_cache, autotune=autotune,
                 background=background, canary=self._canary)
             # donate exactly the cache leaves of the wave's flat
             # signature (params..., cache..., toks, poss): the stacked
@@ -222,14 +230,14 @@ class ContinuousBatcher:
             n_p = len(jax.tree_util.tree_leaves(params))
             n_c = len(jax.tree_util.tree_leaves(self.cache))
             self._decode_wave = stitched_jit(
-                wave, plan_cache=plan_cache, autotune=autotune,
+                decode_wave, plan_cache=plan_cache, autotune=autotune,
                 background=background, canary=self._canary,
                 donate_argnums=(tuple(range(n_p, n_p + n_c))
                                 if donate else None))
         else:
-            self._prefill = jax.jit(prefill_fn)
+            self._prefill = jax.jit(prefill)
             self._decode_wave = jax.jit(
-                wave, donate_argnums=(1,) if donate else ())
+                decode_wave, donate_argnums=(1,) if donate else ())
 
     # -- client API -----------------------------------------------------------
     def submit(self, prompt: np.ndarray, max_new: int = 16) -> int:
@@ -326,56 +334,65 @@ class ContinuousBatcher:
                 self.slots[i] = req
 
     def _prefill_slot(self, i: int, req: Request) -> None:
-        t0 = time.perf_counter()
+        t0 = req.t_admit = time.perf_counter()
+        self.stats.queue_wait.append((t0, t0 - req.t_submit))
         true_len = len(req.prompt)
-        if self._pad_prompts:
-            plen = self.buckets.pad_len(true_len, cap=self.max_len)
-            toks = pad_tokens(req.prompt, plen, pad_id=self.pad_id)
-        else:
-            toks = req.prompt
-        one = self.mdl.init_cache(1, self.max_len)
-        logits, filled = self._prefill(self.params, toks[None, :], one)
-        self.cache = jax.tree_util.tree_map(
-            lambda st, c: st.at[i].set(c), self.cache, filled)
-        # the *true* last prompt position: the causal mask makes the
-        # padded tail invisible to it.
-        first = int(jnp.argmax(
-            logits[0, true_len - 1, : self.mdl.cfg.vocab_size]))
-        dt = time.perf_counter() - t0
-        self._note_call(("prefill", int(toks.shape[-1])), dt, tokens=1)
-        req.out.append(first)
-        req.pos = true_len
-        self.stats.prefills += 1
-        self.stats.tokens_out += 1
-        self.stats.ttft_s.append(time.perf_counter() - req.t_submit)
-        self._check_done(req)
+        with spans.span("serve.prefill", rid=req.rid, slot=i,
+                        plen=true_len):
+            if self._pad_prompts:
+                plen = self.buckets.pad_len(true_len, cap=self.max_len)
+                toks = pad_tokens(req.prompt, plen, pad_id=self.pad_id)
+            else:
+                toks = req.prompt
+            with spans.span("prefill.cache_init"):
+                one = self.mdl.init_cache(1, self.max_len)
+            logits, filled = self._prefill(self.params, toks[None, :], one)
+            with spans.span("prefill.cache_write"):
+                self.cache = jax.tree_util.tree_map(
+                    lambda st, c: st.at[i].set(c), self.cache, filled)
+            with spans.span("prefill.sample"):
+                # the *true* last prompt position: the causal mask makes
+                # the padded tail invisible to it.
+                first = int(jnp.argmax(
+                    logits[0, true_len - 1, : self.mdl.cfg.vocab_size]))
+            dt = time.perf_counter() - t0
+            self._note_call(("prefill", int(toks.shape[-1])), dt, tokens=1)
+            req.out.append(first)
+            req.pos = true_len
+            self.stats.prefills += 1
+            self.stats.tokens_out += 1
+            self.stats.ttft_s.append(time.perf_counter() - req.t_submit)
+            self._check_done(req)
 
     def _decode_step(self) -> None:
-        toks = np.zeros((self.n_slots, 1, 1), np.int32)
-        poss = np.zeros((self.n_slots,), np.int32)
-        active = []
-        for i, req in enumerate(self.slots):
-            if req is None or req.done:
-                continue
-            toks[i, 0, 0] = req.out[-1]
-            poss[i] = req.pos
-            active.append(i)
+        active = [i for i, req in enumerate(self.slots)
+                  if req is not None and not req.done]
         if not active:
             return
-        t0 = time.perf_counter()
-        logits, self.cache = self._decode_wave(
-            self.params, self.cache, jnp.asarray(toks), jnp.asarray(poss))
-        nxt = np.asarray(jnp.argmax(logits[:, 0, :], axis=-1))
-        dt = time.perf_counter() - t0
-        self.stats.decode_waves += 1
-        self.stats.wave_s.append(dt)
-        self._note_call(("decode",), dt, tokens=len(active))
-        for i in active:
-            req = self.slots[i]
-            req.out.append(int(nxt[i]))
-            req.pos += 1
-            self.stats.tokens_out += 1
-            self._check_done(req)
+        with spans.span("serve.wave", n_active=len(active)):
+            t0 = time.perf_counter()
+            with spans.span("wave.inputs"):
+                toks = np.zeros((self.n_slots, 1, 1), np.int32)
+                poss = np.zeros((self.n_slots,), np.int32)
+                for i in active:
+                    toks[i, 0, 0] = self.slots[i].out[-1]
+                    poss[i] = self.slots[i].pos
+                toks, poss = jnp.asarray(toks), jnp.asarray(poss)
+            logits, self.cache = self._decode_wave(
+                self.params, self.cache, toks, poss)
+            with spans.span("wave.sample"):
+                nxt = np.asarray(jnp.argmax(logits[:, 0, :], axis=-1))
+            dt = time.perf_counter() - t0
+            self.stats.decode_waves += 1
+            self.stats.wave_s.append(dt)
+            self._note_call(("decode",), dt, tokens=len(active))
+            with spans.span("wave.retire"):
+                for i in active:
+                    req = self.slots[i]
+                    req.out.append(int(nxt[i]))
+                    req.pos += 1
+                    self.stats.tokens_out += 1
+                    self._check_done(req)
 
     def _check_done(self, req: Request) -> None:
         if len(req.out) >= req.max_new or \

@@ -13,6 +13,7 @@ imports this file), and the compiles stay in the test's own process.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -95,6 +96,22 @@ def test_stitched_chain_compiles(spec, dtype, schedule):
     sched, n = _compile_stitched(_residual_rmsnorm_gelu, args, hw=hw)
     assert sched and set(sched) == {schedule}, sched
     assert n >= 1
+
+
+def test_names_survive_a_v5e_compile(spec):
+    """The stitched program's module, a stitch group's kernel and a hand
+    kernel keep their names through the chip's compiler: the names the
+    profiler's trace shows."""
+    args = (spec((2048, D_MODEL), jnp.float32),
+            spec((2048, D_MODEL), jnp.float32), spec((D_MODEL,), jnp.float32))
+    c = stitched_jit(_residual_rmsnorm_gelu).compiled(*args)
+    text = c._jitted.lower(*args).compile().as_text()
+    assert "HloModule jit_stitched__residual_rmsnorm_gelu" in text
+    assert re.search(r"%stitch_onepass_0(\.\d+)? = .*tpu_custom_call", text)
+    hand = jax.jit(lambda x, g: ops.rmsnorm(x, g)).lower(
+        spec((4, 128, D_MODEL), jnp.float32),
+        spec((D_MODEL,), jnp.float32)).compile().as_text()
+    assert re.search(r"%rmsnorm(\.\d+)? = .*tpu_custom_call", hand)
 
 
 @DTYPES
