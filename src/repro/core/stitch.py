@@ -8,7 +8,7 @@ Usage::
 The wrapper is a pure JAX-traceable function, so it composes with jit /
 grad / vmap / pjit: stitched kernels appear as pallas_call ops inside a
 larger program, exactly like the paper's fusions live inside an XLA
-module.  Plans are cached per static shape/dtype signature in-process
+module.  Plans are cached per argument tree and leaf shapes/dtypes in-process
 and, when ``$REPRO_PLAN_CACHE`` (or ``plan_cache=``) points at a
 directory, persistently across processes (the paper's
 tune-once-run-many model; dynamic shapes share its §7.5 limitation).
@@ -68,6 +68,26 @@ from .plan_cache import PlanCache, entry_format_for, \
 from .planner import PlanStats, make_plan, plan_stats
 from .stitcher import absorb_anchors, break_cycles, search_groups
 from .tracer import bind_node, trace, trace_with_shape
+
+
+#: ``str(jnp.result_type(dtype))`` by ``(dtype, jax_enable_x64)``: the
+#: name canonicalises (a float64 leaf keys as float32 with x64 off) and
+#: costs microseconds to compute.  None marks an extended dtype (a PRNG
+#: key), which ``jnp.result_type`` names only from a value.
+_DTYPE_KEYS: dict[tuple, str | None] = {}
+
+
+def _dtype_key(dtype, x64: bool) -> str | None:
+    try:
+        return _DTYPE_KEYS[dtype, x64]
+    except KeyError:
+        pass
+    try:
+        name = str(jnp.result_type(dtype))
+    except TypeError:
+        name = None
+    _DTYPE_KEYS[dtype, x64] = name
+    return name
 
 
 @dataclass
@@ -640,6 +660,8 @@ class StitchedFunction:
                         else canary if canary is not None
                         else CanaryController.from_env(self._plan_cache))
         self._cache: dict[tuple, _Compiled] = {}
+        #: leaves keyed by the slow path of ``_signature`` so far
+        self.key_fallback_leaves = 0
         self._compile_lock = threading.Lock()
         self._swap_lock = threading.Lock()
 
@@ -657,14 +679,32 @@ class StitchedFunction:
             return None
         return ShardCtx.ambient()
 
-    def _signature(self, flat_args) -> tuple:
-        base = tuple((tuple(np.shape(a)), str(jnp.result_type(a)))
-                     for a in flat_args)
+    def _signature(self, flat_args, in_tree) -> tuple:
+        """The dispatch-table key: the argument tree, each leaf's shape
+        and ``jnp.result_type`` name, and the mesh.  A strongly typed
+        array leaf reads its shape and dtype as attributes and its name
+        from ``_DTYPE_KEYS``; any other leaf (a Python scalar, a weakly
+        typed array, a PRNG key) is keyed by ``np.shape`` and
+        ``jnp.result_type`` on the value and counted in
+        ``key_fallback_leaves``."""
+        x64 = jax.config.jax_enable_x64
+        key = [in_tree]
+        for a in flat_args:
+            dtype = getattr(a, "dtype", None)
+            name = (None if dtype is None or getattr(a, "weak_type", False)
+                    else _dtype_key(dtype, x64))
+            if name is None:
+                self.key_fallback_leaves += 1
+                key.append((tuple(np.shape(a)), str(jnp.result_type(a))))
+            else:
+                key.append((a.shape, name))
         # the ambient mesh can change between calls (serving enters /
         # leaves ``use_mesh``): a sharded compile must never be served
         # to an unsharded call, so the mesh keys the dispatch table too.
         shard = self._shard_ctx()
-        return base + ((shard.mesh_key(),) if shard is not None else ())
+        if shard is not None:
+            key.append(shard.mesh_key())
+        return tuple(key)
 
     def _load_cached_plan(self, graph: Graph, sig: str
                           ) -> tuple[FusionPlan, list[dict], dict] | None:
@@ -681,7 +721,7 @@ class StitchedFunction:
 
     def _compile(self, args, kwargs) -> tuple[_Compiled, Any]:
         flat, in_tree = jax.tree_util.tree_flatten((args, kwargs))
-        key = self._signature(flat)
+        key = self._signature(flat, in_tree)
         compiled = self._cache.get(key)
         if compiled is not None:
             return compiled, flat
