@@ -40,6 +40,21 @@ class ArchConfig:
     conv_width: int = 4
     # hybrid (Zamba2): shared attention block applied every N layers
     attn_every: int = 0
+    # hybrid by pattern (Granite-4.0-H): one letter per layer, "M" a
+    # Mamba-2 mixer, "A" attention, each with its own weights and an FFN
+    # block (routed experts plus a shared MLP) after it.  "" -> Zamba's
+    # shared block every ``attn_every`` layers.
+    layer_pattern: str = ""
+    experts_held: int = 0       # experts this chip holds (0: all)
+    expert_offset: int = 0      # index of the first expert held
+    d_ff_shared: int = 0        # shared MLP width of the FFN block
+    # muP multipliers (1.0: none): embedding output, each sub-block's
+    # output before its residual add, logits divided by
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: float = 0.0   # softmax scale; 0 -> 1/sqrt(Dh)
+    position_embedding: str = "rope"    # rope | nope
     # modality frontends (stub inputs per task spec)
     frontend: str = "none"      # none | audio | vision
     frontend_dim: int = 0       # audio: conv-stem feature dim
@@ -52,6 +67,13 @@ class ArchConfig:
     # capability flags (derived from family; see DESIGN.md §Arch-applicability)
     supports_decode: bool = True
     supports_long_context: bool = False  # sub-quadratic decode at 500k
+
+    def __post_init__(self):
+        if self.layer_pattern and (len(self.layer_pattern) != self.n_layers
+                                   or set(self.layer_pattern) - set("MA")):
+            raise ValueError(f"layer_pattern {self.layer_pattern!r} needs "
+                             f"one of 'M' or 'A' per layer "
+                             f"({self.n_layers})")
 
     @property
     def resolved_head_dim(self) -> int:
@@ -73,8 +95,13 @@ class ArchConfig:
     def ssm_heads(self) -> int:
         return self.resolved_d_inner // self.ssm_head_dim
 
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held or self.n_experts
+
     def reduced(self, **overrides) -> "ArchConfig":
-        """Tiny same-family config for CPU smoke tests."""
+        """Tiny same-family config for CPU smoke tests.  A layer pattern
+        keeps one attention layer between two Mamba-2 layers."""
         small = dict(
             n_layers=min(self.n_layers, 2 if self.attn_every == 0 else 4),
             d_model=128,
@@ -94,6 +121,11 @@ class ArchConfig:
             n_vision_tokens=8 if self.frontend == "vision" else 0,
             max_seq=256,
         )
+        if self.layer_pattern:
+            small.update(n_layers=3, layer_pattern="MAM",
+                         experts_held=min(self.experts_held, 2),
+                         expert_offset=0,
+                         d_ff_shared=96 if self.d_ff_shared else 0)
         small.update(overrides)
         return dataclasses.replace(self, **small)
 
@@ -104,7 +136,7 @@ class ArchConfig:
 ARCH_IDS = [
     "zamba2-1.2b", "internvl2-26b", "deepseek-67b", "mistral-nemo-12b",
     "llama3.2-3b", "gemma-7b", "hubert-xlarge", "mamba2-370m",
-    "granite-moe-1b-a400m", "granite-moe-3b-a800m",
+    "granite-moe-1b-a400m", "granite-moe-3b-a800m", "granite-4.0-h-small",
 ]
 
 _MODULE_OF = {a: a.replace("-", "_").replace(".", "_") for a in ARCH_IDS}

@@ -37,6 +37,7 @@ cutting HBM pressure at decode batch sizes.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 import hashlib
@@ -131,6 +132,9 @@ class StitchReport:
     recompute_bytes_freed: int = 0   # VMEM scratch bytes those flips elide
     # -- no silent caps + cache observability --------------------------------
     caps_hit: dict = field(default_factory=dict)  # guardrail -> truncations
+    #: OPAQUE primitive -> nodes of it the planner met (each is a hard
+    #: group boundary: ``{"top_k": 10}`` splits a routing chain per layer)
+    opaque_prims: dict = field(default_factory=dict)
     plan_cache_hits: int = 0         # this cache instance's load hits
     plan_cache_misses: int = 0       # ...and misses (absent/corrupt entries)
     # -- SPMD-aware stitching (one plan replayed per shard) ------------------
@@ -1310,6 +1314,9 @@ class StitchedFunction:
             mesh_axes=(shard.mesh_key() if shard is not None else ()),
             n_collective=sum(1 for n in graph.nodes.values()
                              if n.kind is OpKind.COLLECTIVE),
+            opaque_prims=dict(collections.Counter(
+                n.prim for n in graph.nodes.values()
+                if n.kind is OpKind.OPAQUE and n.prim != "tuple_get")),
             collective_boundaries=getattr(stitch_stats,
                                           "collective_boundaries", 0)
             if stitch_stats else 0,

@@ -19,11 +19,19 @@ def _rms_kernel(x_ref, g_ref, y_ref, rstd_ref, *, eps: float):
     rstd_ref[...] = rstd.astype(rstd_ref.dtype)
 
 
+#: VMEM bytes the row blocks may take: the input and output blocks, each
+#: double-buffered, stay well inside the chip's 16 MiB scoped limit
+BLOCK_VMEM_BYTES = 8 << 20
+
+
 def rmsnorm_fwd(x, gamma, *, eps: float = 1e-6, block_rows: int = 128):
     orig_shape = x.shape
     C = x.shape[-1]
     R = x.size // C
     x2 = x.reshape(R, C)
+    while block_rows > 8 and \
+            4 * block_rows * C * x.dtype.itemsize > BLOCK_VMEM_BYTES:
+        block_rows //= 2        # wide rows (Granite's 8192): fewer per block
     br = max(1, min(block_rows, R))
     Rp = math.ceil(R / br) * br
     if Rp != R:

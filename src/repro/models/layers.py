@@ -111,11 +111,14 @@ def attn_apply(cfg: ArchConfig, p, x, *, fm: FusionMode, positions,
     q = (x @ p["wq"]).reshape(B, S, Hq, Dh).transpose(0, 2, 1, 3)
     k = (xk @ p["wk"]).reshape(B, S, Hkv, Dh).transpose(0, 2, 1, 3)
     v = (xk @ p["wv"]).reshape(B, S, Hkv, Dh).transpose(0, 2, 1, 3)
-    q, k = rope(q, k, positions, cfg.rope_theta)
+    if cfg.position_embedding != "nope":
+        q, k = rope(q, k, positions, cfg.rope_theta)
     q = constrain(q, "act_bhsd")
+    scale = cfg.attention_multiplier or None
 
     if cache is None:
-        o = ops.attention(q, k, v, causal=cfg.causal, use_pallas=fm.use_pallas)
+        o = ops.attention(q, k, v, causal=cfg.causal, scale=scale,
+                          use_pallas=fm.use_pallas)
         new_cache = None
     elif S > 1:  # prefill into pre-allocated cache
         kc = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
@@ -123,7 +126,8 @@ def attn_apply(cfg: ArchConfig, p, x, *, fm: FusionMode, positions,
         vc = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
                                           (0, 0, 0, 0))
         kc, vc = constrain(kc, "kv_cache"), constrain(vc, "kv_cache")
-        o = ops.attention(q, k, v, causal=cfg.causal, use_pallas=fm.use_pallas)
+        o = ops.attention(q, k, v, causal=cfg.causal, scale=scale,
+                          use_pallas=fm.use_pallas)
         new_cache = {"k": kc, "v": vc}
     else:        # decode one token
         kc = jax.lax.dynamic_update_slice(
@@ -133,6 +137,7 @@ def attn_apply(cfg: ArchConfig, p, x, *, fm: FusionMode, positions,
         kc, vc = constrain(kc, "kv_cache"), constrain(vc, "kv_cache")
         eff = kv_len if kv_len is not None else kc.shape[2]
         o = ops.decode_attention(q[:, :, 0, :], kc, vc, kv_len=eff,
+                                 scale=scale,
                                  use_pallas=fm.use_pallas)[:, :, None, :]
         new_cache = {"k": kc, "v": vc}
 
@@ -149,9 +154,9 @@ def attn_cache_init(cfg: ArchConfig, batch: int, max_len: int, dtype):
 # ---------------------------------------------------------------------------
 # MLP (SwiGLU / GeGLU / plain GELU)
 # ---------------------------------------------------------------------------
-def mlp_init(cfg: ArchConfig, key, dtype):
+def mlp_init(cfg: ArchConfig, key, dtype, d_ff: int | None = None):
     k1, k2, k3 = jax.random.split(key, 3)
-    d, ff = cfg.d_model, cfg.d_ff
+    d, ff = cfg.d_model, d_ff or cfg.d_ff
     if cfg.activation == "gelu_mlp":
         return {"w_up": _dense(k1, d, ff, dtype), "w_down": _dense(k2, ff, d, dtype)}
     return {"w_gate": _dense(k1, d, ff, dtype),
@@ -315,6 +320,48 @@ def _moe_sort_dispatch(cfg: ArchConfig, p, x, gate_vals, gate_idx):
     out_tok = out_tok * (flat_g * keep).astype(ye.dtype)[..., None]
     y = jnp.sum(out_tok.reshape(G, Tg, k, d), axis=2)
     return y
+
+
+def moe_share_init(cfg: ArchConfig, key, dtype):
+    """A router over all ``n_experts`` and the weights of the experts
+    this chip holds."""
+    k0, k1, k2, k3 = jax.random.split(key, 4)
+    d, ff, held = cfg.d_model, cfg.d_ff, cfg.n_experts_held
+    scale = 1.0 / math.sqrt(d)
+    return {
+        "router": _dense(k0, d, cfg.n_experts, dtype),
+        "w_gate": (jax.random.normal(k1, (held, d, ff), jnp.float32)
+                   * scale).astype(dtype),
+        "w_up": (jax.random.normal(k2, (held, d, ff), jnp.float32)
+                 * scale).astype(dtype),
+        "w_down": (jax.random.normal(k3, (held, ff, d), jnp.float32)
+                   / math.sqrt(ff)).astype(dtype),
+    }
+
+
+def moe_share_apply(cfg: ArchConfig, p, x, fm: FusionMode):
+    """The part of a routed expert layer's output that the experts held
+    here give (expert parallelism without its exchange).  x: [B, S, d].
+
+    The router scores all ``n_experts``; a token's gates are the softmax
+    over its ``top_k`` logits (Granite's ``TopKGating``).  Held expert
+    ``expert_offset + j`` adds ``gate * FFN_j(x)`` for every token routed
+    to it: no capacity, nothing dropped.  The first cut is dense: every
+    held expert runs on every token with a zero gate where it was not
+    chosen, so each held expert's weights are read once per call.
+    """
+    B, S, d = x.shape
+    xt = x.reshape(B * S, d)
+    top, idx = jax.lax.top_k((xt @ p["router"]).astype(jnp.float32),
+                             cfg.top_k)                       # [T, k]
+    gates = jax.nn.softmax(top, axis=-1)
+    held = cfg.expert_offset + jnp.arange(p["w_gate"].shape[0])
+    gate = jnp.sum(jnp.where(idx[:, :, None] == held, gates[:, :, None],
+                             0.0), axis=1)                    # [T, held]
+    h = _act(cfg.activation, jnp.einsum("td,edf->tef", xt, p["w_gate"])) \
+        * jnp.einsum("td,edf->tef", xt, p["w_up"])
+    h = h * gate[:, :, None].astype(h.dtype)
+    return jnp.einsum("tef,efd->td", h, p["w_down"]).reshape(B, S, d)
 
 
 # ---------------------------------------------------------------------------

@@ -1,9 +1,13 @@
 """Unified model facade over all assigned architecture families.
 
 Families: dense, vlm (dense + vision-token stub), encoder (bidirectional),
-moe, ssm (Mamba2), hybrid (Zamba2: Mamba2 backbone + shared attention
-block every ``attn_every`` layers, weights shared across applications,
-input = concat(hidden, initial embedding) per the Zamba design).
+moe, ssm (Mamba2), hybrid.  A hybrid stack is either Zamba2's (Mamba2
+backbone + shared attention block every ``attn_every`` layers, weights
+shared across applications, input = concat(hidden, initial embedding))
+or driven by ``layer_pattern`` (Granite-4.0-H: each layer a Mamba2 or an
+attention mixer with its own weights, then an FFN block of routed
+experts plus a shared MLP, both sub-blocks scaled before their residual
+add).
 
 Homogeneous stacks run under ``lax.scan`` with stacked params (compile
 time stays flat in depth — 95-layer deepseek lowers as one scanned
@@ -81,6 +85,39 @@ def block_apply(cfg: ArchConfig, p, h, *, fm: FusionMode, positions,
     return constrain(h, "act_btd"), new_cache, aux
 
 
+def pattern_block_init(cfg: ArchConfig, kind: str, key, dtype):
+    """One layer of a ``layer_pattern`` stack: ``kind`` "M" or "A"."""
+    k1, k2, k3 = jax.random.split(key, 3)
+    mixer = ({"attn": L.attn_init(cfg, k1, dtype)} if kind == "A" else
+             {"mamba": L.mamba_init(cfg, k1, dtype)})
+    return {"norm1": L.norm_init(cfg, dtype), **mixer,
+            "norm2": L.norm_init(cfg, dtype),
+            "moe": L.moe_share_init(cfg, k2, dtype),
+            "shared_mlp": L.mlp_init(cfg, k3, dtype, d_ff=cfg.d_ff_shared)}
+
+
+def pattern_block_apply(cfg: ArchConfig, p, h, *, fm: FusionMode, positions,
+                        cache=None, cache_pos=None, kv_len=None):
+    """norm -> mixer -> scaled residual, norm -> routed share + shared MLP
+    -> scaled residual.  Returns (h, new_cache)."""
+    x = L.norm_apply(cfg, p["norm1"], h, fm)
+    if "attn" in p:
+        y, c = L.attn_apply(cfg, p["attn"], x, fm=fm, positions=positions,
+                            cache=None if cache is None else cache["attn"],
+                            cache_pos=cache_pos, kv_len=kv_len)
+        new_cache = None if cache is None else {"attn": c}
+    else:
+        y, c = L.mamba_apply(cfg, p["mamba"], x, fm=fm,
+                             cache=None if cache is None else cache["mamba"],
+                             cache_pos=cache_pos)
+        new_cache = None if cache is None else {"mamba": c}
+    h = h + y * cfg.residual_multiplier
+    x = L.norm_apply(cfg, p["norm2"], h, fm)
+    y = L.moe_share_apply(cfg, p["moe"], x, fm) \
+        + L.mlp_apply(cfg, p["shared_mlp"], x, fm)
+    return constrain(h + y * cfg.residual_multiplier, "act_btd"), new_cache
+
+
 def block_cache_init(cfg: ArchConfig, batch: int, max_len: int, dtype):
     if cfg.family in ("ssm", "hybrid"):
         return {"mamba": L.mamba_cache_init(cfg, batch, dtype)}
@@ -122,6 +159,9 @@ class Model:
             params["blocks"] = jax.vmap(
                 lambda k: block_init(cfg, k, dtype))(
                     jnp.stack(keys[: cfg.n_layers]))
+        elif cfg.layer_pattern:  # hybrid: unrolled, each layer its own
+            params["blocks"] = [pattern_block_init(cfg, kind, keys[i], dtype)
+                                for i, kind in enumerate(cfg.layer_pattern)]
         else:  # hybrid: unrolled mamba list + shared attention block
             params["blocks"] = [block_init(cfg, keys[i], dtype)
                                 for i in range(cfg.n_layers)]
@@ -141,6 +181,8 @@ class Model:
             h = frames.astype(self.param_dtype) @ params["feat_proj"]["w"]
         else:
             h = jnp.take(params["embed"], tokens, axis=0)
+        if cfg.embedding_multiplier != 1.0:
+            h = h * cfg.embedding_multiplier
         if cfg.frontend == "vision" and vision_embeds is not None:
             nv = vision_embeds.shape[1]
             h = jnp.concatenate(
@@ -175,6 +217,16 @@ class Model:
             (h, aux), new_cache = jax.lax.scan(
                 body_fn, (h, jnp.zeros((), jnp.float32)),
                 (params["blocks"], cache), unroll=self.scan_unroll)
+        elif cfg.layer_pattern:  # hybrid by pattern (unrolled)
+            aux = jnp.zeros((), jnp.float32)
+            new_cache = {"blocks": []} if cache is not None else None
+            for i in range(cfg.n_layers):
+                h, nc = pattern_block_apply(
+                    cfg, params["blocks"][i], h, fm=fm, positions=positions,
+                    cache=None if cache is None else cache["blocks"][i],
+                    cache_pos=cache_pos, kv_len=kv_len)
+                if cache is not None:
+                    new_cache["blocks"].append(nc)
         else:  # hybrid (unrolled)
             aux = jnp.zeros((), jnp.float32)
             emb0 = h
@@ -208,6 +260,8 @@ class Model:
 
         h = L.norm_apply(cfg, params["final_norm"], h, fm)
         logits = h @ params["lm_head"]
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
         if cfg.padded_vocab != cfg.vocab_size:  # mask pad columns to -inf
             col = jax.lax.broadcasted_iota(jnp.int32, (cfg.padded_vocab,), 0)
             logits = jnp.where(col < cfg.vocab_size, logits, -1e30)
@@ -238,6 +292,12 @@ class Model:
             one = block_cache_init(cfg, batch, max_len, dtype)
             return jax.tree_util.tree_map(
                 lambda x: jnp.zeros((cfg.n_layers,) + x.shape, x.dtype), one)
+        if cfg.layer_pattern:   # KV for attention layers, else Mamba state
+            return {"blocks": [
+                {"attn": L.attn_cache_init(cfg, batch, max_len, dtype)}
+                if kind == "A" else
+                {"mamba": L.mamba_cache_init(cfg, batch, dtype)}
+                for kind in cfg.layer_pattern]}
         n_apps = len([i for i in range(cfg.n_layers)
                       if cfg.attn_every and i % cfg.attn_every == 0])
         return {

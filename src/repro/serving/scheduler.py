@@ -201,6 +201,8 @@ class ContinuousBatcher:
         one = mdl.init_cache(1, max_len)
         self.cache = jax.tree_util.tree_map(
             lambda x: jnp.zeros((n_slots,) + x.shape, x.dtype), one)
+        #: leaves a prefill makes and writes (the cache-span stat)
+        self._cache_leaves = len(jax.tree_util.tree_leaves(one))
 
         # the function names name the stitched programs
         # (``stitched_prefill``, ``stitched_decode_wave``)
@@ -344,10 +346,12 @@ class ContinuousBatcher:
                 toks = pad_tokens(req.prompt, plen, pad_id=self.pad_id)
             else:
                 toks = req.prompt
-            with spans.span("prefill.cache_init"):
+            with spans.span("prefill.cache_init",
+                            leaves=self._cache_leaves):
                 one = self.mdl.init_cache(1, self.max_len)
             logits, filled = self._prefill(self.params, toks[None, :], one)
-            with spans.span("prefill.cache_write"):
+            with spans.span("prefill.cache_write",
+                            leaves=self._cache_leaves):
                 self.cache = jax.tree_util.tree_map(
                     lambda st, c: st.at[i].set(c), self.cache, filled)
             with spans.span("prefill.sample"):

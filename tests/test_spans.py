@@ -105,6 +105,8 @@ def test_prefill_and_wave_spans_nest_as_named(batcher, monkeypatch):
         ("prefill.cache_init", ()), CALL, ("prefill.cache_write", ()),
         ("prefill.sample", ())))
     assert prefill[1] == {"rid": req.rid, "slot": 1, "plen": 7}
+    leaves = {"leaves": len(jax.tree_util.tree_leaves(cb.cache))}
+    assert prefill[2][0][1] == leaves and prefill[2][2][1] == leaves
     assert prefill[2][1][1] == {"program": "stitched_prefill"}
     assert shape(wave) == ("serve.wave", (
         ("wave.inputs", ()), CALL, ("wave.sample", ()),

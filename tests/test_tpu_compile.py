@@ -122,6 +122,27 @@ def test_rmsnorm_compiles(spec, dtype):
 
 
 @DTYPES
+def test_rmsnorm_compiles_at_granite_width(spec, dtype):
+    """The gated norm of a 1024-token granite-4.0-h-small prefill: rows
+    of d_inner 8192, whose 128-row blocks would overrun scoped VMEM."""
+    f = jax.jit(lambda x, g: ops.rmsnorm(x, g))
+    assert _kernels_in(f.lower(spec((1, 1024, 8192), dtype),
+                               spec((8192,), dtype))) >= 1
+
+
+def test_gqa_attention_compiles_at_granite_width(spec):
+    """granite-4.0-h-small's prefill attention: 32 query heads over 8
+    key/value heads of 128, the configured 1/128 scale."""
+    B, S = 1, 1024
+    f = jax.jit(functools.partial(flash_attention, causal=True,
+                                  scale=1 / 128))
+    lowered = f.lower(spec((B, 32, S, 128), jnp.float32),
+                      spec((B, 8, S, 128), jnp.float32),
+                      spec((B, 8, S, 128), jnp.float32))
+    assert _kernels_in(lowered) >= 1
+
+
+@DTYPES
 def test_ssd_scan_compiles(spec, dtype):
     b, L = 4, 128
     f = jax.jit(functools.partial(ops.ssd_scan, chunk=SSM_CHUNK))
