@@ -24,6 +24,10 @@ Serving the compiler (paper §7, tune-once-run-many):
   analytic plan immediately while the top-k partition race runs in the
   background and hot-swaps the measured winner into the live dispatch.
 
+The chip does not wait on the host between waves while the next wave is
+certain: ``_decode_step`` dispatches wave n+1 on wave n's logits before
+it reads wave n back (see ``ContinuousBatcher``).
+
 Simplifications vs a full vLLM (documented): greedy decoding; idle slots
 still burn a decode lane (masked out functionally); prefills are
 one-slot-at-a-time (chunked-prefill interleaving is future work);
@@ -69,6 +73,7 @@ def _pct(xs: list[float], q: float) -> float:
 class ServeStats:
     prefills: int = 0
     decode_waves: int = 0
+    waves_ahead: int = 0       # ...dispatched before the last was read back
     tokens_out: int = 0
     wall_s: float = 0.0
     # -- shape canonicalization / replans ------------------------------------
@@ -139,8 +144,11 @@ class ServeStats:
         return _pct(self.wave_s, 99)
 
     def summary(self) -> str:
+        ahead = (self.waves_ahead / self.decode_waves
+                 if self.decode_waves else 0.0)
         out = (f"{self.prefills} prefills, {self.decode_waves} decode "
-               f"waves, {self.tokens_out} tokens | shape hit rate "
+               f"waves ({ahead:.1%} ahead), {self.tokens_out} tokens | "
+               f"shape hit rate "
                f"{self.hit_rate:.1%} ({self.replans} replans) | "
                f"plan-cache {self.plan_cache_hits}h/"
                f"{self.plan_cache_misses}m | ttft p50/p99 "
@@ -161,6 +169,29 @@ class ServeStats:
 
 
 class ContinuousBatcher:
+    """A pool of decode slots served in lock-step waves.
+
+    **Running ahead.**  ``_decode_step`` runs one wave and returns with
+    one more token on every active request.  When the next wave is
+    certain -- every slot holds a request (so no arrival could be
+    admitted before it anyway), none of them stops at this wave by
+    length, and there is no ``eos_id`` -- it dispatches the next wave
+    *before* reading this one back, on inputs made on the device from
+    this wave's logits (``argmax``) and positions (+1).  The next call
+    then finds its wave in flight.  The program, its shapes and the
+    tokens served are the same either way; only the host's steps overlap
+    the device's.
+
+    A wave dispatched ahead is committed: its tokens belong to the
+    requests it served, and only reading them is deferred.  So whatever
+    changes the state from outside ``_decode_step`` -- assigning
+    ``cache`` or ``slots``, or a prefill -- first reads the pending wave
+    back into its requests (as the next call would) and releases its
+    logits.  So no wave outlives the cache it ran on, and no new state
+    meets a wave meant for the old one.  Between calls a caller may clear
+    a slot or fill a cleared one through ``_prefill_slot``.
+    """
+
     def __init__(self, mdl: Model, params, *, n_slots: int = 4,
                  max_len: int = 256, eos_id: int | None = None,
                  stitched: bool | None = None,
@@ -178,6 +209,8 @@ class ContinuousBatcher:
         self.eos_id = eos_id
         self.pad_id = pad_id
         self.queue: deque[Request] = deque()
+        #: ``(logits, positions)`` of the wave dispatched and not read back
+        self._wave: tuple | None = None
         self.slots: list[Request | None] = [None] * n_slots
         self._ids = itertools.count()
         self.stats = ServeStats()
@@ -240,6 +273,34 @@ class ContinuousBatcher:
             self._prefill = jax.jit(prefill)
             self._decode_wave = jax.jit(
                 decode_wave, donate_argnums=(1,) if donate else ())
+
+        # a wave's tokens and the next wave's positions, in one dispatch
+        # on the device: the inputs of a wave dispatched ahead
+        def advance(logits, poss):
+            toks = jnp.argmax(logits[:, 0, :], axis=-1).astype(jnp.int32)
+            return toks[:, None, None], poss + 1
+
+        self._advance = jax.jit(advance)
+
+    @property
+    def cache(self):
+        """The stacked KV/SSM cache of every slot."""
+        return self._cache
+
+    @cache.setter
+    def cache(self, value) -> None:
+        self._settle()
+        self._cache = value
+
+    @property
+    def slots(self) -> list[Request | None]:
+        """The request each slot serves, or ``None``."""
+        return self._slots
+
+    @slots.setter
+    def slots(self, value: list[Request | None]) -> None:
+        self._settle()
+        self._slots = value
 
     # -- client API -----------------------------------------------------------
     def submit(self, prompt: np.ndarray, max_new: int = 16) -> int:
@@ -336,6 +397,7 @@ class ContinuousBatcher:
                 self.slots[i] = req
 
     def _prefill_slot(self, i: int, req: Request) -> None:
+        self._settle()
         t0 = req.t_admit = time.perf_counter()
         self.stats.queue_wait.append((t0, t0 - req.t_submit))
         true_len = len(req.prompt)
@@ -352,8 +414,8 @@ class ContinuousBatcher:
             logits, filled = self._prefill(self.params, toks[None, :], one)
             with spans.span("prefill.cache_write",
                             leaves=self._cache_leaves):
-                self.cache = jax.tree_util.tree_map(
-                    lambda st, c: st.at[i].set(c), self.cache, filled)
+                self._cache = jax.tree_util.tree_map(
+                    lambda st, c: st.at[i].set(c), self._cache, filled)
             with spans.span("prefill.sample"):
                 # the *true* last prompt position: the causal mask makes
                 # the padded tail invisible to it.
@@ -368,26 +430,32 @@ class ContinuousBatcher:
             self.stats.ttft_s.append(time.perf_counter() - req.t_submit)
             self._check_done(req)
 
-    def _decode_step(self) -> None:
+    def _decode_step(self, ahead: bool = True) -> None:
         active = [i for i, req in enumerate(self.slots)
                   if req is not None and not req.done]
         if not active:
+            self._wave = None   # the caller cleared every slot it served
             return
         with spans.span("serve.wave", n_active=len(active)):
             t0 = time.perf_counter()
-            with spans.span("wave.inputs"):
-                toks = np.zeros((self.n_slots, 1, 1), np.int32)
-                poss = np.zeros((self.n_slots,), np.int32)
-                for i in active:
-                    toks[i, 0, 0] = self.slots[i].out[-1]
-                    poss[i] = self.slots[i].pos
-                toks, poss = jnp.asarray(toks), jnp.asarray(poss)
-            logits, self.cache = self._decode_wave(
-                self.params, self.cache, toks, poss)
+            if self._wave is None:
+                with spans.span("wave.inputs"):
+                    toks = np.zeros((self.n_slots, 1, 1), np.int32)
+                    poss = np.zeros((self.n_slots,), np.int32)
+                    for i in active:
+                        toks[i, 0, 0] = self.slots[i].out[-1]
+                        poss[i] = self.slots[i].pos
+                    toks, poss = jnp.asarray(toks), jnp.asarray(poss)
+                self._dispatch(toks, poss)
+            toks, poss = self._advance(*self._wave)
+            self._wave = None
+            if ahead and self._next_wave_certain(active):
+                with spans.span("wave.ahead"):
+                    self._dispatch(toks, poss)
+                self.stats.waves_ahead += 1
             with spans.span("wave.sample"):
-                nxt = np.asarray(jnp.argmax(logits[:, 0, :], axis=-1))
+                nxt = np.asarray(toks)[:, 0, 0]
             dt = time.perf_counter() - t0
-            self.stats.decode_waves += 1
             self.stats.wave_s.append(dt)
             self._note_call(("decode",), dt, tokens=len(active))
             with spans.span("wave.retire"):
@@ -398,8 +466,32 @@ class ContinuousBatcher:
                     self.stats.tokens_out += 1
                     self._check_done(req)
 
+    def _dispatch(self, toks, poss) -> None:
+        """Launch one decode wave; it stays in flight until read back."""
+        logits, self._cache = self._decode_wave(
+            self.params, self._cache, toks, poss)
+        self._wave = (logits, poss)
+        self.stats.decode_waves += 1
+
+    def _next_wave_certain(self, active: list[int]) -> bool:
+        """Whether the wave after the one in flight runs whatever its
+        tokens: every slot is held and no request stops by length once
+        the wave in flight adds its token."""
+        return (self.eos_id is None and len(active) == self.n_slots
+                and not any(self._stops_by_length(self.slots[i], 1)
+                            for i in active))
+
+    def _settle(self) -> None:
+        """Read a wave dispatched ahead back into its requests."""
+        if self._wave is not None:
+            self._decode_step(ahead=False)
+
+    def _stops_by_length(self, req: Request, more: int = 0) -> bool:
+        """Whether ``req`` is done by length after ``more`` tokens."""
+        return (len(req.out) + more >= req.max_new
+                or req.pos + more + 1 >= self.max_len)
+
     def _check_done(self, req: Request) -> None:
-        if len(req.out) >= req.max_new or \
-                (self.eos_id is not None and req.out[-1] == self.eos_id) or \
-                req.pos + 1 >= self.max_len:
+        if self._stops_by_length(req) or \
+                (self.eos_id is not None and req.out[-1] == self.eos_id):
             req.done = True

@@ -94,7 +94,8 @@ def test_prefill_and_wave_spans_nest_as_named(batcher, monkeypatch):
     cb._decode_step()
     rec.take()
 
-    # warm: a second request's prefill, then one wave over both slots
+    # warm: a second request's prefill, then one wave over both slots;
+    # neither stops at it, so the next wave is dispatched ahead
     cb.submit(_prompt(cfg, 7), max_new=4)   # the same bucket: warm
     req = cb.queue.popleft()
     cb._prefill_slot(1, req)
@@ -109,9 +110,27 @@ def test_prefill_and_wave_spans_nest_as_named(batcher, monkeypatch):
     assert prefill[2][0][1] == leaves and prefill[2][2][1] == leaves
     assert prefill[2][1][1] == {"program": "stitched_prefill"}
     assert shape(wave) == ("serve.wave", (
+        ("wave.inputs", ()), CALL, ("wave.ahead", (CALL,)),
+        ("wave.sample", ()), ("wave.retire", ())))
+    assert wave[1] == {"n_active": 2}
+    assert wave[2][1][1] == {"program": "stitched_decode_wave"}
+    assert wave[2][2][2][0][1] == {"program": "stitched_decode_wave"}
+
+    # the wave in flight is only read back: slot 0 stops at it
+    cb._decode_step()
+    (wave,) = rec.take()
+    assert shape(wave) == ("serve.wave", (
+        ("wave.sample", ()), ("wave.retire", ())))
+    assert cb.slots[0].done and not cb.slots[1].done
+
+    # one slot free: a synchronous wave
+    cb.slots[0] = None
+    cb._decode_step()
+    (wave,) = rec.take()
+    assert shape(wave) == ("serve.wave", (
         ("wave.inputs", ()), CALL, ("wave.sample", ()),
         ("wave.retire", ())))
-    assert wave[1] == {"n_active": 2}
+    assert wave[1] == {"n_active": 1}
     assert wave[2][1][1] == {"program": "stitched_decode_wave"}
     cb.slots = [None] * cb.n_slots
 
