@@ -22,7 +22,7 @@ exactly the gap ``tune_partitions`` closes.  Deterministic: no wall
 clock in the decision, so the row is CI-stable.
 
 Part 3 -- tune-once-run-many.  The measured partition persists in
-plan-cache format v4 (``partition_source: measured``); a second process
+the plan cache (``partition_source: measured``); a second process
 replays it without re-searching or re-racing (asserted via call
 counting), reporting the compile-time saving.
 """
@@ -179,7 +179,7 @@ def _emulated_silicon_gap() -> str:
 
 
 def _cache_replay() -> str:
-    """v4 round-trip: the measured partition replays with no re-race."""
+    """Round-trip: the measured partition replays with no re-race."""
     args = (_rand((16, 256)), _scale(256), _rand(256))
     with tempfile.TemporaryDirectory() as cache_dir:
         t0 = time.perf_counter()
@@ -214,7 +214,7 @@ def _cache_replay() -> str:
         np.testing.assert_allclose(y1, y2, rtol=1e-6, atol=1e-6)
     return csv_row(
         "topk_cache_replay", warm_s * 1e6,
-        f"v4 measured-partition replay: cold_compile={cold_s:.2f}s vs "
+        f"measured-partition replay: cold_compile={cold_s:.2f}s vs "
         f"replay={warm_s:.2f}s (speedup={cold_s / max(warm_s, 1e-9):.1f}x); "
         f"no re-search, no re-race")
 

@@ -58,17 +58,15 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     choices=["fig1", "table2", "fig7", "overhead", "roofline",
                              "plan_time", "stitch_groups", "beam_stitch",
-                             "topk_tune", "recompute", "serving",
-                             "guard_overhead", "anchor", "spmd_stitch",
-                             "canary"])
+                             "topk_tune", "serving", "guard_overhead",
+                             "spmd_stitch", "canary"])
     ap.add_argument("--json", default=None, metavar="OUT.json",
                     help="also write structured per-row records")
     args = ap.parse_args()
 
-    from . import (bench_anchor_fusion, bench_beam_stitch, bench_canary,
-                   bench_fig1_layernorm, bench_fig7_speedup,
-                   bench_guard_overhead, bench_overhead, bench_plan_time,
-                   bench_recompute, bench_serving, bench_spmd_stitch,
+    from . import (bench_beam_stitch, bench_canary, bench_fig1_layernorm,
+                   bench_fig7_speedup, bench_guard_overhead, bench_overhead,
+                   bench_plan_time, bench_serving, bench_spmd_stitch,
                    bench_stitch_groups, bench_table2_breakdown,
                    bench_topk_tune, roofline)
 
@@ -82,10 +80,8 @@ def main() -> None:
         "stitch_groups": bench_stitch_groups.run,
         "beam_stitch": bench_beam_stitch.run,
         "topk_tune": bench_topk_tune.run,
-        "recompute": bench_recompute.run,
         "serving": bench_serving.run,
         "guard_overhead": bench_guard_overhead.run,
-        "anchor": bench_anchor_fusion.run,
         "spmd_stitch": bench_spmd_stitch.run,
         "canary": bench_canary.run,
     }

@@ -76,9 +76,9 @@ def _recompute_variants(graph, pattern, info, ctx, hw):
     the VMEM budget.  The single source of the recompute override shape
     for both the measured sweep (``_recompute_overrides``) and the
     partition race's swap branches (``_recompute_swap_override``)."""
-    from .cost_model import estimate_onepass, recompute_enabled, reuse_plan
+    from .cost_model import estimate_onepass, reuse_plan
 
-    if info is None or not recompute_enabled():
+    if info is None:
         return
     for br in BLOCK_ROWS:
         rp = (ctx.reuse(pattern, br) if ctx is not None

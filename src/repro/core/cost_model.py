@@ -24,7 +24,6 @@ TPU re-derivation of the paper's GPU model:
 from __future__ import annotations
 
 import math
-import os
 from dataclasses import dataclass
 
 from repro.kernels.matmul import DEFAULT_BLOCK_M
@@ -34,33 +33,6 @@ from .ir import Graph, OpKind
 from .memory_planner import ReusePlan, plan_reuse, plan_scratch, \
     recompute_extra_ops
 from .rowspec import Role, RowInfo, analyze, role_bytes_per_row
-
-#: Env switch: set ``REPRO_RECOMPUTE=0`` to disable the thread-composition
-#: recompute scheme (staging-only pricing and emission, the pre-ISSUE-5
-#: behavior).  Deliberately NOT hashed into ``graph_signature`` (like
-#: ``REPRO_STITCH_TOPK``): cached schedule pins re-validate at load time
-#: and ``plan_cache._sanitize_override`` drops recompute pins when the
-#: knob is off, so old entries degrade instead of being orphaned.
-ENV_RECOMPUTE = "REPRO_RECOMPUTE"
-
-
-def recompute_enabled() -> bool:
-    return os.environ.get(ENV_RECOMPUTE, "1").lower() \
-        not in ("0", "off", "false")
-
-
-#: Env switch: set ``REPRO_SHARD=0`` to disable SPMD-aware stitching.
-#: Ambient mesh contexts stop keying signatures; an *explicit* mesh still
-#: dispatches correctly but pinned to the sharded XLA baseline rung (the
-#: shard_map wrap stays -- only the stitched emission is disabled).
-#: Deliberately NOT hashed into ``graph_signature`` (same contract as
-#: ``REPRO_RECOMPUTE`` / ``REPRO_ANCHOR``: knobs degrade, never re-key).
-ENV_SHARD = "REPRO_SHARD"
-
-
-def shard_enabled() -> bool:
-    return os.environ.get(ENV_SHARD, "1").lower() \
-        not in ("0", "off", "false")
 
 
 @dataclass(frozen=True)
@@ -346,10 +318,8 @@ def reuse_plan(graph: Graph, pattern: frozenset[int], info: RowInfo,
     exactly as ``estimate_onepass`` does, screens flip candidates
     through ``recompute_cost`` legality, and hands the greedy
     flip-until-feasible loop to ``memory_planner.plan_reuse``.  Returns
-    None when recompute is disabled or no candidate is legal.
+    None when no candidate is legal.
     """
-    if not recompute_enabled():
-        return None
     R, C = info.R, info.C
     Cp = _pad(C, 128)
     if ctx is not None:
@@ -498,7 +468,6 @@ def best_estimate(graph: Graph, pattern: frozenset[int],
     cands = [estimate_packed(graph, pattern, hw, ctx=ctx)]
     info = ctx.info(pattern) if ctx is not None else analyze(graph, pattern)
     if info is not None:
-        allow_recompute = recompute_enabled()
         tried: set[int] = set()
         for br in BLOCK_ROWS:
             est = estimate_onepass(graph, pattern, info, br, hw, ctx=ctx)
@@ -507,7 +476,7 @@ def best_estimate(graph: Graph, pattern: frozenset[int],
             tried.add(est.block_rows)
             if est.feasible:
                 cands.append(est)
-            elif allow_recompute:
+            else:
                 rp = (ctx.reuse(pattern, br) if ctx is not None
                       else reuse_plan(graph, pattern, info, br, hw))
                 if rp is not None and rp.feasible and rp.recompute:
@@ -606,20 +575,6 @@ def partition_gain(graph: Graph, partition, hw: Hardware = V5E,
 # ---------------------------------------------------------------------------
 # compute-anchored stitching (fusion across the memory/compute divide)
 # ---------------------------------------------------------------------------
-#: Env switch: set ``REPRO_ANCHOR=0`` to disable compute-anchored groups
-#: (anchors stay hard graph breaks; plans and plan-cache entries are
-#: byte-for-byte the pre-anchor behavior).  Deliberately NOT hashed into
-#: ``graph_signature`` (same contract as ``REPRO_RECOMPUTE``): anchored
-#: cache entries re-validate at load time and degrade to re-planning
-#: when the knob is off instead of being orphaned.
-ENV_ANCHOR = "REPRO_ANCHOR"
-
-
-def anchor_enabled() -> bool:
-    return os.environ.get(ENV_ANCHOR, "1").lower() \
-        not in ("0", "off", "false")
-
-
 @dataclass(frozen=True)
 class AnchorGain:
     """What folding memory-intensive parts into a compute kernel buys.

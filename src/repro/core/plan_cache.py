@@ -78,59 +78,10 @@ ENV_GRACE = "REPRO_PLAN_CACHE_GRACE"
 #: an entry must not lose it to an evictor ranking by a stale mtime.
 DEFAULT_EVICT_GRACE_S = 30.0
 
-#: Bump when the entry layout or planner semantics change incompatibly.
-#: v2: stitch groups (group membership + group schedules) + planner-side
-#: MAX_PATTERN coalesce bound changed plan granularity.
-#: v3: measured *group* schedules (``tuned`` flag on group records) from
-#: the batched group autotuner.  v2 entries still load -- the pattern
-#: and group-composition sections are unchanged -- but their group
-#: schedules are dropped, degrading to re-tuning (or the analytic
-#: sweep) instead of erroring; the upgraded entry is written back.
-#: v4: measured *partition* choice (top-level ``partition_source``
-#: marker) from the top-k partition tuner.  v3 entries still load --
-#: plan, groups and tuned group schedules are unchanged -- but their
-#: partition was never raced against the runner-up candidates, so an
-#: autotuning process degrades to re-measuring the partition and
-#: upgrades the entry in place, mirroring the v2 -> v3 path.
-#: v5: per-kernel stage-vs-recompute decision (``recompute`` id list on
-#: onepass schedule records) from the thread-composition scheme.  v4
-#: entries still load in full -- plan, groups, tuned schedules and the
-#: measured-partition marker are unchanged -- but carry no recompute
-#: pins, so a onepass pin that is only feasible under recompute fails
-#: its override re-price at emission and degrades to re-deciding via
-#: the latency sweep; the entry is upgraded to v5 in place.
-#: v6: compute-anchored groups (``anchors`` node-id list on group
-#: records) from anchored stitching.  v5 entries still load in full --
-#: their composition simply predates anchor absorption, so the loader
-#: re-plans the anchors (absorption is deterministic) and backfills the
-#: upgraded entry.  A plan with *no* anchored group is still written as
-#: v5, so ``REPRO_ANCHOR=0`` runs reproduce pre-anchor entries
-#: byte-for-byte; v6 entries loaded with the knob off degrade to
-#: re-stitching instead of silently re-enabling the scheme.
-#: v7: SPMD-aware plans (top-level ``mesh`` record: shape + axis names)
-#: from sharded stitching.  The *signature* of a sharded graph already
-#: hashes the mesh (see ``graph_signature``), so 1-device and 8-device
-#: plans can never collide; the entry-side record is observability +
-#: load-time sanity.  Mesh-free plans are still written as v6/v5 with
-#: byte-identical signatures, so every pre-shard entry keeps loading
-#: and ``REPRO_SHARD=0`` runs never see v7 at all (explicit-mesh builds
-#: with the knob off pin the baseline rung and skip the store).
-FORMAT_VERSION = 7
-
-#: Formats ``entry_to_plan`` / ``entry_to_groups`` still understand.
-SUPPORTED_FORMATS = (2, 3, 4, 5, 6, FORMAT_VERSION)
-
-
-def entry_format_for(groups, shard=None) -> int:
-    """The format ``plan_to_entry`` stamps for this composition: v7 only
-    when a shard context forces it, v6 only for anchored groups (see the
-    version ladder above) -- so mesh-free, anchor-free plans reproduce
-    pre-shard entries byte-for-byte."""
-    if shard is not None:
-        return FORMAT_VERSION
-    if groups and any(getattr(g, "anchors", ()) for g in groups):
-        return 6
-    return 5
+#: The one entry layout this code reads and writes.  An entry stamped
+#: with anything else (an older layout, a malformed stamp) is a miss:
+#: the graph re-plans and the fresh entry is stored over it.
+FORMAT_VERSION = 8
 
 
 # ---------------------------------------------------------------------------
@@ -145,9 +96,7 @@ def graph_signature(graph: Graph, hw, *, remote_fusion: bool = True,
     names + input/output PartitionSpecs into the key, so a plan built on
     per-shard shapes for an 8-device mesh can never collide with a
     1-device plan (or with a different mesh/layout of the same graph).
-    Mesh-free graphs hash nothing extra: their signatures are
-    byte-identical to every pre-v7 release, which is what keeps v6/v5
-    entries loadable.
+    Mesh-free graphs hash nothing extra.
     """
     from .explorer import MAX_GROUP, MAX_PATTERN, TOP_K
     from .planner import BEAM_WIDTH
@@ -159,13 +108,10 @@ def graph_signature(graph: Graph, hw, *, remote_fusion: bool = True,
         h.update(repr(xs).encode())
         h.update(b";")
 
-    # NOTE: the entry FORMAT_VERSION is deliberately *not* hashed --
-    # signatures are stable across format bumps so an old-format entry
-    # can be found and degraded (v2 -> re-tune) instead of orphaned.
-    # v3 itself rotated signatures once by adding the stitch beam width.
-    # REPRO_STITCH_TOPK is likewise unhashed: it only widens the set of
-    # measurement candidates, and hashing it would orphan every v3
-    # entry the v3 -> v4 degrade path exists to rescue.
+    # The entry format is not hashed: an entry of another format sits
+    # under the same key, loads as a miss and is overwritten.
+    # REPRO_STITCH_TOPK is unhashed too: it only widens the set of
+    # measurement candidates, not the plan space.
     w("hw", hw.peak_bf16_flops, hw.hbm_bw, hw.vpu_ops, hw.vmem_bytes,
       hw.launch_s, hw.hbm_latency_s)
     w("knobs", TOP_K, MAX_GROUP, MAX_PATTERN, BEAM_WIDTH, remote_fusion,
@@ -178,11 +124,7 @@ def graph_signature(graph: Graph, hw, *, remote_fusion: bool = True,
         params = tuple(sorted(
             (k, repr(v)) for k, v in n.params.items()
             if not k.startswith("_")))  # skip live jax primitive handles
-        # anchors hash as "opaque": classification promoted compute prims
-        # from OPAQUE to ANCHOR, and the signature must stay stable so
-        # pre-anchor entries are found and upgraded instead of orphaned.
-        kind = "opaque" if n.kind is OpKind.ANCHOR else n.kind.value
-        w(nid, n.prim, kind, n.inputs, n.spec.shape, n.spec.dtype,
+        w(nid, n.prim, n.kind.value, n.inputs, n.spec.shape, n.spec.dtype,
           params)
     return h.hexdigest()
 
@@ -207,7 +149,7 @@ def plan_to_entry(plan: FusionPlan, schedules: list[dict],
     trusts a measured partition and re-races a modeled one.
     """
     entry = {
-        "format": entry_format_for(groups, shard),
+        "format": FORMAT_VERSION,
         "signature": signature,
         "patterns": [
             {"members": sorted(pat.members), **sched}
@@ -247,12 +189,15 @@ def entry_to_plan(entry: dict, graph: Graph
                   ) -> tuple[FusionPlan, list[dict]] | None:
     """Reconstruct (plan, per-pattern schedule overrides); None if stale.
 
-    Validates against the live graph (membership, fusibility,
-    disjointness, convexity) so a corrupt or hand-edited entry degrades
-    to a re-plan instead of a miscompile.
+    Checks the format stamp, then validates against the live graph
+    (membership, fusibility, disjointness, convexity) so a stale,
+    corrupt or hand-edited entry degrades to a re-plan instead of a
+    miscompile.
     """
-    if not isinstance(entry, dict) \
-            or entry.get("format") not in SUPPORTED_FORMATS:
+    if not isinstance(entry, dict):
+        return None
+    fmt = entry.get("format")
+    if type(fmt) is not int or fmt != FORMAT_VERSION:
         return None
     patterns: list[Pattern] = []
     overrides: list[dict] = []
@@ -284,19 +229,13 @@ def entry_to_groups(entry: dict, plan: FusionPlan, graph: Graph
     (fusible, outside every pattern, not duplicated) and union convexity
     so a corrupt groups section degrades to re-running the stitcher --
     never to a miscompile.  Patterns not referenced by any group become
-    singleton groups, so the result always covers the plan.
-
-    Version skew: a v2 entry's group *composition* loads unchanged, but
-    its group schedules predate measured group tuning and are dropped
-    (every override comes back empty), so the caller re-tunes (or falls
-    back to the analytic sweep) instead of trusting a stale pin.  v3
-    records may carry a ``tuned: true`` marker, passed through on the
-    override so reports can distinguish measured from analytic pins.
+    singleton groups, so the result always covers the plan.  A record's
+    ``tuned: true`` marker passes through on its override so reports can
+    tell measured from analytic pins.
     """
     recs = entry.get("groups")
     if not isinstance(recs, list):
         return None
-    format_v = entry.get("format")
     n = len(plan.patterns)
     in_pattern = plan.covered()
     used_idx: set[int] = set()
@@ -332,14 +271,6 @@ def entry_to_groups(entry: dict, plan: FusionPlan, graph: Graph
             if node is None or node.kind is not OpKind.ANCHOR:
                 return None
             used_extra.add(a)
-        if anchors:
-            from .cost_model import anchor_enabled
-
-            # with the knob off an anchored composition degrades to
-            # re-stitching (absorption simply won't re-form the group),
-            # never to silently re-enabling the scheme.
-            if not anchor_enabled():
-                return None
         parts = sorted(
             [plan.patterns[i].members for i in idxs]
             + [frozenset({e}) for e in extra]
@@ -357,9 +288,6 @@ def entry_to_groups(entry: dict, plan: FusionPlan, graph: Graph
                 unanchored=tuple((p,) for p in parts)))
         else:
             groups.append(StitchGroup(tuple(parts)))
-        if format_v == 2:  # pre-group-tuning schedules: degrade to re-tune
-            overrides.append({})
-            continue
         over = _sanitize_override(rec)
         if over and rec.get("tuned") is True:
             over["tuned"] = True
@@ -373,18 +301,11 @@ def entry_to_groups(entry: dict, plan: FusionPlan, graph: Graph
 
 
 def entry_partition_source(entry: dict) -> str:
-    """How the entry's stored group partition was chosen.
-
-    Formats >= 4 record the marker (the partition-race semantics are
-    unchanged since); older formats predate partition racing, so their
-    partitions count as model-chosen and an autotuning loader degrades
-    to re-measuring the top-k candidates.
-    """
-    fmt = entry.get("format") if isinstance(entry, dict) else None
-    if isinstance(fmt, int) and not isinstance(fmt, bool) and fmt >= 4 \
-            and entry.get("partition_source") == "measured":
-        return "measured"
-    return "model"
+    """How the entry's stored group partition was chosen: ``"measured"``
+    when its top-k candidates were raced, else ``"model"`` (an autotuning
+    loader then re-races them)."""
+    return ("measured" if entry.get("partition_source") == "measured"
+            else "model")
 
 
 def override_fp(over: dict | None) -> tuple:
@@ -401,10 +322,6 @@ def _sanitize_override(rec: dict) -> dict:
     """Keep only well-typed schedule fields; a malformed override must
     degrade to the analytic sweep, not crash emission."""
     if rec.get("schedule") == "anchored":
-        from .cost_model import anchor_enabled
-
-        if not anchor_enabled():
-            return {}
         over = {"schedule": "anchored"}
         v = rec.get("block_rows")
         if isinstance(v, int) and not isinstance(v, bool) and v > 0:
@@ -422,13 +339,7 @@ def _sanitize_override(rec: dict) -> dict:
             and recompute \
             and all(isinstance(x, int) and not isinstance(x, bool)
                     and x >= 0 for x in recompute):
-        from .cost_model import recompute_enabled
-
-        # with the knob off a cached recompute pin degrades to
-        # re-deciding (the staged/streaming sweep) instead of silently
-        # re-enabling the scheme.
-        if recompute_enabled():
-            over["recompute"] = sorted(set(recompute))
+        over["recompute"] = sorted(set(recompute))
     return over
 
 

@@ -20,8 +20,7 @@ pipeline:
   / shadow verification work per-shard.
 * **plan cache** -- ``signature_items`` folds mesh shape + axis names
   + specs into ``graph_signature`` so 1-device and 8-device plans can
-  never collide (FORMAT_VERSION 7); mesh-free graphs hash nothing and
-  keep their v6 signatures byte-for-byte.
+  never collide; mesh-free graphs hash nothing extra.
 
 Two flavors:
 
@@ -34,12 +33,6 @@ Two flavors:
   global-view (GSPMD places the collectives); the mesh is folded into
   the plan signature and compile keys only, so serving under
   ``use_mesh`` never collides its plans with single-device ones.
-
-``$REPRO_SHARD=0`` is the kill switch (see
-``cost_model.shard_enabled``): ambient contexts are ignored outright,
-explicit ones degrade the dispatch to the sharded XLA baseline rung --
-the plan signature does NOT re-key, matching the REPRO_RECOMPUTE /
-REPRO_ANCHOR precedent (knobs degrade, they never re-key).
 """
 from __future__ import annotations
 
@@ -175,7 +168,7 @@ class ShardCtx:
                 self.explicit)
 
     def mesh_record(self) -> dict:
-        """The ``mesh`` section a v7 plan-cache entry stores."""
+        """The ``mesh`` section a sharded plan-cache entry stores."""
         return {"shape": [int(s) for s in self.mesh.shape.values()],
                 "axes": [str(a) for a in self.mesh.shape.keys()]}
 

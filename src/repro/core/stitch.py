@@ -60,14 +60,13 @@ from repro.testing import faults as _faults
 
 from .codegen import Emitted, emit_group, emit_pattern
 from .costctx import CostContext
-from .cost_model import Hardware, KernelEstimate, anchor_enabled, \
-    hardware
+from .cost_model import Hardware, KernelEstimate, hardware
 from .ir import FUSIBLE_KINDS, FusionPlan, Graph, OpKind, StitchGroup
-from .plan_cache import PlanCache, entry_format_for, \
-    entry_partition_source, entry_to_groups, entry_to_plan, \
-    graph_signature, override_fp, plan_to_entry
+from .plan_cache import PlanCache, entry_partition_source, \
+    entry_to_groups, entry_to_plan, graph_signature, override_fp, \
+    plan_to_entry
 from .planner import PlanStats, make_plan, plan_stats
-from .stitcher import absorb_anchors, break_cycles, search_groups
+from .stitcher import break_cycles, search_groups
 from .tracer import bind_node, trace, trace_with_shape
 
 
@@ -672,15 +671,11 @@ class StitchedFunction:
     def _shard_ctx(self):
         """The active shard context for the next compile: the explicit
         one this function was constructed with, else the ambient
-        ``use_mesh`` context (signature-keying only; ignored when
-        ``$REPRO_SHARD=0``)."""
-        from .cost_model import shard_enabled
+        ``use_mesh`` context (signature-keying only)."""
         from .shard import ShardCtx
 
         if self._shard is not None:
             return self._shard
-        if not shard_enabled():
-            return None
         return ShardCtx.ambient()
 
     def _signature(self, flat_args, in_tree) -> tuple:
@@ -827,8 +822,8 @@ class StitchedFunction:
             # the measured winner is committed -- the paper's
             # model-validated-by-measurement tuning of the stitching scheme.
             # A cached entry whose partition was already measured is
-            # trusted; a pre-v4 (or model-sourced) entry degrades to
-            # re-measuring and is upgraded in place.
+            # trusted; a model-sourced one is re-raced when this process
+            # can measure.
             groups: list[StitchGroup]
             group_overrides: list[dict]
             groups_from_cache = False
@@ -858,25 +853,12 @@ class StitchedFunction:
                     groups, group_overrides = loaded
                     groups_from_cache = True
                     partition_source = cached_source
-                    # a pre-anchor (v5) composition re-plans its anchors on
-                    # load: absorption is deterministic given the graph, so
-                    # the backfill below rewrites the upgraded entry in v6.
-                    if anchor_enabled() and not any(g.anchors for g in groups):
-                        a_groups, n_anch = absorb_anchors(
-                            graph, [list(g.parts) for g in groups], ctx)
-                        if n_anch:
-                            over_by = {g.parts: o for g, o in
-                                       zip(groups, group_overrides)}
-                            groups = a_groups
-                            group_overrides = [
-                                dict(over_by.get(g.parts, {}))
-                                for g in groups]
                 else:
-                    # pre-v4 / model-sourced entries degrade to re-measuring
-                    # the *partition*, but their group schedule pins (earlier
-                    # measurements, keyed by composition) are reused for any
-                    # winner group with the same parts instead of being
-                    # re-swept from scratch.
+                    # a model-sourced entry loaded by a process that can
+                    # tune re-races the *partition*, but its group schedule
+                    # pins (earlier measurements, keyed by composition) are
+                    # reused for any winner group with the same parts
+                    # instead of being re-swept from scratch.
                     loaded_over_by_parts: dict[tuple, dict] = {}
                     if loaded is not None:
                         for lgrp, lover in zip(*loaded):
@@ -961,14 +943,7 @@ class StitchedFunction:
         """Group tuning + emission + plan-cache store + report: the part
         of compilation shared by the cold path and the background
         ``rerace`` rebuild."""
-        from .cost_model import shard_enabled
-
         explicit_shard = shard is not None and shard.explicit
-        # kill switch: the compile completes (the graph, tree and the
-        # shard_map-wrapped baseline are all still needed to answer
-        # calls correctly on the mesh) but pins the baseline rung below
-        # and skips the cache store -- degrade, never re-key.
-        shard_off = explicit_shard and not shard_enabled()
         group_tuned = group_tuned_wins = 0
         tuned_fresh = False
         with phases("search"):
@@ -986,9 +961,7 @@ class StitchedFunction:
             # ---- measured group tuning (paper: tune the stitching scheme)
             # Stitched unions get their onepass/streaming phase split + tile
             # measured (batch-compiled sweep); a cache hit that already holds
-            # a measured pin (override carries ``tuned``) is trusted, and a
-            # v2-format entry arrives with its group schedules dropped, so it
-            # re-tunes here instead of erroring.
+            # a measured pin (override carries ``tuned``) is trusted.
             if tune_groups and self._stitch_groups:
                 from .autotune import autotune_available, tune_group
 
@@ -1200,23 +1173,18 @@ class StitchedFunction:
         # below assume one emitted kernel per group).
         poisoned = self._poison.rung_for(sig) is not None
         store_fresh = (self._plan_cache is not None and not cached_hit
-                       and not fallbacks and not poisoned
-                       and not shard_off)
+                       and not fallbacks and not poisoned)
         # a cache hit whose entry lacked a usable groups section (e.g.
         # first written by a stitch_groups=False baseline run) gets the
         # freshly stitched composition written back once, so later
-        # processes skip the stitcher again.  Likewise an entry in an
-        # older format (v2: no measured group schedules), or one whose
-        # groups were just measured for the first time, is rewritten in
-        # the current format so later processes skip the re-tune.
+        # processes skip the stitcher again.  Likewise an entry whose
+        # groups were just measured for the first time is rewritten so
+        # later processes skip the re-tune.
         store_groups_backfill = (self._plan_cache is not None
                                  and cached_hit
                                  and self._stitch_groups
                                  and not fallbacks and not poisoned
-                                 and not shard_off
-                                 and (not groups_from_cache or tuned_fresh
-                                      or (entry or {}).get("format")
-                                      != entry_format_for(groups, shard)))
+                                 and (not groups_from_cache or tuned_fresh))
         #: the clean entry payload, kept (not only stored) so canary
         #: re-admission can re-persist the plan after a quarantine
         #: evicted it -- including the restart case, where the compile
@@ -1225,7 +1193,7 @@ class StitchedFunction:
         build_payload = store_fresh or store_groups_backfill or (
             self._canary is not None and poisoned
             and self._plan_cache is not None and self._stitch_groups
-            and not fallbacks and not shard_off)
+            and not fallbacks)
         if build_payload:
             em_of_pattern = {em.parts[0]: em for em in emitted
                              if len(em.parts) == 1}
@@ -1355,18 +1323,12 @@ class StitchedFunction:
             compiled.pin_baseline(
                 "signature poisoned: "
                 + (self._poison.reason_for(sig) or "unspecified"))
-        elif shard_off:
-            # the whole pipeline still ran (plan, emission, report) so
-            # the knob is observable; execution just pins the sharded
-            # XLA baseline rung.
-            compiled.pin_baseline(
-                "sharded stitching disabled (REPRO_SHARD=0)")
         elif not poisoned:
             compiled._race_ctx = race_ctx
         # with a canary attached a poisoned signature is NOT hard-pinned:
         # register() adopts it as quarantined and the per-call governor
         # serves the baseline until probation re-admits it.
-        if self._canary is not None and not shard_off:
+        if self._canary is not None:
             self._canary.register(
                 sig,
                 poisoned_reason=((self._poison.reason_for(sig) or "poisoned")

@@ -43,7 +43,7 @@ import os
 from dataclasses import dataclass
 
 from .codegen import EMITTABLE_PRIMS, anchor_emittable, pattern_emittable
-from .cost_model import Hardware, V5E, anchor_enabled
+from .cost_model import Hardware, V5E
 from .costctx import CostContext
 from .ir import FUSIBLE_KINDS, FusionPlan, Graph, OpKind, StitchGroup
 
@@ -840,26 +840,24 @@ def search_groups(graph: Graph, plan: FusionPlan, hw: Hardware = V5E,
         ctx.partition_gain([tuple(g) for g in groups]),
         _candidate_scratch_bytes(graph, ctx, [tuple(g) for g in groups]))
     candidates = [best]
-    if anchor_enabled():
-        # compute-anchored variant: fold flanking groups into adjacent
-        # dot_general kernels.  Prepended when any fold commits -- it is
-        # served by default, with the memory-only partition kept as the
-        # next race branch (and as the structural fallback rung).
-        a_groups, n_anch = absorb_anchors(graph, [list(g) for g in groups],
-                                          ctx)
-        if n_anch:
-            extra = 0.0
-            for g in a_groups:
-                if not g.anchors:
-                    continue
-                folded = tuple(
-                    frozenset(x for p in sub for x in p)
-                    for sub in g.unanchored
-                    if frozenset(x for p in sub for x in p)
-                    - frozenset(g.anchors))
-                extra += ctx.anchor_gain(g.anchors, folded).latency_gain_s
-            candidates.insert(0, PartitionCandidate(
-                a_groups, best.gain_s + extra, best.scratch_bytes))
+    # compute-anchored variant: fold flanking groups into adjacent
+    # dot_general kernels.  Prepended when any fold commits -- it is
+    # served by default, with the memory-only partition kept as the
+    # next race branch (and as the structural fallback rung).
+    a_groups, n_anch = absorb_anchors(graph, [list(g) for g in groups], ctx)
+    if n_anch:
+        extra = 0.0
+        for g in a_groups:
+            if not g.anchors:
+                continue
+            folded = tuple(
+                frozenset(x for p in sub for x in p)
+                for sub in g.unanchored
+                if frozenset(x for p in sub for x in p)
+                - frozenset(g.anchors))
+            extra += ctx.anchor_gain(g.anchors, folded).latency_gain_s
+        candidates.insert(0, PartitionCandidate(
+            a_groups, best.gain_s + extra, best.scratch_bytes))
     # global runners-up: swap one segment's choice for its next-ranked
     # alternative -- and, when several segments have alternatives,
     # combine the rank-1 swaps of two segments at once (multi-segment

@@ -4,9 +4,8 @@ matmul and flash-attention Pallas bodies.
 Covers the anchor pattern kind end to end: classification, the anchored
 partition (fewer launches, more HBM saved than memory-only stitching),
 numerics (fp32 exact vs the interpret oracle; bf16 within the widened
-anchored band), plan-cache v6 round-trip plus the v5 degrade/upgrade
-path, the ``REPRO_ANCHOR`` kill switch, and isomorphic anchored-group
-emission dedup.
+anchored band), the anchored plan-cache round-trip, and isomorphic
+anchored-group emission dedup.
 """
 import json
 import os
@@ -16,9 +15,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import StitchedFunction
+from repro.core import CostContext, StitchedFunction, make_plan, \
+    plan_stats, search_groups, trace
 from repro.core.classify import classify, vpu_cost
-from repro.core.cost_model import anchor_enabled
 from repro.core.ir import OpKind
 from repro.core.plan_cache import FORMAT_VERSION, PlanCache
 from repro.runtime import RUNG_ANCHORED
@@ -73,16 +72,6 @@ def test_classify_anchor_kinds():
     assert classify("sort") is OpKind.OPAQUE
 
 
-def test_anchor_knob_parsing(monkeypatch):
-    monkeypatch.delenv("REPRO_ANCHOR", raising=False)
-    assert anchor_enabled()
-    for off in ("0", "off", "FALSE"):
-        monkeypatch.setenv("REPRO_ANCHOR", off)
-        assert not anchor_enabled()
-    monkeypatch.setenv("REPRO_ANCHOR", "1")
-    assert anchor_enabled()
-
-
 # ---------------------------------------------------------------------------
 # anchored matmul: numerics + plan shape
 # ---------------------------------------------------------------------------
@@ -102,17 +91,28 @@ def test_matmul_anchored_exact_fp32():
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
 
 
-def test_anchored_beats_memory_only_stitching(monkeypatch):
-    """The anchored plan must launch fewer kernels and model strictly
-    more HBM saved than the pure-memory partition of the same graph."""
-    args = _mlp_args()
-    monkeypatch.setenv("REPRO_ANCHOR", "0")
-    rep_off = StitchedFunction(_mlp).report(*args)
-    monkeypatch.setenv("REPRO_ANCHOR", "1")
-    rep_on = StitchedFunction(_mlp).report(*args)
-    assert rep_on.n_anchored >= 1 and rep_off.n_anchored == 0
-    assert rep_on.stats.n_kernels_stitched < rep_off.stats.n_kernels_stitched
-    assert rep_on.stitched_hbm_bytes_saved > rep_off.stitched_hbm_bytes_saved
+def _anchored_vs_memory_only(fn, args):
+    """``search_groups``' anchored candidate and the memory-only
+    partition it keeps right behind it, with each one's kernel count."""
+    graph = trace(fn, *args)
+    ctx = CostContext(graph)
+    plan = make_plan(graph, ctx=ctx)
+    anchored, memory_only = search_groups(graph, plan, ctx=ctx).candidates[:2]
+    assert any(g.anchors for g in anchored.groups)
+    assert not any(g.anchors for g in memory_only.groups)
+    kernels = [plan_stats(graph, plan, ctx=ctx, groups=c.groups)
+               .n_kernels_stitched for c in (anchored, memory_only)]
+    return anchored, memory_only, kernels
+
+
+def test_anchored_beats_memory_only_stitching():
+    """The anchored candidate must form fewer kernels and model a
+    strictly higher gain than the memory-only partition of the same
+    graph."""
+    anchored, memory_only, (k_on, k_off) = _anchored_vs_memory_only(
+        _mlp, _mlp_args())
+    assert k_on < k_off
+    assert anchored.gain_s > memory_only.gain_s
 
 
 def test_attention_bias_scale_folded():
@@ -128,14 +128,11 @@ def test_attention_bias_scale_folded():
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
 
 
-def test_attention_anchored_fewer_launches(monkeypatch):
-    args = _attn_args()
-    monkeypatch.setenv("REPRO_ANCHOR", "0")
-    rep_off = StitchedFunction(_attn).report(*args)
-    monkeypatch.setenv("REPRO_ANCHOR", "1")
-    rep_on = StitchedFunction(_attn).report(*args)
-    assert rep_on.stats.n_kernels_stitched < rep_off.stats.n_kernels_stitched
-    assert rep_on.stitched_hbm_bytes_saved > rep_off.stitched_hbm_bytes_saved
+def test_attention_anchored_fewer_launches():
+    anchored, memory_only, (k_on, k_off) = _anchored_vs_memory_only(
+        _attn, _attn_args())
+    assert k_on < k_off
+    assert anchored.gain_s > memory_only.gain_s
 
 
 # ---------------------------------------------------------------------------
@@ -182,7 +179,7 @@ def test_bf16_shadow_verification_passes(monkeypatch, tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# plan cache: v6 round-trip, v5 degrade/upgrade, kill switch
+# plan cache: anchored round-trip
 # ---------------------------------------------------------------------------
 def _entry_on_disk(cache_dir, signature):
     with open(os.path.join(cache_dir, f"{signature}.json")) as f:
@@ -197,9 +194,7 @@ def test_plan_cache_v6_roundtrip(tmp_path):
     assert rep1.n_anchored == 1
 
     entry = _entry_on_disk(str(tmp_path), rep1.signature)
-    # anchored mesh-free plans stay v6 even though FORMAT_VERSION moved
-    # on (v7 is reserved for sharded plans)
-    assert entry["format"] == 6 < FORMAT_VERSION
+    assert entry["format"] == FORMAT_VERSION
     anchored_recs = [g for g in entry["groups"] if g.get("anchors")]
     assert anchored_recs and all(
         isinstance(a, int) for g in anchored_recs for a in g["anchors"])
@@ -209,61 +204,6 @@ def test_plan_cache_v6_roundtrip(tmp_path):
     assert rep2.plan_cache_hit
     assert rep2.n_anchored == rep1.n_anchored
     np.testing.assert_array_equal(np.asarray(sf2(*args)), y1)
-
-
-def test_knob_off_writes_v5_and_signature_is_stable(monkeypatch, tmp_path):
-    """``REPRO_ANCHOR=0`` reproduces the pre-anchor plan: a v5 entry
-    with no anchor record anywhere, under the *same* graph signature
-    (anchors hash as opaque, so toggling the knob never re-keys)."""
-    args = _mlp_args()
-    monkeypatch.setenv("REPRO_ANCHOR", "0")
-    rep_off = StitchedFunction(_mlp, plan_cache=str(tmp_path)).report(*args)
-    assert rep_off.n_anchored == 0
-    entry = _entry_on_disk(str(tmp_path), rep_off.signature)
-    assert entry["format"] == 5
-    assert all("anchors" not in g for g in entry.get("groups", []))
-
-    monkeypatch.setenv("REPRO_ANCHOR", "1")
-    rep_on = StitchedFunction(_mlp).report(*args)
-    assert rep_on.signature == rep_off.signature
-
-
-def test_v5_entry_upgrades_in_place(monkeypatch, tmp_path):
-    """A v5 (pre-anchor) entry loads, the absorbed anchored composition
-    is rebuilt on top of it, and the entry is backfilled to v6."""
-    args = _mlp_args()
-    monkeypatch.setenv("REPRO_ANCHOR", "0")
-    rep_off = StitchedFunction(_mlp, plan_cache=str(tmp_path)).report(*args)
-    assert _entry_on_disk(str(tmp_path), rep_off.signature)["format"] == 5
-
-    monkeypatch.setenv("REPRO_ANCHOR", "1")
-    sf = StitchedFunction(_mlp, plan_cache=str(tmp_path))
-    rep = sf.report(*args)
-    assert rep.plan_cache_hit
-    assert rep.n_anchored == 1
-    upgraded = _entry_on_disk(str(tmp_path), rep.signature)
-    assert upgraded["format"] == 6 < FORMAT_VERSION  # anchored, mesh-free
-    assert any(g.get("anchors") for g in upgraded["groups"])
-    ref = np.asarray(_mlp(*(jnp.asarray(a) for a in args)))
-    np.testing.assert_allclose(np.asarray(sf(*args)), ref,
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_v6_entry_degrades_under_kill_switch(monkeypatch, tmp_path):
-    """A v6 anchored entry read with ``REPRO_ANCHOR=0`` must not revive
-    the anchored composition -- the anchors re-plan as graph breaks and
-    the answer stays right."""
-    args = _mlp_args()
-    rep1 = StitchedFunction(_mlp, plan_cache=str(tmp_path)).report(*args)
-    assert _entry_on_disk(str(tmp_path), rep1.signature)["format"] == 6
-
-    monkeypatch.setenv("REPRO_ANCHOR", "0")
-    sf = StitchedFunction(_mlp, plan_cache=str(tmp_path))
-    rep = sf.report(*args)
-    assert rep.n_anchored == 0
-    ref = np.asarray(_mlp(*(jnp.asarray(a) for a in args)))
-    np.testing.assert_allclose(np.asarray(sf(*args)), ref,
-                               rtol=1e-5, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------

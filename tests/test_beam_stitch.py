@@ -1,11 +1,8 @@
 """ISSUE-3 tests: beam-search stitch partitioning (quality, determinism,
 struct-keyed segment reuse), batched group-level measured autotune
-(serial equivalence), plan-cache format v3 (tuned group schedules
-round-trip, v2 entries degrade to re-tune), donation aliasing into the
-first schedule item's kernel, and explicit VMEM scratch staging."""
-import json
-import os
-
+(serial equivalence), tuned group schedules round-tripping the plan
+cache, donation aliasing into the first schedule item's kernel, and
+explicit VMEM scratch staging."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,7 +13,7 @@ from repro.core import (CostContext, Hardware, StitchedFunction, make_plan,
 from repro.core import autotune as autotune_mod
 from repro.core.autotune import tune_group, tune_pattern
 from repro.core.ir import FusionPlan, Pattern
-from repro.core.plan_cache import PlanCache, entry_to_groups
+from repro.core.plan_cache import FORMAT_VERSION, PlanCache
 from repro.core.stitcher import DEFAULT_BEAM_WIDTH, beam_width_from_env
 
 rng = np.random.default_rng(29)
@@ -244,7 +241,7 @@ def test_group_tune_measures_real_kernels():
     assert over.get("block_rows", 0) > 0
 
 
-# -- plan-cache format v3 ------------------------------------------------------
+# -- plan cache: tuned group schedules ----------------------------------------
 def test_tuned_group_schedule_roundtrips_cache(tmp_path, monkeypatch):
     monkeypatch.setenv("REPRO_AUTOTUNE", "force")
     args = _deep_args()
@@ -253,9 +250,7 @@ def test_tuned_group_schedule_roundtrips_cache(tmp_path, monkeypatch):
     assert rep1.autotuned and rep1.group_tuned >= 1
 
     entry = PlanCache(str(tmp_path)).load(rep1.signature)
-    # _deep has no anchors, so the entry persists as v5 (v6 is reserved
-    # for plans carrying anchored groups)
-    assert entry is not None and entry["format"] == 5
+    assert entry is not None and entry["format"] == FORMAT_VERSION
     tuned_recs = [r for r in entry["groups"] if r.get("tuned")]
     assert tuned_recs and all(
         r["schedule"] in ("onepass", "streaming") for r in tuned_recs)
@@ -276,43 +271,6 @@ def test_tuned_group_schedule_roundtrips_cache(tmp_path, monkeypatch):
     np.testing.assert_allclose(np.asarray(sf2(*args)),
                                np.asarray(sf1(*args)),
                                rtol=1e-6, atol=1e-6)
-
-
-def test_v2_entry_degrades_to_retune(tmp_path, monkeypatch):
-    monkeypatch.setenv("REPRO_AUTOTUNE", "force")
-    args = _deep_args()
-    sf1 = StitchedFunction(_deep, autotune=True, plan_cache=str(tmp_path))
-    rep1 = sf1.report(*args)
-    path = os.path.join(str(tmp_path), f"{rep1.signature}.json")
-    with open(path) as f:
-        entry = json.load(f)
-    entry["format"] = 2                    # downgrade: strip v3-only bits
-    entry.pop("checksum", None)            # pre-checksum era had none
-    for r in entry["groups"]:
-        r.pop("tuned", None)
-    with open(path, "w") as f:
-        json.dump(entry, f)
-
-    graph = trace(_deep, *args)
-    from repro.core.plan_cache import entry_to_plan
-    plan, _ = entry_to_plan(entry, graph)
-    decoded = entry_to_groups(entry, plan, graph)
-    assert decoded is not None             # composition loads...
-    _, overrides = decoded
-    assert all(o == {} for o in overrides)  # ...but schedules are dropped
-
-    sf2 = StitchedFunction(_deep, autotune=True, plan_cache=str(tmp_path))
-    rep2 = sf2.report(*args)
-    assert rep2.plan_cache_hit             # no failure, plan reused
-    assert rep2.group_tuned >= 1           # groups were re-tuned
-    # and the entry was upgraded back to the current format on disk
-    upgraded = PlanCache(str(tmp_path)).load(rep1.signature)
-    assert upgraded["format"] == 5         # anchor-free: native format
-    assert any(r.get("tuned") for r in upgraded["groups"])
-    np.testing.assert_allclose(np.asarray(sf2(*args)),
-                               np.asarray(_deep(*(jnp.asarray(a)
-                                                  for a in args))),
-                               rtol=1e-4, atol=1e-4)
 
 
 # -- donation aliasing + explicit scratch staging ------------------------------

@@ -164,8 +164,7 @@ def test_plan_cache_roundtrip(tmp_path):
     cache = PlanCache(str(tmp_path))
     cache.store(sig, plan_to_entry(plan, schedules, sig))
     entry = cache.load(sig)
-    # a pattern-only entry carries no anchored groups -> native v5
-    assert entry is not None and entry["format"] == 5
+    assert entry is not None and entry["format"] == FORMAT_VERSION
     decoded = entry_to_plan(entry, graph)
     assert decoded is not None
     plan2, overrides = decoded
@@ -204,9 +203,11 @@ def test_plan_cache_rejects_stale_entry(tmp_path):
              "patterns": [{"members": [99999]}]}
     assert entry_to_plan(entry, graph) is None        # unknown node
     entry = {"format": 1, "patterns": []}
-    assert entry_to_plan(entry, graph) is None        # unsupported version
-    # v2 is *supported* (degrades to re-tuning groups), not rejected
+    assert entry_to_plan(entry, graph) is None        # another format
+    # an older layout is a miss too: only FORMAT_VERSION loads
     entry = {"format": 2, "signature": "x", "patterns": []}
+    assert entry_to_plan(entry, graph) is None
+    entry = {"format": FORMAT_VERSION, "signature": "x", "patterns": []}
     assert entry_to_plan(entry, graph) is not None
 
 

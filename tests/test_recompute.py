@@ -3,8 +3,8 @@
 Covers the per-value stage-vs-recompute decision pass
 (``memory_planner.plan_reuse`` / ``cost_model.recompute_cost``), the
 emitter honoring it (numerics vs the ``dispatch="interpret"`` oracle in
-fp32 and bf16), the illegal-across-reduce-level guard, plan-cache
-format v5 round-trip with v4 degrade + in-place upgrade, the autotuned
+fp32 and bf16), the illegal-across-reduce-level guard, the plan-cache
+round-trip of recompute pins, the autotuned
 stage-vs-recompute race branches, the report fields, the amortized
 single-dispatch screening pass, multi-segment swap candidates and the
 no-silent-caps / cache-counter observability satellites.
@@ -18,15 +18,15 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from repro.core import (CostContext, Hardware, PlanCache, StitchedFunction,
-                        best_estimate, recompute_enabled, trace)  # noqa: E402
+                        best_estimate, trace)  # noqa: E402
 from repro.core import autotune as autotune_mod  # noqa: E402
 from repro.core.cost_model import (estimate_onepass, estimate_streaming,
                                    reuse_plan)  # noqa: E402
 from repro.core.codegen import _emit_packed, emit_pattern  # noqa: E402
 from repro.core.ir import FusionPlan, Pattern  # noqa: E402
 from repro.core.memory_planner import plan_scratch  # noqa: E402
-from repro.core.plan_cache import (FORMAT_VERSION, _sanitize_override,
-                                   entry_partition_source)  # noqa: E402
+from repro.core.plan_cache import (FORMAT_VERSION,
+                                   _sanitize_override)  # noqa: E402
 from repro.core.stitcher import search_groups  # noqa: E402
 
 rng = np.random.default_rng(7)
@@ -88,21 +88,6 @@ def test_recompute_rescues_vmem_infeasible_onepass():
                            recompute=frozenset(best.recompute_ids))
     assert rec.scratch_bytes < staged.scratch_bytes
     assert rec.vpu_ops > staged.vpu_ops
-
-
-def test_recompute_disabled_by_env_knob(monkeypatch):
-    x, g = _fanout_args()
-    graph = trace(_fanout, x, g)
-    pat = frozenset(graph.fusible_nodes())
-    hw = _tight_hw()
-    monkeypatch.setenv("REPRO_RECOMPUTE", "0")
-    assert not recompute_enabled()
-    best = best_estimate(graph, pat, hw, ctx=CostContext(graph, hw))
-    assert not best.recompute_ids
-    assert best.schedule != "onepass", \
-        "staging-only pricing must refuse the one-pass schedule here"
-    monkeypatch.delenv("REPRO_RECOMPUTE")
-    assert recompute_enabled()
 
 
 def test_illegal_across_reduce_level_guard():
@@ -235,7 +220,7 @@ def test_caps_hit_reports_max_pattern_truncation():
 
 
 # ---------------------------------------------------------------------------
-# plan-cache: v5 round-trip, v4 degrade + upgrade
+# plan-cache: recompute pins round-trip
 # ---------------------------------------------------------------------------
 def test_v5_roundtrip_and_v4_degrade_upgrade(tmp_path):
     x, g = _fanout_args()
@@ -246,49 +231,19 @@ def test_v5_roundtrip_and_v4_degrade_upgrade(tmp_path):
     y = np.asarray(sf(x, g))
     pc = PlanCache(cache_dir)
     entry = pc.load(rep.signature)
-    # memory-only plans (no anchored groups, no mesh) still persist as
-    # v5; anchored plans need v6 and sharded plans v7.
-    assert entry["format"] == 5 < FORMAT_VERSION
+    assert entry["format"] == FORMAT_VERSION
     pins = [p for p in entry["patterns"] if p.get("recompute")]
     assert pins and all(isinstance(i, int) for p in pins
                         for i in p["recompute"])
 
-    # v5 replay: the recompute pin is honored without re-deciding
+    # replay: the recompute pin is honored without re-deciding
     sf2 = StitchedFunction(_fanout, hw=hw, plan_cache=cache_dir)
     rep2 = sf2.report(x, g)
     assert rep2.plan_cache_hit and rep2.n_recomputed == rep.n_recomputed
     np.testing.assert_allclose(np.asarray(sf2(x, g)), y, rtol=1e-6)
 
-    # v4 degrade: strip the pins, mark the entry v4 -- the onepass pin
-    # re-prices as infeasible and emission re-decides recompute...
-    entry["format"] = 4
-    for p in entry["patterns"]:
-        p.pop("recompute", None)
-    for grec in entry.get("groups", []):
-        grec.pop("recompute", None)
-    pc.store(rep.signature, entry)
-    sf3 = StitchedFunction(_fanout, hw=hw, plan_cache=cache_dir)
-    rep3 = sf3.report(x, g)
-    assert rep3.plan_cache_hit
-    assert rep3.n_recomputed == rep.n_recomputed
-    np.testing.assert_allclose(np.asarray(sf3(x, g)), y, rtol=1e-6)
-    # ...and the entry is upgraded in place
-    upgraded = pc.load(rep.signature)
-    assert upgraded["format"] == 5
-    assert any(grec.get("recompute") for grec in upgraded.get("groups", []))
 
-
-def test_v4_measured_partition_marker_still_trusted():
-    entry = {"format": 4, "partition_source": "measured"}
-    assert entry_partition_source(entry) == "measured"
-    assert entry_partition_source({"format": 5,
-                                   "partition_source": "measured"}) \
-        == "measured"
-    assert entry_partition_source({"format": 3,
-                                   "partition_source": "measured"}) == "model"
-
-
-def test_sanitize_override_recompute(monkeypatch):
+def test_sanitize_override_recompute():
     over = _sanitize_override({"schedule": "onepass", "block_rows": 8,
                                "recompute": [3, 5, 3]})
     assert over["recompute"] == [3, 5]
@@ -297,10 +252,6 @@ def test_sanitize_override_recompute(monkeypatch):
         {"schedule": "onepass", "recompute": [3, "x"]})
     assert "recompute" not in _sanitize_override(
         {"schedule": "streaming", "recompute": [3]})
-    # with the knob off the pin degrades to re-deciding
-    monkeypatch.setenv("REPRO_RECOMPUTE", "0")
-    assert "recompute" not in _sanitize_override(
-        {"schedule": "onepass", "recompute": [3, 5]})
 
 
 # ---------------------------------------------------------------------------
@@ -358,7 +309,7 @@ def test_autotuned_stage_vs_recompute_commit(monkeypatch, tmp_path):
     np.testing.assert_allclose(y, np.asarray(oracle(x, g)),
                                rtol=2e-5, atol=2e-5)
     entry = PlanCache(str(tmp_path)).load(rep.signature)
-    assert entry["format"] == 5            # no anchors in _fanout
+    assert entry["format"] == FORMAT_VERSION
     assert any(p.get("recompute") for p in entry["patterns"])
 
 

@@ -3,10 +3,10 @@ shard through ``shard_map``.
 
 In-process tests cover the pieces that need no real multi-device mesh:
 ``_fit_spec`` repair/dedupe, ``ShardCtx`` local-shape math, plan-cache
-v7 signatures (a mesh can never collide with mesh-free), the
+signatures (a mesh can never collide with mesh-free), and the
 collective-as-boundary planning contract (an explicit (1, 1) host mesh
-exercises the whole sharded pipeline on a single device), and the
-``REPRO_SHARD=0`` kill switch.  True 8-device numerics run in
+exercises the whole sharded pipeline on a single device).  True
+8-device numerics run in
 subprocesses via the ``run_sharded`` fixture, where
 ``--xla_force_host_platform_device_count`` can be set before jax init.
 """
@@ -159,8 +159,8 @@ def test_sharded_and_meshfree_entries_roundtrip_independently(tmp_path):
     pc = PlanCache(str(tmp_path))
     e_free = pc.load(rep_free.signature)
     e_shard = pc.load(rep_shard.signature)
-    assert e_free is not None and e_free["format"] < FORMAT_VERSION
-    assert "mesh" not in e_free        # mesh-free entries stay v5/v6
+    assert e_free is not None and e_free["format"] == FORMAT_VERSION
+    assert "mesh" not in e_free
     assert e_shard is not None and e_shard["format"] == FORMAT_VERSION
     assert e_shard["mesh"] == {"shape": [1, 1], "axes": ["data", "model"]}
 
@@ -219,27 +219,6 @@ def test_explicit_shard_api_validation():
         StitchedFunction(_chain, dispatch="interpret",
                          mesh=make_test_mesh(1), in_specs=(P(),),
                          out_specs=(P(),))
-
-
-def test_repro_shard_kill_switch_degrades_never_rekeys(tmp_path,
-                                                       monkeypatch):
-    x = np.asarray(rng.integers(-2, 3, (8, 16)), np.float32)
-    kw = dict(mesh=make_test_mesh(1), in_specs=(P(),), out_specs=(P(),))
-    rep_on = StitchedFunction(_chain, plan_cache=str(tmp_path),
-                              **kw).report(x)
-
-    monkeypatch.setenv("REPRO_SHARD", "0")
-    sf = StitchedFunction(_chain, **kw)
-    out = sf(x)
-    rep = sf.reports()[0]
-    np.testing.assert_allclose(np.asarray(out), np.asarray(_chain(x)),
-                               rtol=1e-6)
-    assert rep.rung == RUNG_BASELINE         # pinned, not crashed
-    assert rep.signature == rep_on.signature  # knob degrades, never re-keys
-    # and a disabled compile is never persisted
-    sf2 = StitchedFunction(_chain, plan_cache=str(tmp_path / "off"), **kw)
-    rep2 = sf2.report(x)
-    assert PlanCache(str(tmp_path / "off")).load(rep2.signature) is None
 
 
 # ---------------------------------------------------------------------------

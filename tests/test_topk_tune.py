@@ -4,14 +4,12 @@ Covers: top-k partition distinctness/ranking from ``search_groups``,
 the ``REPRO_STITCH_TOPK`` knob, batched-vs-serial ``tune_partitions``
 equivalence via the ``_time_callable`` seam, the end-to-end
 measured-vs-model disagreement path (a stubbed timer forces a runner-up
-partition to win on "silicon"), plan-cache v4 round-trip (measured
-partitions replay without re-measuring; v3 entries degrade to
-re-measuring and are upgraded in place), ``partition_source``
+partition to win on "silicon"), the plan-cache round-trip (measured
+partitions replay without re-measuring), ``partition_source``
 reporting, COL-role interface outputs exposed by candidate boundaries,
 the deterministic beam tie-break, the timer synchronization fix, and
 the plan-cache eviction grace window.
 """
-import json
 import os
 import random
 import time
@@ -27,7 +25,7 @@ from repro.core import autotune as autotune_mod
 from repro.core import stitch as stitch_mod
 from repro.core.autotune import tune_partitions
 from repro.core.ir import FusionPlan, Pattern
-from repro.core.plan_cache import PlanCache, \
+from repro.core.plan_cache import FORMAT_VERSION, PlanCache, \
     entry_partition_source
 from repro.core.stitcher import (DEFAULT_TOPK, TopKResult, _state_rank_key,
                                  _State, topk_from_env)
@@ -196,7 +194,7 @@ def test_measured_partition_disagreement_end_to_end(monkeypatch, tmp_path):
     np.testing.assert_allclose(y, ref, rtol=1e-4, atol=1e-4)
 
     entry = PlanCache(str(tmp_path)).load(rep1.signature)
-    assert entry["format"] == 5            # anchor-free plan: native v5
+    assert entry["format"] == FORMAT_VERSION
     assert entry["partition_source"] == "measured"
     assert entry_partition_source(entry) == "measured"
 
@@ -216,44 +214,6 @@ def test_measured_partition_disagreement_end_to_end(monkeypatch, tmp_path):
     assert rep2.groups == rep1.groups      # same committed partition
     np.testing.assert_allclose(np.asarray(sf2(*args)), y,
                                rtol=1e-6, atol=1e-6)
-
-
-def test_v3_entry_degrades_to_remeasure_and_upgrades(monkeypatch, tmp_path):
-    monkeypatch.setenv("REPRO_AUTOTUNE", "force")
-    monkeypatch.setattr(autotune_mod, "_time_callable",
-                        _force_partition_timer(0))
-    args = _deep_args()
-    sf1 = StitchedFunction(_deep, autotune=True, plan_cache=str(tmp_path))
-    rep1 = sf1.report(*args)
-    path = os.path.join(str(tmp_path), f"{rep1.signature}.json")
-    with open(path) as f:
-        entry = json.load(f)
-    entry["format"] = 3                    # downgrade: strip the v4 marker
-    entry.pop("checksum", None)            # pre-checksum era had none
-    entry.pop("partition_source", None)
-    with open(path, "w") as f:
-        json.dump(entry, f)
-    assert entry_partition_source(entry) == "model"
-
-    calls = []
-    real = autotune_mod.tune_partitions
-
-    def counting(*a, **k):
-        calls.append(a)
-        return real(*a, **k)
-
-    monkeypatch.setattr(autotune_mod, "tune_partitions", counting)
-    sf2 = StitchedFunction(_deep, autotune=True, plan_cache=str(tmp_path))
-    rep2 = sf2.report(*args)
-    assert rep2.plan_cache_hit             # the plan itself was reused
-    assert rep2.partition_source == "measured"
-    assert calls                           # the partition was re-raced
-    upgraded = PlanCache(str(tmp_path)).load(rep1.signature)
-    assert upgraded["format"] == 5         # anchor-free plan: native v5
-    assert upgraded["partition_source"] == "measured"
-    ref = np.asarray(_deep(*(jnp.asarray(a) for a in args)))
-    np.testing.assert_allclose(np.asarray(sf2(*args)), ref,
-                               rtol=1e-4, atol=1e-4)
 
 
 def test_partition_source_model_without_autotune():
