@@ -6,7 +6,11 @@ of ``n_slots`` decode slots advances in lock-step (one jitted vmap'd
 decode per wave), each slot carrying its own KV/SSM cache and position;
 finished slots are refilled from the queue mid-flight via a single-slot
 prefill written into the stacked cache (no global re-batch, no pause of
-in-flight requests).
+in-flight requests).  The prefill fills an empty one-slot cache built
+once, and one jitted program writes the filled slot into the stacked
+cache at a traced slot index -- one compile for every slot and prompt
+length, with the stacked cache donated as the wave donates it, so XLA
+writes the one slot in place instead of copying every stacked leaf.
 
 Serving the compiler (paper §7, tune-once-run-many):
 
@@ -44,6 +48,7 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core.stitch import StitchedFunction, stitched_jit
 from repro.models.model import Model
@@ -231,11 +236,14 @@ class ContinuousBatcher:
             canary = CanaryController.from_env(plan_cache)
         self._canary = canary if self.stitched else None
 
-        one = mdl.init_cache(1, max_len)
+        #: the empty one-slot cache every prefill fills: arrays are
+        #: immutable and the prefill donates none of its arguments
+        self._empty_slot = mdl.init_cache(1, max_len)
         self.cache = jax.tree_util.tree_map(
-            lambda x: jnp.zeros((n_slots,) + x.shape, x.dtype), one)
-        #: leaves a prefill makes and writes (the cache-span stat)
-        self._cache_leaves = len(jax.tree_util.tree_leaves(one))
+            lambda x: jnp.zeros((n_slots,) + x.shape, x.dtype),
+            self._empty_slot)
+        #: leaves a prefill writes (the cache-span stat)
+        self._cache_leaves = len(jax.tree_util.tree_leaves(self._empty_slot))
 
         # the function names name the stitched programs
         # (``stitched_prefill``, ``stitched_decode_wave``)
@@ -281,6 +289,17 @@ class ContinuousBatcher:
             return toks[:, None, None], poss + 1
 
         self._advance = jax.jit(advance)
+
+        # a prefilled slot enters the stacked cache at a traced index:
+        # one compile serves every slot and prompt length, and with the
+        # cache donated XLA writes the slot in place
+        def write_slot(cache, filled, i):
+            return jax.tree_util.tree_map(
+                lambda st, c: lax.dynamic_update_index_in_dim(st, c, i, 0),
+                cache, filled)
+
+        self._write_slot = jax.jit(
+            write_slot, donate_argnums=(0,) if donate else ())
 
     @property
     def cache(self):
@@ -337,7 +356,8 @@ class ContinuousBatcher:
             except Exception:  # noqa: BLE001 -- older jax without the API
                 return -1
         return {"prefill": count(self._prefill),
-                "decode": count(self._decode_wave)}
+                "decode": count(self._decode_wave),
+                "slot_write": count(self._write_slot)}
 
     # -- internals ---------------------------------------------------------------
     def _note_call(self, shape_key: tuple, dt: float, tokens: int) -> None:
@@ -408,14 +428,12 @@ class ContinuousBatcher:
                 toks = pad_tokens(req.prompt, plen, pad_id=self.pad_id)
             else:
                 toks = req.prompt
-            with spans.span("prefill.cache_init",
-                            leaves=self._cache_leaves):
-                one = self.mdl.init_cache(1, self.max_len)
-            logits, filled = self._prefill(self.params, toks[None, :], one)
+            logits, filled = self._prefill(self.params, toks[None, :],
+                                           self._empty_slot)
             with spans.span("prefill.cache_write",
                             leaves=self._cache_leaves):
-                self._cache = jax.tree_util.tree_map(
-                    lambda st, c: st.at[i].set(c), self._cache, filled)
+                self._cache = self._write_slot(self._cache, filled,
+                                               np.int32(i))
             with spans.span("prefill.sample"):
                 # the *true* last prompt position: the causal mask makes
                 # the padded tail invisible to it.

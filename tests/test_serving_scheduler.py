@@ -178,3 +178,53 @@ def test_each_step_adds_one_token_and_no_wave_outlives_a_stop():
             if r is not None and r.done:
                 server.slots[i] = None
     assert stops >= 3 and server.stats.waves_ahead > 0
+
+
+@pytest.mark.parametrize("arch", ["llama3.2-3b", "mamba2-370m",
+                                  "zamba2-1.2b", "granite-4.0-h-small"])
+def test_prefill_writes_its_slot_and_only_it(arch):
+    """Attention, scanned SSM, hybrid and layer-pattern caches: the
+    prefilled slot holds exactly what the prefill made, and every other
+    slot keeps its state bit for bit."""
+    cfg, mdl, params = _setup(arch)
+    server = ContinuousBatcher(mdl, params, n_slots=3, max_len=32)
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 256))
+    server.cache = jax.tree_util.tree_map(
+        lambda x: jax.random.normal(next(keys), x.shape, x.dtype) + 1.0,
+        server.cache)
+    before = jax.tree_util.tree_map(np.asarray, server.cache)
+    made = []
+
+    def prefill(*args):
+        out = real(*args)
+        made.append(out[1])
+        return out
+
+    real, server._prefill = server._prefill, prefill
+    server.submit(rng.integers(0, cfg.vocab_size, 7).astype(np.int32),
+                  max_new=4)
+    server._prefill_slot(1, server.queue.popleft())
+    (filled,) = made
+    after = jax.tree_util.tree_leaves(server.cache)
+    assert len(after) == len(jax.tree_util.tree_leaves(filled))
+    for st, old, c in zip(after, jax.tree_util.tree_leaves(before),
+                          jax.tree_util.tree_leaves(filled)):
+        st = np.asarray(st)
+        np.testing.assert_array_equal(st[1], np.asarray(c))
+        np.testing.assert_array_equal(st[0], old[0])
+        np.testing.assert_array_equal(st[2], old[2])
+
+
+def test_slot_write_compiles_once_for_every_slot_and_length():
+    """Every slot at prompt lengths of two buckets: one slot-write
+    program serves every prefill."""
+    cfg, mdl, params = _setup()
+    server = ContinuousBatcher(mdl, params, n_slots=3, max_len=48)
+    for n in (5, 12, 20):
+        for i in range(server.n_slots):
+            server.submit(rng.integers(0, cfg.vocab_size, n).astype(np.int32),
+                          max_new=2)
+            server._prefill_slot(i, server.queue.popleft())
+    assert server.stats.prefills == 9
+    counts = server.compile_counts()
+    assert counts["prefill"] == 3 and counts["slot_write"] == 1

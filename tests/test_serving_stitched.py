@@ -121,7 +121,8 @@ def test_bucket_boundary_lengths():
     for rid, ref in zip(rids, refs):
         assert results[rid] == ref
     # 7 and 8 share the 8-bucket; 9 pads to 16: exactly two prefills
-    assert server.compile_counts() == {"prefill": 2, "decode": 1}
+    assert server.compile_counts() == {"prefill": 2, "decode": 1,
+                                       "slot_write": 1}
 
 
 def test_eos_mid_wave_and_refill():
@@ -162,7 +163,8 @@ def test_prompt_mix_compiles_once_per_bucket():
     for p in prompts:
         server.submit(p, max_new=3)
     server.run()
-    assert server.compile_counts() == {"prefill": 2, "decode": 1}
+    assert server.compile_counts() == {"prefill": 2, "decode": 1,
+                                       "slot_write": 1}
     assert server.stats.replans == 3          # 2 prefill shapes + 1 decode
     assert server.stats.shape_hits > 0
     assert 0.0 < server.stats.hit_rate < 1.0
@@ -173,7 +175,8 @@ def test_prompt_mix_compiles_once_per_bucket():
         server.submit(p, max_new=3)
     server.run()
     assert server.stats.replans == before
-    assert server.compile_counts() == {"prefill": 2, "decode": 1}
+    assert server.compile_counts() == {"prefill": 2, "decode": 1,
+                                       "slot_write": 1}
 
 
 def test_ssm_prompts_stay_exact():

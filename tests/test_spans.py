@@ -85,7 +85,7 @@ def test_prefill_and_wave_spans_nest_as_named(batcher, monkeypatch):
     cb._prefill_slot(0, req)
     cb.slots[0] = req
     cold = rec.take()
-    lookup = cold[0][2][1][2][0]
+    lookup = cold[0][2][0][2][0]
     assert lookup[0] == "stitch.lookup"
     build = lookup[2][0]
     assert build[:2] == ["stitch.build", {"program": "stitched_prefill"}]
@@ -103,12 +103,11 @@ def test_prefill_and_wave_spans_nest_as_named(batcher, monkeypatch):
     cb._decode_step()
     prefill, wave = rec.take()
     assert shape(prefill) == ("serve.prefill", (
-        ("prefill.cache_init", ()), CALL, ("prefill.cache_write", ()),
-        ("prefill.sample", ())))
+        CALL, ("prefill.cache_write", ()), ("prefill.sample", ())))
     assert prefill[1] == {"rid": req.rid, "slot": 1, "plen": 7}
     leaves = {"leaves": len(jax.tree_util.tree_leaves(cb.cache))}
-    assert prefill[2][0][1] == leaves and prefill[2][2][1] == leaves
-    assert prefill[2][1][1] == {"program": "stitched_prefill"}
+    assert prefill[2][1][1] == leaves
+    assert prefill[2][0][1] == {"program": "stitched_prefill"}
     assert shape(wave) == ("serve.wave", (
         ("wave.inputs", ()), CALL, ("wave.ahead", (CALL,)),
         ("wave.sample", ()), ("wave.retire", ())))
