@@ -40,11 +40,15 @@ class ArchConfig:
     conv_width: int = 4
     # hybrid (Zamba2): shared attention block applied every N layers
     attn_every: int = 0
-    # hybrid by pattern (Granite-4.0-H): one letter per layer, "M" a
-    # Mamba-2 mixer, "A" attention, each with its own weights and an FFN
-    # block (routed experts plus a shared MLP) after it.  "" -> Zamba's
-    # shared block every ``attn_every`` layers.
+    # hybrid by pattern (Granite-4.0-H, SmallThinker): one letter per
+    # layer, "M" a Mamba-2 mixer, "A" causal attention over the whole
+    # sequence, "S" causal attention over the last ``sliding_window``
+    # positions, each with its own weights and an FFN block (routed
+    # experts, plus a shared MLP when ``d_ff_shared``) after it.  "" ->
+    # Zamba's shared block every ``attn_every`` layers.
     layer_pattern: str = ""
+    sliding_window: int = 0     # positions an "S" layer's query sees
+    pre_attention_router: bool = False  # the FFN routes on the mixer's input
     experts_held: int = 0       # experts this chip holds (0: all)
     expert_offset: int = 0      # index of the first expert held
     d_ff_shared: int = 0        # shared MLP width of the FFN block
@@ -54,7 +58,7 @@ class ArchConfig:
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
     attention_multiplier: float = 0.0   # softmax scale; 0 -> 1/sqrt(Dh)
-    position_embedding: str = "rope"    # rope | nope
+    position_embedding: str = "rope"    # rope | nope ("S" layers: rope)
     # modality frontends (stub inputs per task spec)
     frontend: str = "none"      # none | audio | vision
     frontend_dim: int = 0       # audio: conv-stem feature dim
@@ -70,10 +74,13 @@ class ArchConfig:
 
     def __post_init__(self):
         if self.layer_pattern and (len(self.layer_pattern) != self.n_layers
-                                   or set(self.layer_pattern) - set("MA")):
+                                   or set(self.layer_pattern) - set("MAS")):
             raise ValueError(f"layer_pattern {self.layer_pattern!r} needs "
-                             f"one of 'M' or 'A' per layer "
+                             f"one of 'M', 'A' or 'S' per layer "
                              f"({self.n_layers})")
+        if ("S" in self.layer_pattern) != (self.sliding_window > 0):
+            raise ValueError("a sliding_window needs 'S' layers in the "
+                             "layer_pattern, and 'S' layers need one")
 
     @property
     def resolved_head_dim(self) -> int:
@@ -101,7 +108,8 @@ class ArchConfig:
 
     def reduced(self, **overrides) -> "ArchConfig":
         """Tiny same-family config for CPU smoke tests.  A layer pattern
-        keeps one attention layer between two Mamba-2 layers."""
+        keeps one attention layer between two Mamba-2 layers, or with
+        windowed layers one period "ASSS" with an 8-position window."""
         small = dict(
             n_layers=min(self.n_layers, 2 if self.attn_every == 0 else 4),
             d_model=128,
@@ -126,6 +134,9 @@ class ArchConfig:
                          experts_held=min(self.experts_held, 2),
                          expert_offset=0,
                          d_ff_shared=96 if self.d_ff_shared else 0)
+            if self.sliding_window:
+                small.update(n_layers=4, layer_pattern="ASSS",
+                             sliding_window=8)
         small.update(overrides)
         return dataclasses.replace(self, **small)
 
@@ -137,6 +148,7 @@ ARCH_IDS = [
     "zamba2-1.2b", "internvl2-26b", "deepseek-67b", "mistral-nemo-12b",
     "llama3.2-3b", "gemma-7b", "hubert-xlarge", "mamba2-370m",
     "granite-moe-1b-a400m", "granite-moe-3b-a800m", "granite-4.0-h-small",
+    "smallthinker-21b-a3b",
 ]
 
 _MODULE_OF = {a: a.replace("-", "_").replace(".", "_") for a in ARCH_IDS}

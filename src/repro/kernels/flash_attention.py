@@ -30,6 +30,44 @@ DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
 
 
+def _pad_seq(x, n: int):
+    """x [B, H, S, D] zero-padded along S to ``n`` positions."""
+    S = x.shape[2]
+    return x if S == n else jnp.pad(x, ((0, 0), (0, 0), (0, n - S), (0, 0)))
+
+
+def _scratch(blk_q: int, d: int):
+    return [pltpu.VMEM((blk_q, 1), jnp.float32),   # running max
+            pltpu.VMEM((blk_q, 1), jnp.float32),   # running denom
+            pltpu.VMEM((blk_q, d), jnp.float32)]   # output accumulator
+
+
+def _init_scratch(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _online_softmax_step(s, v, m_ref, l_ref, acc_ref):
+    """Fold one key block's masked scores ``s`` [bq, bk] and values
+    ``v`` [bk, D] into the running max, denominator and accumulator."""
+    m_prev = m_ref[...]                       # [bq, 1]
+    m_cur = jnp.max(s, axis=-1, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_cur)
+    p = jnp.exp(s - m_new)                    # [bq, bk]
+    alpha = jnp.exp(m_prev - m_new)           # rescale factor
+
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
+
+
+def _write_out(o_ref, l_ref, acc_ref):
+    denom = jnp.maximum(l_ref[...], 1e-30)
+    o_ref[...] = (acc_ref[...] / denom).reshape(o_ref.shape).astype(o_ref.dtype)
+
+
 def _attn_kernel(*refs, scale: float, causal: bool, sq: int, skv: int,
                  blk_q: int, blk_k: int, score_mod=None, n_score: int = 0):
     q_ref, k_ref, v_ref = refs[:3]
@@ -40,11 +78,7 @@ def _attn_kernel(*refs, scale: float, causal: bool, sq: int, skv: int,
     ik = pl.program_id(3)
     nk = pl.num_programs(3)
 
-    @pl.when(ik == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    pl.when(ik == 0)(lambda: _init_scratch(m_ref, l_ref, acc_ref))
 
     q = q_ref[...].reshape(blk_q, -1).astype(jnp.float32)   # [bq, D]
     k = k_ref[...].reshape(blk_k, -1).astype(jnp.float32)   # [bk, D]
@@ -68,22 +102,8 @@ def _attn_kernel(*refs, scale: float, causal: bool, sq: int, skv: int,
     if causal:
         mask &= q_idx + (skv - sq) >= k_idx  # causal offset for Sq != Skv
     s = jnp.where(mask, s, NEG_INF)
-
-    m_prev = m_ref[...]                       # [bq, 1]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_new)                    # [bq, bk]
-    alpha = jnp.exp(m_prev - m_new)           # rescale factor
-
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-
-    @pl.when(ik == nk - 1)
-    def _finish():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = (acc_ref[...] / denom).reshape(o_ref.shape).astype(o_ref.dtype)
+    _online_softmax_step(s, v, m_ref, l_ref, acc_ref)
+    pl.when(ik == nk - 1)(lambda: _write_out(o_ref, l_ref, acc_ref))
 
 
 def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
@@ -110,11 +130,7 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
     blk_k = max(1, min(block_k, Skv))
     Sqp = math.ceil(Sq / blk_q) * blk_q
     Skp = math.ceil(Skv / blk_k) * blk_k
-    if Sqp != Sq:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, Sqp - Sq), (0, 0)))
-    if Skp != Skv:
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, Skp - Skv), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, Skp - Skv), (0, 0)))
+    q, k, v = _pad_seq(q, Sqp), _pad_seq(k, Skp), _pad_seq(v, Skp)
 
     score_specs = []
     padded_scores = []
@@ -151,14 +167,108 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
         out_specs=pl.BlockSpec((1, 1, blk_q, D),
                                lambda b, h, iq, ik: (b, h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sqp, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((blk_q, 1), jnp.float32),   # running max
-            pltpu.VMEM((blk_q, 1), jnp.float32),   # running denom
-            pltpu.VMEM((blk_q, D), jnp.float32),   # output accumulator
-        ],
+        scratch_shapes=_scratch(blk_q, D),
         name=name,
         interpret=kernels.interpret_mode(),
     )(q, k, v, *padded_scores)
+    return out[:, :, :Sq, :]
+
+
+def _window_blocks(iq, *, blk_q: int, blk_k: int, off: int, window: int,
+                   nk: int):
+    """First and last key block that query block ``iq`` sees: its
+    queries ``iq*blk_q + off ...`` look back over ``window`` keys."""
+    lo = jnp.maximum(iq * blk_q + off - window + 1, 0) // blk_k
+    hi = jnp.minimum((iq * blk_q + blk_q - 1 + off) // blk_k, nk - 1)
+    return lo, hi
+
+
+def _window_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                   scale: float, sq: int, skv: int, blk_q: int, blk_k: int,
+                   window: int, nk: int):
+    iq = pl.program_id(2)
+    ik = pl.program_id(3)
+    off = skv - sq
+    lo, hi = _window_blocks(iq, blk_q=blk_q, blk_k=blk_k, off=off,
+                            window=window, nk=nk)
+    kb = lo + ik                              # the key block this step sees
+
+    pl.when(ik == 0)(lambda: _init_scratch(m_ref, l_ref, acc_ref))
+
+    @pl.when(kb <= hi)                        # past it: nothing in window
+    def _step():
+        q = q_ref[...].reshape(blk_q, -1).astype(jnp.float32)
+        k = k_ref[...].reshape(blk_k, -1).astype(jnp.float32)
+        v = v_ref[...].reshape(blk_k, -1).astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        q_idx = iq * blk_q + off + jax.lax.broadcasted_iota(
+            jnp.int32, (blk_q, blk_k), 0)
+        k_idx = kb * blk_k + jax.lax.broadcasted_iota(
+            jnp.int32, (blk_q, blk_k), 1)
+        back = q_idx - k_idx                  # 0 <= back < window is seen
+        mask = (k_idx < skv) & (back >= 0) & (back < window)
+        _online_softmax_step(jnp.where(mask, s, NEG_INF), v,
+                             m_ref, l_ref, acc_ref)
+
+    pl.when(ik == pl.num_programs(3) - 1)(
+        lambda: _write_out(o_ref, l_ref, acc_ref))
+
+
+def flash_attention_window(q, k, v, *, window: int,
+                           scale: float | None = None,
+                           block_q: int = DEFAULT_BLOCK_Q,
+                           block_k: int = DEFAULT_BLOCK_K,
+                           name: str = "flash_attention_window"):
+    """Causal attention over a sliding window: query ``i`` (at position
+    ``i + Skv - Sq``) sees key ``j`` iff ``0 <= i + Skv - Sq - j <
+    window``.  q: [B, Hq, Sq, D]; k, v: [B, Hkv, Skv, D].
+
+    The key-block axis of the grid spans only the blocks one query
+    block's window can touch, offset per query block in the K/V index
+    maps, so key blocks wholly outside ``(i - window, i]`` are neither
+    read nor computed; blocks on the window's edges are masked.  A step
+    past a query block's last key block repeats that block's index (no
+    copy) and computes nothing.
+    """
+    B, Hq, Sq, D = q.shape
+    _, Hkv, Skv, _ = k.shape
+    assert Hq % Hkv == 0, "GQA requires Hq % Hkv == 0"
+    assert window > 0, "a window of at least one position"
+    group = Hq // Hkv
+    scale = scale if scale is not None else 1.0 / math.sqrt(D)
+
+    blk_q = max(1, min(block_q, Sq))
+    blk_k = max(1, min(block_k, Skv))
+    Sqp = math.ceil(Sq / blk_q) * blk_q
+    Skp = math.ceil(Skv / blk_k) * blk_k
+    q, k, v = _pad_seq(q, Sqp), _pad_seq(k, Skp), _pad_seq(v, Skp)
+    nk = Skp // blk_k
+    # key blocks a span of blk_q + window - 1 positions can touch
+    nkw = min(nk, (blk_q + window - 2) // blk_k + 2)
+    bounds = functools.partial(_window_blocks, blk_q=blk_q, blk_k=blk_k,
+                               off=Skv - Sq, window=window, nk=nk)
+
+    def kv_map(b, h, iq, ik):
+        lo, hi = bounds(iq)
+        return b, h // group, jnp.minimum(lo + ik, hi), 0
+
+    out = pl.pallas_call(
+        functools.partial(_window_kernel, scale=scale, sq=Sq, skv=Skv,
+                          blk_q=blk_q, blk_k=blk_k, window=window, nk=nk),
+        grid=(B, Hq, Sqp // blk_q, nkw),
+        in_specs=[
+            pl.BlockSpec((1, 1, blk_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, blk_k, D), kv_map),
+            pl.BlockSpec((1, 1, blk_k, D), kv_map),
+        ],
+        out_specs=pl.BlockSpec((1, 1, blk_q, D),
+                               lambda b, h, iq, ik: (b, h, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Sqp, D), q.dtype),
+        scratch_shapes=_scratch(blk_q, D),
+        name=name,
+        interpret=kernels.interpret_mode(),
+    )(q, k, v)
     return out[:, :, :Sq, :]
 
 

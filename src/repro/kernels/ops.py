@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from . import ref
 from .flash_attention import flash_attention as _flash_attention
+from .flash_attention import flash_attention_window as _flash_window
 from .flash_attention import flash_decode as _flash_decode
 from .layernorm import layernorm as _layernorm_kernel
 from .rmsnorm import rmsnorm as _rmsnorm_kernel
@@ -40,23 +41,28 @@ def softmax(x, *, use_pallas: bool = True):
     return ref.softmax(x)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _attention_diff(q, k, v, causal, scale, block_q, block_k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _attention_diff(q, k, v, causal, scale, block_q, block_k, window):
+    if window:
+        return _flash_window(q, k, v, window=window, scale=scale,
+                             block_q=block_q, block_k=block_k)
     return _flash_attention(q, k, v, causal=causal, scale=scale,
                             block_q=block_q, block_k=block_k)
 
 
-def _attention_fwd(q, k, v, causal, scale, block_q, block_k):
-    return _attention_diff(q, k, v, causal, scale, block_q, block_k), (q, k, v)
+def _attention_fwd(q, k, v, causal, scale, block_q, block_k, window):
+    return (_attention_diff(q, k, v, causal, scale, block_q, block_k,
+                            window), (q, k, v))
 
 
-def _attention_bwd(causal, scale, block_q, block_k, res, do):
+def _attention_bwd(causal, scale, block_q, block_k, window, res, do):
     # backward via the oracle's VJP (recompute-style; the Pallas backward
     # kernel is a further optimization tracked in EXPERIMENTS.md §Perf)
     q, k, v = res
     _, pullback = jax.vjp(
         lambda q_, k_, v_: ref.attention(q_, k_, v_, causal=causal,
-                                         scale=scale), q, k, v)
+                                         scale=scale, window=window),
+        q, k, v)
     return pullback(do)
 
 
@@ -64,10 +70,15 @@ _attention_diff.defvjp(_attention_fwd, _attention_bwd)
 
 
 def attention(q, k, v, *, causal: bool = True, scale=None,
-              use_pallas: bool = True, block_q: int = 128, block_k: int = 128):
+              use_pallas: bool = True, block_q: int = 128, block_k: int = 128,
+              window: int = 0):
+    """``window`` > 0: causal attention over the last ``window``
+    positions (``flash_attention_window``)."""
+    assert causal or not window, "a window is causal"
     if use_pallas:
-        return _attention_diff(q, k, v, causal, scale, block_q, block_k)
-    return ref.attention(q, k, v, causal=causal, scale=scale)
+        return _attention_diff(q, k, v, causal, scale, block_q, block_k,
+                               window)
+    return ref.attention(q, k, v, causal=causal, scale=scale, window=window)
 
 
 def decode_attention(q, k_cache, v_cache, *, kv_len=None, scale=None,

@@ -46,10 +46,13 @@ def bias_gelu(x, bias):
 # --------------------------------------------------------------------------
 # attention
 # --------------------------------------------------------------------------
-def attention(q, k, v, *, causal: bool = True, scale: float | None = None):
+def attention(q, k, v, *, causal: bool = True, scale: float | None = None,
+              window: int = 0):
     """Reference multi-head attention (repeat-free GQA).
 
     q: [B, Hq, Sq, D]; k, v: [B, Hkv, Skv, D] (GQA: Hq % Hkv == 0).
+    ``window`` > 0 (causal only): query ``i`` sees key ``j`` iff
+    ``0 <= i + Skv - Sq - j < window``.
     The grouped-query einsum contracts against the UNEXPANDED kv tensors:
     materializing ``jnp.repeat(k, group)`` makes GSPMD all-gather the KV
     cache across the TP axis at decode shapes (1 GiB/layer for
@@ -64,6 +67,8 @@ def attention(q, k, v, *, causal: bool = True, scale: float | None = None):
                         preferred_element_type=jnp.float32) * scale
     if causal:
         mask = jnp.tril(jnp.ones((Sq, Skv), bool), k=Skv - Sq)
+        if window:
+            mask &= ~jnp.tril(jnp.ones((Sq, Skv), bool), k=Skv - Sq - window)
         logits = jnp.where(mask, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgqs,bksd->bkgqd", probs.astype(q.dtype), v)

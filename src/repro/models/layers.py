@@ -100,10 +100,20 @@ def attn_init(cfg: ArchConfig, key, dtype, d_in: int | None = None):
 
 
 def attn_apply(cfg: ArchConfig, p, x, *, fm: FusionMode, positions,
-               cache=None, cache_pos=None, kv_len=None, x_kv=None):
+               cache=None, cache_pos=None, kv_len=None, x_kv=None,
+               window: int = 0, use_rope: bool | None = None):
     """x: [B, S, d_in].  Prefill fills ``cache`` when provided with S > 1;
     decode (S == 1) updates ``cache`` at ``cache_pos`` and streams the
-    cache.  Returns (out [B,S,d_model], new_cache)."""
+    cache.  Returns (out [B,S,d_model], new_cache).
+
+    ``window`` > 0: a query sees the last ``window`` positions, and the
+    cache is a ring of ``R = min(window, max_len)`` positions
+    (``attn_cache_init``) holding position ``t`` at ``t mod R``: prefill
+    writes the prompt's last ``R`` keys there, decode writes at
+    ``cache_pos mod R`` and reads ``min(kv_len, R)`` slots.  Keys carry
+    their rotary phase from before they were cached, and attention over
+    a set of keys does not depend on their order in the ring.
+    ``use_rope`` (default: ``cfg.position_embedding``) rotates q and k."""
     B, S, _ = x.shape
     Dh, Hq, Hkv = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
     xk = x if x_kv is None else x_kv
@@ -111,31 +121,36 @@ def attn_apply(cfg: ArchConfig, p, x, *, fm: FusionMode, positions,
     q = (x @ p["wq"]).reshape(B, S, Hq, Dh).transpose(0, 2, 1, 3)
     k = (xk @ p["wk"]).reshape(B, S, Hkv, Dh).transpose(0, 2, 1, 3)
     v = (xk @ p["wv"]).reshape(B, S, Hkv, Dh).transpose(0, 2, 1, 3)
-    if cfg.position_embedding != "nope":
+    if use_rope is None:
+        use_rope = cfg.position_embedding != "nope"
+    if use_rope:
         q, k = rope(q, k, positions, cfg.rope_theta)
     q = constrain(q, "act_bhsd")
     scale = cfg.attention_multiplier or None
 
     if cache is None:
         o = ops.attention(q, k, v, causal=cfg.causal, scale=scale,
-                          use_pallas=fm.use_pallas)
+                          use_pallas=fm.use_pallas, window=window)
         new_cache = None
     elif S > 1:  # prefill into pre-allocated cache
-        kc = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
-                                          (0, 0, 0, 0))
-        vc = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
-                                          (0, 0, 0, 0))
+        kc = _cache_fill(cache["k"], k, window)
+        vc = _cache_fill(cache["v"], v, window)
         kc, vc = constrain(kc, "kv_cache"), constrain(vc, "kv_cache")
         o = ops.attention(q, k, v, causal=cfg.causal, scale=scale,
-                          use_pallas=fm.use_pallas)
+                          use_pallas=fm.use_pallas, window=window)
         new_cache = {"k": kc, "v": vc}
     else:        # decode one token
+        ring = cache["k"].shape[2]
+        at = jax.lax.rem(cache_pos, ring) if window else cache_pos
         kc = jax.lax.dynamic_update_slice(
-            cache["k"], k.astype(cache["k"].dtype), (0, 0, cache_pos, 0))
+            cache["k"], k.astype(cache["k"].dtype), (0, 0, at, 0))
         vc = jax.lax.dynamic_update_slice(
-            cache["v"], v.astype(cache["v"].dtype), (0, 0, cache_pos, 0))
+            cache["v"], v.astype(cache["v"].dtype), (0, 0, at, 0))
         kc, vc = constrain(kc, "kv_cache"), constrain(vc, "kv_cache")
         eff = kv_len if kv_len is not None else kc.shape[2]
+        if window:
+            eff = (min(eff, ring) if isinstance(eff, (int, np.integer))
+                   else jnp.minimum(eff, ring))
         o = ops.decode_attention(q[:, :, 0, :], kc, vc, kv_len=eff,
                                  scale=scale,
                                  use_pallas=fm.use_pallas)[:, :, None, :]
@@ -145,10 +160,25 @@ def attn_apply(cfg: ArchConfig, p, x, *, fm: FusionMode, positions,
     return o @ p["wo"], new_cache
 
 
-def attn_cache_init(cfg: ArchConfig, batch: int, max_len: int, dtype):
+def _cache_fill(cache, x, window: int):
+    """A prompt's keys or values ``x`` [B, H, S, D] written from position
+    0: in order, or for a ring shorter than the prompt the last ``R``
+    positions, position ``t`` at ``t mod R``."""
+    x = x.astype(cache.dtype)
+    S, ring = x.shape[2], cache.shape[2]
+    if window and S > ring:
+        return jnp.roll(x[:, :, S - ring:], (S - ring) % ring, axis=2)
+    return jax.lax.dynamic_update_slice(cache, x, (0, 0, 0, 0))
+
+
+def attn_cache_init(cfg: ArchConfig, batch: int, max_len: int, dtype,
+                    window: int = 0):
+    """KV of ``max_len`` positions, or a ring of ``min(window, max_len)``
+    for a windowed layer."""
     Dh, Hkv = cfg.resolved_head_dim, cfg.n_kv_heads
-    return {"k": jnp.zeros((batch, Hkv, max_len, Dh), dtype),
-            "v": jnp.zeros((batch, Hkv, max_len, Dh), dtype)}
+    n = min(window, max_len) if window else max_len
+    return {"k": jnp.zeros((batch, Hkv, n, Dh), dtype),
+            "v": jnp.zeros((batch, Hkv, n, Dh), dtype)}
 
 
 # ---------------------------------------------------------------------------
@@ -167,6 +197,8 @@ def mlp_init(cfg: ArchConfig, key, dtype, d_ff: int | None = None):
 def _act(name: str, x):
     if name in ("gelu", "gelu_mlp"):
         return jax.nn.gelu(x, approximate=True)
+    if name == "relu":
+        return jax.nn.relu(x)
     return jax.nn.silu(x)
 
 
@@ -339,29 +371,43 @@ def moe_share_init(cfg: ArchConfig, key, dtype):
     }
 
 
-def moe_share_apply(cfg: ArchConfig, p, x, fm: FusionMode):
-    """The part of a routed expert layer's output that the experts held
-    here give (expert parallelism without its exchange).  x: [B, S, d].
-
-    The router scores all ``n_experts``; a token's gates are the softmax
-    over its ``top_k`` logits (Granite's ``TopKGating``).  Held expert
-    ``expert_offset + j`` adds ``gate * FFN_j(x)`` for every token routed
-    to it: no capacity, nothing dropped.  The first cut is dense: every
-    held expert runs on every token with a zero gate where it was not
-    chosen, so each held expert's weights are read once per call.
-    """
-    B, S, d = x.shape
-    xt = x.reshape(B * S, d)
+def moe_share_route(cfg: ArchConfig, p, xt):
+    """Each token's gate on each expert held here, [T, held], for tokens
+    ``xt`` [T, d]: the router scores all ``n_experts``, and a token's
+    gates are the softmax over its ``top_k`` logits (Granite's
+    ``TopKGating``; the same as a softmax over all, kept to the top ``k``
+    and renormalised), zero on a held expert it was not routed to."""
     top, idx = jax.lax.top_k((xt @ p["router"]).astype(jnp.float32),
                              cfg.top_k)                       # [T, k]
     gates = jax.nn.softmax(top, axis=-1)
     held = cfg.expert_offset + jnp.arange(p["w_gate"].shape[0])
-    gate = jnp.sum(jnp.where(idx[:, :, None] == held, gates[:, :, None],
+    return jnp.sum(jnp.where(idx[:, :, None] == held, gates[:, :, None],
                              0.0), axis=1)                    # [T, held]
+
+
+def moe_share_experts(cfg: ArchConfig, p, xt, gate):
+    """The held experts' part of the routed output of tokens ``xt``
+    [T, d] under the gates of ``moe_share_route``.  Held expert
+    ``expert_offset + j`` adds ``gate * FFN_j(x)`` for every token routed
+    to it: no capacity, nothing dropped.  The first cut is dense: every
+    held expert runs on every token with a zero gate where it was not
+    chosen, so each held expert's weights are read once per call."""
     h = _act(cfg.activation, jnp.einsum("td,edf->tef", xt, p["w_gate"])) \
         * jnp.einsum("td,edf->tef", xt, p["w_up"])
     h = h * gate[:, :, None].astype(h.dtype)
-    return jnp.einsum("tef,efd->td", h, p["w_down"]).reshape(B, S, d)
+    return jnp.einsum("tef,efd->td", h, p["w_down"])
+
+
+def moe_share_apply(cfg: ArchConfig, p, x, fm: FusionMode, gate=None):
+    """The part of a routed expert layer's output that the experts held
+    here give (expert parallelism without its exchange).  x: [B, S, d].
+    Routed on ``x`` itself, or by ``gate`` [B*S, held] where the model
+    routes on another input (``moe_share_route``)."""
+    B, S, d = x.shape
+    xt = x.reshape(B * S, d)
+    if gate is None:
+        gate = moe_share_route(cfg, p, xt)
+    return moe_share_experts(cfg, p, xt, gate).reshape(B, S, d)
 
 
 # ---------------------------------------------------------------------------

@@ -4,10 +4,10 @@ Families: dense, vlm (dense + vision-token stub), encoder (bidirectional),
 moe, ssm (Mamba2), hybrid.  A hybrid stack is either Zamba2's (Mamba2
 backbone + shared attention block every ``attn_every`` layers, weights
 shared across applications, input = concat(hidden, initial embedding))
-or driven by ``layer_pattern`` (Granite-4.0-H: each layer a Mamba2 or an
-attention mixer with its own weights, then an FFN block of routed
-experts plus a shared MLP, both sub-blocks scaled before their residual
-add).
+or driven by ``layer_pattern`` (Granite-4.0-H, SmallThinker: each layer
+a Mamba2, a full or a sliding-window attention mixer with its own
+weights, then an FFN block of routed experts, plus a shared MLP where
+the model has one, both sub-blocks scaled before their residual add).
 
 Homogeneous stacks run under ``lax.scan`` with stacked params (compile
 time stays flat in depth — 95-layer deepseek lowers as one scanned
@@ -86,35 +86,48 @@ def block_apply(cfg: ArchConfig, p, h, *, fm: FusionMode, positions,
 
 
 def pattern_block_init(cfg: ArchConfig, kind: str, key, dtype):
-    """One layer of a ``layer_pattern`` stack: ``kind`` "M" or "A"."""
+    """One layer of a ``layer_pattern`` stack: ``kind`` "M", "A" or "S"."""
     k1, k2, k3 = jax.random.split(key, 3)
-    mixer = ({"attn": L.attn_init(cfg, k1, dtype)} if kind == "A" else
-             {"mamba": L.mamba_init(cfg, k1, dtype)})
-    return {"norm1": L.norm_init(cfg, dtype), **mixer,
-            "norm2": L.norm_init(cfg, dtype),
-            "moe": L.moe_share_init(cfg, k2, dtype),
-            "shared_mlp": L.mlp_init(cfg, k3, dtype, d_ff=cfg.d_ff_shared)}
+    mixer = ({"mamba": L.mamba_init(cfg, k1, dtype)} if kind == "M" else
+             {"attn": L.attn_init(cfg, k1, dtype)})
+    p = {"norm1": L.norm_init(cfg, dtype), **mixer,
+         "norm2": L.norm_init(cfg, dtype),
+         "moe": L.moe_share_init(cfg, k2, dtype)}
+    if cfg.d_ff_shared:
+        p["shared_mlp"] = L.mlp_init(cfg, k3, dtype, d_ff=cfg.d_ff_shared)
+    return p
 
 
 def pattern_block_apply(cfg: ArchConfig, p, h, *, fm: FusionMode, positions,
-                        cache=None, cache_pos=None, kv_len=None):
-    """norm -> mixer -> scaled residual, norm -> routed share + shared MLP
-    -> scaled residual.  Returns (h, new_cache)."""
+                        kind: str, cache=None, cache_pos=None, kv_len=None):
+    """norm -> mixer -> scaled residual, norm -> routed share (+ shared
+    MLP) -> scaled residual.  With ``pre_attention_router`` the routing
+    reads the mixer's normed input, not the FFN's.  Returns (h,
+    new_cache)."""
     x = L.norm_apply(cfg, p["norm1"], h, fm)
-    if "attn" in p:
-        y, c = L.attn_apply(cfg, p["attn"], x, fm=fm, positions=positions,
-                            cache=None if cache is None else cache["attn"],
-                            cache_pos=cache_pos, kv_len=kv_len)
-        new_cache = None if cache is None else {"attn": c}
-    else:
+    gate = (L.moe_share_route(cfg, p["moe"], x.reshape(-1, x.shape[-1]))
+            if cfg.pre_attention_router else None)
+    if kind == "M":
         y, c = L.mamba_apply(cfg, p["mamba"], x, fm=fm,
                              cache=None if cache is None else cache["mamba"],
                              cache_pos=cache_pos)
         new_cache = None if cache is None else {"mamba": c}
+    else:
+        windowed = kind == "S"
+        y, c = L.attn_apply(
+            cfg, p["attn"], x, fm=fm, positions=positions,
+            cache=None if cache is None else cache["attn"],
+            cache_pos=cache_pos, kv_len=kv_len,
+            window=cfg.sliding_window if windowed else 0,
+            # windowed layers always carry RoPE (rope_layout 1 wherever
+            # sliding_window_layout is 1); "A" layers follow the model
+            use_rope=windowed or cfg.position_embedding != "nope")
+        new_cache = None if cache is None else {"attn": c}
     h = h + y * cfg.residual_multiplier
     x = L.norm_apply(cfg, p["norm2"], h, fm)
-    y = L.moe_share_apply(cfg, p["moe"], x, fm) \
-        + L.mlp_apply(cfg, p["shared_mlp"], x, fm)
+    y = L.moe_share_apply(cfg, p["moe"], x, fm, gate=gate)
+    if "shared_mlp" in p:
+        y = y + L.mlp_apply(cfg, p["shared_mlp"], x, fm)
     return constrain(h + y * cfg.residual_multiplier, "act_btd"), new_cache
 
 
@@ -223,6 +236,7 @@ class Model:
             for i in range(cfg.n_layers):
                 h, nc = pattern_block_apply(
                     cfg, params["blocks"][i], h, fm=fm, positions=positions,
+                    kind=cfg.layer_pattern[i],
                     cache=None if cache is None else cache["blocks"][i],
                     cache_pos=cache_pos, kv_len=kv_len)
                 if cache is not None:
@@ -294,9 +308,11 @@ class Model:
                 lambda x: jnp.zeros((cfg.n_layers,) + x.shape, x.dtype), one)
         if cfg.layer_pattern:   # KV for attention layers, else Mamba state
             return {"blocks": [
-                {"attn": L.attn_cache_init(cfg, batch, max_len, dtype)}
-                if kind == "A" else
                 {"mamba": L.mamba_cache_init(cfg, batch, dtype)}
+                if kind == "M" else
+                {"attn": L.attn_cache_init(
+                    cfg, batch, max_len, dtype,
+                    window=cfg.sliding_window if kind == "S" else 0)}
                 for kind in cfg.layer_pattern]}
         n_apps = len([i for i in range(cfg.n_layers)
                       if cfg.attn_every and i % cfg.attn_every == 0])

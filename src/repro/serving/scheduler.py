@@ -222,9 +222,11 @@ class ContinuousBatcher:
         self.stitched = (mdl.fusion_mode != "xla" if stitched is None
                          else stitched)
         self.buckets = buckets if buckets is not None else Buckets.from_env()
-        # right-padding is masked for attention caches but folds into a
-        # recurrent state -- exact lengths for ssm/hybrid prefill.
-        self._pad_prompts = mdl.cfg.family not in ("ssm", "hybrid")
+        # right-padding is masked for a dense attention cache but folds
+        # into a recurrent state, and its keys would overwrite real ones
+        # in a windowed layer's ring -- exact lengths for those prefills.
+        self._pad_prompts = (mdl.cfg.family not in ("ssm", "hybrid")
+                             and not mdl.cfg.sliding_window)
         # XLA ignores donation on CPU (and warns); auto-enable elsewhere.
         if donate is None:
             donate = jax.default_backend() != "cpu"
@@ -421,8 +423,10 @@ class ContinuousBatcher:
         t0 = req.t_admit = time.perf_counter()
         self.stats.queue_wait.append((t0, t0 - req.t_submit))
         true_len = len(req.prompt)
+        window = self.mdl.cfg.sliding_window
+        # wrapped: the prompt outruns a windowed layer's ring cache
         with spans.span("serve.prefill", rid=req.rid, slot=i,
-                        plen=true_len):
+                        plen=true_len, wrapped=0 < window < true_len):
             if self._pad_prompts:
                 plen = self.buckets.pad_len(true_len, cap=self.max_len)
                 toks = pad_tokens(req.prompt, plen, pad_id=self.pad_id)

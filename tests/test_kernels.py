@@ -98,6 +98,76 @@ def test_flash_attention_grads():
                                    rtol=1e-3, atol=1e-4)
 
 
+def _qkv(hq, hkv, sq, skv, d=32, b=2):
+    return (rng.standard_normal((b, hq, sq, d)).astype(np.float32),
+            rng.standard_normal((b, hkv, skv, d)).astype(np.float32),
+            rng.standard_normal((b, hkv, skv, d)).astype(np.float32))
+
+
+@pytest.mark.parametrize("hq,hkv,sq,skv,window,bq,bk", [
+    (4, 2, 64, 64, 16, 16, 16),     # window a multiple of the block
+    (4, 2, 100, 100, 20, 32, 16),   # window off the block grid, seq too
+    (8, 2, 96, 96, 40, 16, 32),     # key blocks wider than query blocks
+    (2, 1, 48, 48, 64, 16, 16),     # window >= seq: plain causal
+    (2, 1, 48, 48, 48, 16, 16),     # window == seq
+    (4, 1, 16, 80, 24, 8, 16),      # queries at the end of the keys
+    (2, 2, 33, 33, 1, 64, 64),      # each query sees itself only
+])
+def test_flash_attention_window_sweep(hq, hkv, sq, skv, window, bq, bk):
+    q, k, v = _qkv(hq, hkv, sq, skv)
+    out = ops.attention(q, k, v, causal=True, block_q=bq, block_k=bk,
+                        window=window)
+    want = ref.attention(q, k, v, causal=True, window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
+
+
+def test_window_reference_matches_a_loop():
+    """The oracle's window mask against a query-by-query loop over the
+    keys ``i + Skv - Sq - window < j <= i + Skv - Sq``."""
+    q, k, v = _qkv(2, 1, 12, 20, d=8, b=1)
+    window = 5
+    got = np.asarray(ref.attention(q, k, v, causal=True, window=window))
+    for h in range(2):
+        for i in range(12):
+            at = i + 20 - 12
+            js = np.arange(max(0, at - window + 1), at + 1)
+            s = k[0, 0, js] @ q[0, h, i] / np.sqrt(8)
+            p = np.exp(s - s.max())
+            want = (p / p.sum()) @ v[0, 0, js]
+            np.testing.assert_allclose(got[0, h, i], want, rtol=1e-5,
+                                       atol=1e-6)
+
+
+def test_flash_attention_window_skips_blocks_outside():
+    """A NaN in a key block wholly behind a query block's window does not
+    reach it: that block is never read (a read block's masked NaN
+    would, through ``p @ v``)."""
+    q, k, v = _qkv(2, 1, 128, 128, d=16, b=1)
+    v[0, 0, 5] = np.nan                   # key block 0 of 16-blocks
+    out = np.asarray(ops.attention(q, k, v, causal=True, block_q=16,
+                                   block_k=16, window=16))
+    # query block 2 (32-47) sees keys from 17: blocks 1-2
+    assert np.isnan(out[0, :, 5:32]).any()
+    assert np.isfinite(out[0, :, 32:]).all()
+    v[0, 0, 5] = 0.0
+    want = ref.attention(q, k, v, causal=True, window=16)
+    np.testing.assert_allclose(out[0, :, 32:], np.asarray(want)[0, :, 32:],
+                               rtol=2e-4, atol=2e-4)
+
+
+def test_flash_attention_window_grads():
+    q, k, v = _qkv(2, 1, 40, 40, d=16, b=1)
+    f_k = lambda *a: jnp.sum(ops.attention(*a, causal=True, block_q=16,
+                                           block_k=16, window=12) ** 2)
+    f_r = lambda *a: jnp.sum(ref.attention(*a, causal=True, window=12) ** 2)
+    gk = jax.grad(f_k, (0, 1, 2))(q, k, v)
+    gr = jax.grad(f_r, (0, 1, 2))(q, k, v)
+    for a, b in zip(gk, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-3, atol=1e-4)
+
+
 @pytest.mark.parametrize("kvlen", [96, 64, 40])
 def test_flash_decode(kvlen):
     q = rng.standard_normal((2, 8, 64)).astype(np.float32)

@@ -69,7 +69,7 @@ def _generated(mdl, params, prompt, n):
 
 
 @pytest.mark.parametrize("arch", ["llama3.2-3b", "mamba2-370m",
-                                  "zamba2-1.2b"])
+                                  "zamba2-1.2b", "smallthinker-21b-a3b"])
 def test_waves_ahead_serve_the_same_tokens(arch):
     """Every slot held, refills between: waves run ahead and each request
     still gets exactly its single-request tokens."""
@@ -181,11 +181,12 @@ def test_each_step_adds_one_token_and_no_wave_outlives_a_stop():
 
 
 @pytest.mark.parametrize("arch", ["llama3.2-3b", "mamba2-370m",
-                                  "zamba2-1.2b", "granite-4.0-h-small"])
+                                  "zamba2-1.2b", "granite-4.0-h-small",
+                                  "smallthinker-21b-a3b"])
 def test_prefill_writes_its_slot_and_only_it(arch):
-    """Attention, scanned SSM, hybrid and layer-pattern caches: the
-    prefilled slot holds exactly what the prefill made, and every other
-    slot keeps its state bit for bit."""
+    """Attention, scanned SSM, hybrid and layer-pattern caches (rings
+    too): the prefilled slot holds exactly what the prefill made, and
+    every other slot keeps its state bit for bit."""
     cfg, mdl, params = _setup(arch)
     server = ContinuousBatcher(mdl, params, n_slots=3, max_len=32)
     keys = iter(jax.random.split(jax.random.PRNGKey(1), 256))

@@ -104,7 +104,8 @@ def test_prefill_and_wave_spans_nest_as_named(batcher, monkeypatch):
     prefill, wave = rec.take()
     assert shape(prefill) == ("serve.prefill", (
         CALL, ("prefill.cache_write", ()), ("prefill.sample", ())))
-    assert prefill[1] == {"rid": req.rid, "slot": 1, "plen": 7}
+    assert prefill[1] == {"rid": req.rid, "slot": 1, "plen": 7,
+                          "wrapped": False}
     leaves = {"leaves": len(jax.tree_util.tree_leaves(cb.cache))}
     assert prefill[2][1][1] == leaves
     assert prefill[2][0][1] == {"program": "stitched_prefill"}

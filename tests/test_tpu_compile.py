@@ -24,7 +24,9 @@ from repro import kernels
 from repro.core.cost_model import Hardware
 from repro.core.stitch import stitched_jit
 from repro.kernels import ops
-from repro.kernels.flash_attention import flash_attention, flash_decode
+from repro.kernels.flash_attention import (flash_attention,
+                                          flash_attention_window,
+                                          flash_decode)
 
 #: zamba2-1.2b widths (configs/zamba2_1_2b.py)
 D_MODEL, HEADS, HEAD_DIM, D_FF = 2048, 32, 64, 8192
@@ -140,6 +142,19 @@ def test_gqa_attention_compiles_at_granite_width(spec):
                       spec((B, 8, S, 128), jnp.float32),
                       spec((B, 8, S, 128), jnp.float32))
     assert _kernels_in(lowered) >= 1
+
+
+@pytest.mark.parametrize("S", [1024, 8192])
+def test_window_attention_compiles_at_smallthinker_width(spec, S):
+    """smallthinker-21b-a3b's windowed prefill attention: 28 query heads
+    over 4 key/value heads of 128, a 4096-position window, a prompt
+    inside it and one twice its length; the kernel keeps its name."""
+    f = jax.jit(functools.partial(flash_attention_window, window=4096))
+    text = f.lower(spec((1, 28, S, 128), jnp.float32),
+                   spec((1, 4, S, 128), jnp.float32),
+                   spec((1, 4, S, 128), jnp.float32)).compile().as_text()
+    assert re.search(r"%flash_attention_window(\.\d+)? = .*tpu_custom_call",
+                     text)
 
 
 @DTYPES
