@@ -49,9 +49,11 @@ def _init_scratch(m_ref, l_ref, acc_ref):
 
 
 def _online_softmax_step(s, v, m_ref, l_ref, acc_ref):
-    """Fold one key block's masked scores ``s`` [bq, bk] and values
-    ``v`` [bk, D] into the running max, denominator and accumulator."""
-    m_prev = m_ref[...]                       # [bq, 1]
+    """Fold one key block's masked scores ``s`` [..., bq, bk] and values
+    ``v`` [..., bk, D] into the running max, denominator and accumulator
+    (leading dims, if any, are a batch)."""
+    batch = tuple(range(s.ndim - 2))
+    m_prev = m_ref[...]                       # [..., bq, 1]
     m_cur = jnp.max(s, axis=-1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_cur)
     p = jnp.exp(s - m_new)                    # [bq, bk]
@@ -59,7 +61,8 @@ def _online_softmax_step(s, v, m_ref, l_ref, acc_ref):
 
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        p, v, (((len(batch) + 1,), (len(batch),)), (batch, batch)),
+        preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
 
@@ -272,22 +275,108 @@ def flash_attention_window(q, k, v, *, window: int,
     return out[:, :, :Sq, :]
 
 
-def flash_decode(q, k_cache, v_cache, *, kv_len: int | None = None, scale=None,
-                 block_k: int = 512):
-    """Decode-shape attention: q [B, Hq, D] against caches [B, Hkv, S, D].
+#: K and V bytes a decode step aims at: enough that the fixed cost of a
+#: grid step is small beside its copy, few enough that a row's last,
+#: partly live block wastes little.  A step may hold twice this (many
+#: heads at the shortest block), double-buffered still well inside the
+#: default scoped VMEM
+DECODE_STEP_BYTES = 2 << 20
+#: the shortest key block the decode kernel picks for itself
+DECODE_MIN_BLOCK = 128
 
-    Uses the same streaming kernel with a single q row per block; the
-    K-block axis does the long-context streaming (the 500k case).
-    ``kv_len`` (static) masks cache positions >= kv_len — the serve loop
-    passes the current decode position so a pre-allocated cache works.
+
+def _decode_blocks(hkv: int, s: int, d: int, itemsize: int,
+                   block_k: int | None) -> tuple[int, int]:
+    """(key block, kv heads per step) for a cache of ``hkv`` heads of
+    ``s`` positions: the block spans what a step's byte aim leaves each
+    head, and a step takes as many heads as fit in twice the aim."""
+    cap = max(1, DECODE_STEP_BYTES // (2 * d * itemsize))  # head-positions
+    if block_k is None:
+        block_k = max(DECODE_MIN_BLOCK, cap // hkv)
+    blk_k = max(1, min(block_k, -(-s // 8) * 8))
+    hb = max(n for n in range(1, hkv + 1)
+             if hkv % n == 0 and n * blk_k <= max(2 * cap, blk_k))
+    return blk_k, hb
+
+
+def _decode_live(n, *, blk_k: int, nk: int):
+    """The first step of a row's key-block run that reads a live block:
+    its ``cdiv(n, blk_k)`` live blocks take the run's last steps."""
+    return nk - (jnp.maximum(n, 1) + blk_k - 1) // blk_k
+
+
+def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+                   acc_ref, *, scale: float, blk_k: int, nk: int):
+    n = len_ref[pl.program_id(0)]
+    ik = pl.program_id(2)
+    first = _decode_live(n, blk_k=blk_k, nk=nk)
+
+    pl.when(ik == 0)(lambda: _init_scratch(m_ref, l_ref, acc_ref))
+
+    @pl.when(ik >= first)                     # before the live blocks: skip
+    def _step():
+        q = q_ref[0].astype(jnp.float32)      # [hb, group, D]
+        k = k_ref[0].astype(jnp.float32)      # [hb, blk_k, D]
+        v = v_ref[0].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32) * scale
+        k_idx = (ik - first) * blk_k + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 2)
+        _online_softmax_step(jnp.where(k_idx < n, s, NEG_INF), v,
+                             m_ref, l_ref, acc_ref)
+
+    pl.when(ik == nk - 1)(lambda: _write_out(o_ref, l_ref, acc_ref))
+
+
+def flash_decode(q, k_cache, v_cache, lengths, *, scale=None,
+                 block_k: int | None = None):
+    """Decode-shape attention: q [B, Hq, D] against caches [B, Hkv, S, D],
+    row ``b`` over its first ``lengths[b]`` cache positions (``lengths``
+    [B] int32, each at least 1; an int is every row's length).
+
+    The lengths reach the kernel as scalar prefetch.  The key-block axis
+    of the grid spans the whole cache, and a row's live blocks take the
+    last steps of its run: the steps before them repeat the index of its
+    first block (no copy) and compute nothing, so a row reads only its
+    live blocks, the last one masked.  Skipping at the start of a run,
+    not the end, lets the pipeline fetch a row's first block while the
+    row before computes its last.  One step holds a group of kv
+    heads with all their query heads (``[hb, Hq // Hkv, D]``), so under
+    GQA each K/V byte is read once; the block and the group are sized
+    from the shapes (``_decode_blocks``) unless ``block_k`` is given.
     """
     B, Hq, D = q.shape
-    S = k_cache.shape[2]
-    eff = S if kv_len is None else int(kv_len)
-    if eff < S:  # restrict streaming to the live prefix
-        k_cache = k_cache[:, :, :eff, :]
-        v_cache = v_cache[:, :, :eff, :]
-    out = flash_attention(q[:, :, None, :], k_cache, v_cache, causal=False,
-                          scale=scale, block_q=1, block_k=min(block_k, eff),
-                          name="flash_decode")
-    return out[:, :, 0, :]
+    _, Hkv, S, _ = k_cache.shape
+    assert Hq % Hkv == 0, "GQA requires Hq % Hkv == 0"
+    group = Hq // Hkv
+    scale = scale if scale is not None else 1.0 / math.sqrt(D)
+    lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
+    blk_k, hb = _decode_blocks(Hkv, S, D, k_cache.dtype.itemsize, block_k)
+    nk = math.ceil(S / blk_k)
+    k_cache = _pad_seq(k_cache, nk * blk_k)
+    v_cache = _pad_seq(v_cache, nk * blk_k)
+
+    def kv_map(b, h, ik, lens):
+        return b, h, jnp.maximum(ik - _decode_live(lens[b], blk_k=blk_k,
+                                                   nk=nk), 0), 0
+
+    def q_map(b, h, ik, lens):
+        return b, h, 0, 0
+
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, scale=scale, blk_k=blk_k, nk=nk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, Hkv // hb, nk),
+            in_specs=[pl.BlockSpec((1, hb, group, D), q_map),
+                      pl.BlockSpec((1, hb, blk_k, D), kv_map),
+                      pl.BlockSpec((1, hb, blk_k, D), kv_map)],
+            out_specs=pl.BlockSpec((1, hb, group, D), q_map),
+            scratch_shapes=[pltpu.VMEM((hb, group, 1), jnp.float32),
+                            pltpu.VMEM((hb, group, 1), jnp.float32),
+                            pltpu.VMEM((hb, group, D), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
+        name="flash_decode",
+        interpret=kernels.interpret_mode(),
+    )(lengths, q.reshape(B, Hkv, group, D), k_cache, v_cache)
+    return out.reshape(B, Hq, D)

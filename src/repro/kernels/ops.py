@@ -81,23 +81,44 @@ def attention(q, k, v, *, causal: bool = True, scale=None,
     return ref.attention(q, k, v, causal=causal, scale=scale, window=window)
 
 
-def decode_attention(q, k_cache, v_cache, *, kv_len=None, scale=None,
-                     use_pallas: bool = True, block_k: int = 512):
-    import numpy as _np
-    dynamic = kv_len is not None and not isinstance(kv_len, (int, _np.integer))
-    if dynamic:
-        # traced per-call length (continuous-batching serving): mask path
-        lengths = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32),
-                                   (q.shape[0],))
-        return ref.decode_attention(q, k_cache, v_cache, lengths=lengths,
-                                    scale=scale)
-    if use_pallas:
-        return _flash_decode(q, k_cache, v_cache, kv_len=kv_len, scale=scale,
+@functools.lru_cache(maxsize=None)
+def _decode_by_length(scale, block_k):
+    """``flash_decode`` over per-row lengths, batched by folding: under
+    ``vmap`` the mapped axis joins the kernel's own batch axis (caches
+    ``[N, B, Hkv, S, D]`` run as ``[N*B, Hkv, S, D]``, lengths as
+    ``[N*B]``), so a wave of slots is one kernel, not Pallas's loop of
+    one kernel per slot over a batched scalar-prefetch operand."""
+
+    @jax.custom_batching.custom_vmap
+    def decode(q, k_cache, v_cache, lengths):
+        return _flash_decode(q, k_cache, v_cache, lengths, scale=scale,
                              block_k=block_k)
-    if kv_len is not None and kv_len < k_cache.shape[2]:
-        k_cache = k_cache[:, :, :kv_len, :]
-        v_cache = v_cache[:, :, :kv_len, :]
-    return ref.decode_attention(q, k_cache, v_cache, scale=scale)
+
+    @decode.def_vmap
+    def _fold(axis_size, in_batched, *args):
+        args = [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+                for a, b in zip(args, in_batched)]
+        out = decode(*(a.reshape((-1,) + a.shape[2:]) for a in args))
+        return out.reshape((axis_size, -1) + out.shape[1:]), True
+
+    return decode
+
+
+def decode_attention(q, k_cache, v_cache, *, kv_len=None, scale=None,
+                     use_pallas: bool = True, block_k: int | None = None):
+    """q [B, Hq, D] against caches [B, Hkv, S, D] over the first
+    ``kv_len`` positions (an int, a traced scalar or ``[B]`` per row;
+    ``None``: all ``S``).  With ``use_pallas`` the kernel reads only the
+    key blocks below each row's length; else the oracle masks scores
+    over the whole cache."""
+    B, S = q.shape[0], k_cache.shape[2]
+    lengths = jnp.broadcast_to(
+        jnp.asarray(S if kv_len is None else kv_len, jnp.int32), (B,))
+    if use_pallas:
+        return _decode_by_length(scale, block_k)(q, k_cache, v_cache,
+                                                 lengths)
+    return ref.decode_attention(q, k_cache, v_cache, lengths=lengths,
+                                scale=scale)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))

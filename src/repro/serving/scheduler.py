@@ -111,6 +111,9 @@ class ServeStats:
     queue_wait: list = field(default_factory=list)
     steady_wall_s: float = 0.0  # wall in warm (already-compiled) calls
     steady_tokens: int = 0      # tokens produced by warm calls
+    #: sum over waves of the share of the held slots' cache positions
+    #: that are live (``serve.wave``'s ``kv_live``)
+    kv_live_sum: float = 0.0
 
     @property
     def tok_per_s(self) -> float:
@@ -122,6 +125,12 @@ class ServeStats:
         over warm-call wall (the fleet-amortized rate)."""
         return (self.steady_tokens / self.steady_wall_s
                 if self.steady_wall_s else 0.0)
+
+    @property
+    def kv_live(self) -> float:
+        """Mean over waves of the held slots' live share of the cache:
+        how much of it a length-bound decode kernel reads."""
+        return self.kv_live_sum / len(self.wave_s) if self.wave_s else 0.0
 
     @property
     def hit_rate(self) -> float:
@@ -159,8 +168,8 @@ class ServeStats:
                f"{self.plan_cache_misses}m | ttft p50/p99 "
                f"{self.p50_ttft_s * 1e3:.1f}/{self.p99_ttft_s * 1e3:.1f}ms"
                f" | wave p50/p99 {self.p50_wave_s * 1e3:.1f}/"
-               f"{self.p99_wave_s * 1e3:.1f}ms | "
-               f"{self.tok_per_s:.1f} tok/s "
+               f"{self.p99_wave_s * 1e3:.1f}ms, kv live "
+               f"{self.kv_live:.1%} | {self.tok_per_s:.1f} tok/s "
                f"({self.tok_per_s_steady:.1f} steady)")
         if self.canaried or self.canary_quarantines \
                 or self.canary_baseline_serves:
@@ -458,7 +467,10 @@ class ContinuousBatcher:
         if not active:
             self._wave = None   # the caller cleared every slot it served
             return
-        with spans.span("serve.wave", n_active=len(active)):
+        # the held slots' live share of their caches: each reads pos + 1
+        kv_live = (sum(self.slots[i].pos + 1 for i in active)
+                   / (len(active) * self.max_len))
+        with spans.span("serve.wave", n_active=len(active), kv_live=kv_live):
             t0 = time.perf_counter()
             if self._wave is None:
                 with spans.span("wave.inputs"):
@@ -479,6 +491,7 @@ class ContinuousBatcher:
                 nxt = np.asarray(toks)[:, 0, 0]
             dt = time.perf_counter() - t0
             self.stats.wave_s.append(dt)
+            self.stats.kv_live_sum += kv_live
             self._note_call(("decode",), dt, tokens=len(active))
             with spans.span("wave.retire"):
                 for i in active:

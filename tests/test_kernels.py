@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import flash_attention as fa
 from repro.kernels import ops, ref
 
 rng = np.random.default_rng(7)
@@ -176,6 +177,68 @@ def test_flash_decode(kvlen):
     out = ops.decode_attention(q, kc, vc, kv_len=kvlen, block_k=32)
     want = ops.decode_attention(q, kc, vc, kv_len=kvlen, use_pallas=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
+
+
+def _edge_lengths(blk, S):
+    """A row at each edge of a key block: 1, blk - 1, blk, blk + 1, S."""
+    return np.array([1, blk - 1, blk, blk + 1, S], np.int32)
+
+
+def _decode_case(group, hkv, S, D, lengths):
+    """q and caches for ``len(lengths)`` rows; past each row's length the
+    cache holds large finite keys and values, as a reused slot may."""
+    B = len(lengths)
+    q = rng.standard_normal((B, hkv * group, D)).astype(np.float32)
+    k = rng.standard_normal((B, hkv, S, D)).astype(np.float32)
+    v = rng.standard_normal((B, hkv, S, D)).astype(np.float32)
+    for b, n in enumerate(lengths):
+        k[b, :, n:] = 1e4
+        v[b, :, n:] = -1e4
+    return q, k, v
+
+
+@pytest.mark.parametrize("group", [1, 4, 7])
+@pytest.mark.parametrize("heads_per_step", [1, 2])
+def test_flash_decode_lengths(group, heads_per_step, monkeypatch):
+    """Per-row lengths at each edge of a key block against the oracle's
+    mask, with one or two kv heads (and their query groups) per step:
+    nothing past a row's length reaches its result."""
+    hkv, S, D, blk = 2, 64, 32, 16
+    # a step holds up to twice the aim: K and V of ``heads_per_step`` heads
+    monkeypatch.setattr(fa, "DECODE_STEP_BYTES", heads_per_step * blk * D * 4)
+    assert fa._decode_blocks(hkv, S, D, 4, blk) == (blk, heads_per_step)
+    lengths = _edge_lengths(blk, S)
+    q, k, v = _decode_case(group, hkv, S, D, lengths)
+    out = fa.flash_decode(q, k, v, lengths, block_k=blk)
+    want = ref.decode_attention(q, k, v, lengths=jnp.asarray(lengths))
+    assert np.abs(np.asarray(want)).max() < 10
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("group", [1, 7])
+def test_decode_attention_vmapped_per_slot_lengths(group):
+    """The decode wave's layout: ``vmap`` over slots of one-row caches
+    ``[N, 1, Hkv, S, D]`` with a traced ``kv_len = pos + 1`` per slot,
+    folded into one kernel; a second ``vmap`` over two waves folds
+    too."""
+    hkv, S, D, blk = 2, 48, 16, 16
+    pos = _edge_lengths(blk, S) - 1
+    q, k, v = (x[:, None] for x in _decode_case(group, hkv, S, D, pos + 1))
+
+    def wave(use_pallas):
+        return jax.vmap(lambda q, k, v, p: ops.decode_attention(
+            q, k, v, kv_len=p + 1, block_k=blk, use_pallas=use_pallas))
+
+    want = np.asarray(wave(False)(q, k, v, pos))
+    out = jax.jit(wave(True))(q, k, v, pos)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=2e-4, atol=2e-4)
+    two = jax.vmap(wave(True))(*(jnp.stack([x[::-1], x])
+                                 for x in (q, k, v, pos)))
+    np.testing.assert_allclose(np.asarray(two[1]), want,
+                               rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(two[0]), want[::-1],
                                rtol=2e-4, atol=2e-4)
 
 

@@ -112,7 +112,8 @@ def test_prefill_and_wave_spans_nest_as_named(batcher, monkeypatch):
     assert shape(wave) == ("serve.wave", (
         ("wave.inputs", ()), CALL, ("wave.ahead", (CALL,)),
         ("wave.sample", ()), ("wave.retire", ())))
-    assert wave[1] == {"n_active": 2}
+    # both slots read 8 positions of 32 (kv_live)
+    assert wave[1] == {"n_active": 2, "kv_live": 16 / 64}
     assert wave[2][1][1] == {"program": "stitched_decode_wave"}
     assert wave[2][2][2][0][1] == {"program": "stitched_decode_wave"}
 
@@ -130,7 +131,7 @@ def test_prefill_and_wave_spans_nest_as_named(batcher, monkeypatch):
     assert shape(wave) == ("serve.wave", (
         ("wave.inputs", ()), CALL, ("wave.sample", ()),
         ("wave.retire", ())))
-    assert wave[1] == {"n_active": 1}
+    assert wave[1] == {"n_active": 1, "kv_live": 10 / 32}
     assert wave[2][1][1] == {"program": "stitched_decode_wave"}
     cb.slots = [None] * cb.n_slots
 

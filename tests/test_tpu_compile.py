@@ -183,14 +183,36 @@ def test_flash_attention_score_mod_compiles(spec, dtype):
     assert _kernels_in(lowered) >= 1
 
 
+#: decode caches (slots, kv heads, positions, query group) of the cells:
+#: SmallThinker's global layers and rings, zamba2's shared block
+DECODE_SHAPES = pytest.mark.parametrize("B,hkv,S,group", [
+    (24, 4, 12288, 7), (24, 4, 4096, 7), (16, 32, 1024, 1)],
+    ids=["smallthinker-global", "smallthinker-ring", "zamba2"])
+
+
 @DTYPES
-def test_flash_decode_compiles(spec, dtype):
-    B, S = 4, 1024
-    f = jax.jit(functools.partial(flash_decode, kv_len=S))
-    lowered = f.lower(spec((B, HEADS, HEAD_DIM), dtype),
-                      spec((B, HEADS, S, HEAD_DIM), dtype),
-                      spec((B, HEADS, S, HEAD_DIM), dtype))
+@DECODE_SHAPES
+def test_flash_decode_compiles(spec, dtype, B, hkv, S, group):
+    D = 128
+    lowered = jax.jit(flash_decode).lower(
+        spec((B, hkv * group, D), dtype), spec((B, hkv, S, D), dtype),
+        spec((B, hkv, S, D), dtype), spec((B,), jnp.int32))
     assert _kernels_in(lowered) >= 1
+
+
+def test_decode_wave_attention_compiles_to_one_kernel(spec):
+    """The wave's layout (a ``vmap`` over slots of one-row caches with a
+    traced length each) compiles to one kernel and no loop."""
+    B, hkv, S, group, D = 24, 4, 12288, 7, 128
+    wave = jax.vmap(lambda q, k, v, p: ops.decode_attention(
+        q, k, v, kv_len=p + 1))
+    text = jax.jit(wave).lower(
+        spec((B, 1, hkv * group, D), jnp.float32),
+        spec((B, 1, hkv, S, D), jnp.float32),
+        spec((B, 1, hkv, S, D), jnp.float32),
+        spec((B,), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert not re.search(r"\bwhile\b", text)
 
 
 def _norm_matmul_gelu(x, g, w, r):
